@@ -1,6 +1,6 @@
 """Fine-tune a BERT classifier with the high-level Model API.
 
-    python examples/finetune_bert.py --epochs 3
+    PYTHONPATH=. python examples/finetune_bert.py --epochs 3
 """
 
 import argparse
@@ -18,9 +18,11 @@ def main():
     import paddle_tpu.nn as nn
     from paddle_tpu import Model
     from paddle_tpu.io import TensorDataset
+    from paddle_tpu.jit import enable_compile_cache
     from paddle_tpu.metric import Accuracy
     from paddle_tpu.models.bert import BertConfig, BertForSequenceClassification
     from paddle_tpu.optimizer import AdamW
+    enable_compile_cache()
 
     cfg = BertConfig(vocab_size=1000, hidden_size=64, num_hidden_layers=2,
                      num_attention_heads=4, intermediate_size=128,

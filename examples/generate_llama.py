@@ -4,7 +4,7 @@ Greedy / top-p decoding where prefill + the whole decode loop is ONE
 compiled XLA program, plus the streaming token-at-a-time session
 (donated-cache) used by serving.
 
-    python examples/generate_llama.py --max-new 32 --top-p 0.9
+    PYTHONPATH=. python examples/generate_llama.py --max-new 32 --top-p 0.9
 """
 
 import argparse
@@ -22,8 +22,10 @@ def main():
 
     import jax
     import paddle_tpu as paddle
+    from paddle_tpu.jit import enable_compile_cache
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.models.generation import DecodeSession
+    enable_compile_cache()
 
     paddle.seed(0)
     cfg = LlamaConfig(vocab_size=1024, hidden_size=256,

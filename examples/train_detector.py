@@ -1,7 +1,7 @@
 """Train the PP-YOLOE-style detector (MobileNetV3 + FPN + decoupled head)
 on synthetic boxes, then run static-shape NMS inference.
 
-    python examples/train_detector.py --steps 5 --image 64
+    PYTHONPATH=. python examples/train_detector.py --steps 5 --image 64
 """
 
 import argparse
@@ -17,9 +17,11 @@ def main():
     args = ap.parse_args()
 
     import paddle_tpu as paddle
+    from paddle_tpu.jit import enable_compile_cache
     from paddle_tpu.optimizer import Adam
     from paddle_tpu.vision.detection import (detection_loss, ppyoloe_mbv3,
                                              static_nms)
+    enable_compile_cache()
 
     paddle.seed(0)
     det = ppyoloe_mbv3(num_classes=args.classes, image_size=args.image)

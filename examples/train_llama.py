@@ -1,11 +1,17 @@
 """Pretrain a small LLaMA-family decoder end to end.
 
-Runs on one TPU chip as-is, or on the 8-device CPU mesh with
-``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``
-plus ``--dp 2 --mp 2 --fsdp 2``.
+From the repo root (the package is not installed, so it rides
+``PYTHONPATH``). On one TPU chip as-is; on a four-chip host with
+``--dp 2 --mp 2`` (one process drives all four); on the virtual CPU mesh
+only where the environment says so:
 
-    python examples/train_llama.py --steps 20
-    python examples/train_llama.py --dp 2 --mp 2 --fsdp 2 --steps 5
+    PYTHONPATH=. python examples/train_llama.py --steps 20
+    PYTHONPATH=. python examples/train_llama.py --dp 2 --mp 2 --steps 5
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        PYTHONPATH=. python examples/train_llama.py --dp 2 --mp 2 --fsdp 2
+
+The persistent compile cache is ``JAX_COMPILATION_CACHE_DIR`` where set,
+else ``<checkout>/.jax_cache`` (``paddle_tpu.jit.enable_compile_cache``).
 """
 
 import argparse
@@ -29,10 +35,12 @@ def main():
     ap.add_argument("--sep", type=int, default=1, help="ring-attention CP")
     args = ap.parse_args()
 
+    from paddle_tpu.jit import enable_compile_cache
     from paddle_tpu.models import llama
     from paddle_tpu.distributed.topology import (HybridCommunicateGroup,
                                                  set_hybrid_communicate_group)
     from jax.sharding import NamedSharding
+    enable_compile_cache()
 
     cfg = llama.LlamaConfig(
         vocab_size=4096, hidden_size=args.hidden,
@@ -70,7 +78,10 @@ def main():
             jnp.asarray(rng.integers(0, cfg.vocab_size,
                                      (args.batch, args.seq)), jnp.int32),
             batch_sharding)
-        params, opt, loss = jstep(params, opt, ids, ids)
+        # under the mesh the flash kernel runs as a per-shard region
+        # (GSPMD cannot partition a Mosaic kernel)
+        with jax.set_mesh(hcg.mesh):
+            params, opt, loss = jstep(params, opt, ids, ids)
         if step % 5 == 0 or step == args.steps - 1:
             print(f"step {step:4d}  loss {float(loss):.4f}  "
                   f"({(time.time() - t0):.1f}s)")

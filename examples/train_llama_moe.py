@@ -2,11 +2,13 @@
 
 Experts are GShard-routed; their stacked weights are sharded E/ep per
 device over the ``ep`` mesh axis while the batch is data-parallel over
-``dp`` — GSPMD inserts the expert all_to_all. Runs on one TPU chip as-is
-(``--dp 1 --ep 1``) or on the virtual CPU mesh:
+``dp`` — GSPMD inserts the expert all_to_all. From the repo root, on one
+TPU chip as-is (``--dp 1 --ep 1``), on a four-chip host with
+``--dp 2 --ep 2``, or on the virtual CPU mesh where the environment says so:
 
+    PYTHONPATH=. python examples/train_llama_moe.py --steps 10
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        python examples/train_llama_moe.py --dp 2 --ep 4 --steps 10
+        PYTHONPATH=. python examples/train_llama_moe.py --dp 2 --ep 4
 """
 
 import argparse
@@ -28,6 +30,8 @@ def main():
     from jax.sharding import NamedSharding
 
     from paddle_tpu.distributed.topology import build_mesh
+    from paddle_tpu.jit import enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.models import llama
 
     cfg = llama.LlamaConfig(
@@ -58,7 +62,9 @@ def main():
         ids = jax.device_put(
             rng.integers(0, cfg.vocab_size,
                          (args.batch, args.seq)).astype(np.int32), bs)
-        params, opt, loss = jstep(params, opt, ids, ids)
+        # under the mesh the flash kernel runs as a per-shard region
+        with jax.set_mesh(mesh):
+            params, opt, loss = jstep(params, opt, ids, ids)
         if i % 5 == 0 or i == args.steps - 1:
             print(f"step {i:3d}  loss {float(loss):.4f}")
 

@@ -6,7 +6,8 @@ the interleaved circular schedule for the decoder blocks
 (``llama.make_pp_train_step``). Run on the virtual CPU mesh:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        python examples/train_llama_pp.py --dp 2 --pp 4 --virtual_pp 2
+        PYTHONPATH=. python examples/train_llama_pp.py --dp 2 --pp 4 \\
+        --virtual_pp 2
 """
 
 import argparse
@@ -30,7 +31,9 @@ def main():
 
     from paddle_tpu.distributed.pipeline import pipeline_ticks
     from paddle_tpu.distributed.topology import build_mesh
+    from paddle_tpu.jit import enable_compile_cache
     from paddle_tpu.models import llama
+    enable_compile_cache()
 
     S, V, M = args.pp, args.virtual_pp, args.micro_batches
     cfg = llama.LlamaConfig(

@@ -3,10 +3,11 @@ sparse embedding (SelectedRows gradients, host-resident table) + dense MLP
 tower (SURVEY §2.5 Parameter server; the reference's
 paddle.static.nn.sparse_embedding + a_sync DistributedStrategy workload).
 
-Run:  python examples/train_recsys.py
-Multi-process (vocab-sharded):
-      python -m paddle_tpu.distributed.launch --nproc_per_node 2 \
-          examples/train_recsys.py
+Run:  PYTHONPATH=. python examples/train_recsys.py
+Multi-process (vocab-sharded; several processes per node is the CPU
+simulation, which the launcher runs only where JAX_PLATFORMS=cpu is set):
+      JAX_PLATFORMS=cpu PYTHONPATH=. python -m paddle_tpu.distributed.launch \
+          --nproc_per_node 2 examples/train_recsys.py
 
 What it demonstrates:
   * the [vocab, dim] table never hits device HBM (host=True) — the
@@ -24,6 +25,7 @@ import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 from paddle_tpu.distributed.ps import (AsyncLookup, SparseAdam,
                                        SparseEmbedding)
+from paddle_tpu.jit import enable_compile_cache
 
 VOCAB = 1_000_000          # 1M ids x 32 dims = 128 MB fp32 — host-resident
 DIM = 32
@@ -33,6 +35,7 @@ STEPS = 20
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     emb = SparseEmbedding(VOCAB, DIM, host=True, seed=1)
     tower = nn.Sequential(nn.Linear(SLOTS * DIM, 64), nn.ReLU(),

@@ -2,8 +2,8 @@
 (docs/PERFORMANCE.md): channels-last layout pass + fused donation-aware
 train step + device-prefetched DataLoader, with bf16 AMP.
 
-    python examples/train_resnet.py --steps 10
-    python examples/train_resnet.py --steps 10 --nchw   # layout pass off
+    PYTHONPATH=. python examples/train_resnet.py --steps 10
+    PYTHONPATH=. python examples/train_resnet.py --steps 10 --nchw  # pass off
 """
 
 import argparse
@@ -22,10 +22,11 @@ def main():
 
     import paddle_tpu.nn as nn
     from paddle_tpu.io import DataLoader
-    from paddle_tpu.jit import make_train_step
+    from paddle_tpu.jit import enable_compile_cache, make_train_step
     from paddle_tpu.optimizer import Momentum
     from paddle_tpu.vision.datasets import FakeImageDataset
     from paddle_tpu.vision.models import resnet18
+    enable_compile_cache()
 
     net = resnet18(num_classes=100)
     if not args.nchw:

@@ -10,6 +10,7 @@ scripts run unmodified (a deliberate compatibility shim, logged once).
 
 from __future__ import annotations
 
+import os
 import threading
 import warnings
 from typing import Optional
@@ -18,7 +19,17 @@ import jax
 
 __all__ = ["Place", "CPUPlace", "TPUPlace", "CUDAPlace", "XPUPlace", "set_device",
            "get_device", "device_count", "is_compiled_with_cuda",
-           "is_compiled_with_xpu", "is_compiled_with_tpu", "get_jax_device"]
+           "is_compiled_with_xpu", "is_compiled_with_tpu", "get_jax_device",
+           "cpu_requested"]
+
+
+def cpu_requested() -> bool:
+    """Whether the caller's environment says ``JAX_PLATFORMS=cpu`` in so
+    many words — the one condition under which an entry point may run its
+    CPU simulation (bench's toy preset, the launcher's several processes
+    per node, the dry run's virtual mesh). Resolving to the CPU because no
+    chip was found is not it."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
 
 
 class Place:
@@ -80,12 +91,9 @@ def XPUPlace(device_id: int = 0) -> TPUPlace:  # noqa: N802
 
 
 def _accelerator_platform() -> Optional[str]:
-    try:
-        for d in jax.devices():
-            if d.platform != "cpu":
-                return d.platform
-    except RuntimeError:
-        return None
+    for d in jax.devices():
+        if d.platform != "cpu":
+            return d.platform
     return None
 
 
@@ -130,15 +138,24 @@ def get_device() -> str:
 
 
 def get_jax_device(place: Optional[Place] = None):
-    """Resolve a Place to a concrete jax.Device."""
+    """Resolve a Place to a concrete jax.Device. A ``TPUPlace`` on a
+    machine whose jax found no accelerator, or with an id past the last
+    chip, is an error naming what was found — never a CPU device or
+    another chip under the requested name."""
     place = place or _current_place()
     if place.is_cpu_place():
-        for d in jax.devices("cpu"):
-            return d
-        return jax.devices()[0]
+        return jax.devices("cpu")[0]
     plat = _accelerator_platform()
-    devs = jax.devices(plat) if plat else jax.devices()
-    return devs[place.device_id % len(devs)]
+    if plat is None:
+        raise RuntimeError(
+            f"{place!r} requested, but jax found no accelerator: backend "
+            f"{jax.default_backend()!r}, devices {jax.devices()}")
+    devs = jax.devices(plat)
+    if not 0 <= place.device_id < len(devs):
+        raise ValueError(
+            f"{place!r} requested, but the {plat!r} platform has "
+            f"{len(devs)} device(s): ids 0..{len(devs) - 1}")
+    return devs[place.device_id]
 
 
 def device_count() -> int:

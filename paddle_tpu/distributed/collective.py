@@ -21,9 +21,8 @@ from typing import List, Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..core.jax_compat import shard_map
 
 from ..core.tensor import Tensor, _wrap_value
 from ..health import watchdog

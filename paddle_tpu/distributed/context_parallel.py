@@ -32,9 +32,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ..core.jax_compat import axis_size, shard_map
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.tensor import Tensor

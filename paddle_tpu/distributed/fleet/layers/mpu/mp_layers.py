@@ -19,9 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from .....core.jax_compat import axis_size
 
 from .....core.tensor import Parameter, Tensor
 from .....nn import functional as F

@@ -22,9 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from .....core.jax_compat import axis_size
 
 from .....core.tensor import Tensor
 from .....ops._helpers import ensure_tensor, forward_op

@@ -4,10 +4,11 @@ Parity target: ``python/paddle/distributed/launch/main.py`` +
 ``controllers/collective.py`` in the reference (process spawn, env plumbing,
 workerlog.N files, failure watch, elastic restarts). TPU redesign: the unit
 of launch is one process per HOST (single-controller JAX sees every local
-chip), so ``--nproc_per_node`` defaults to 1; values > 1 run the multi-
+chip), so ``--nproc_per_node`` defaults to 1; values > 1 are the multi-
 process CPU simulation (each child gets a ``jax.distributed`` process id and
 a localhost coordinator — the reference's Gloo-on-localhost testing trick,
-SURVEY §4).
+SURVEY §4) and are refused unless the caller's environment says
+``JAX_PLATFORMS=cpu``, which the children inherit.
 
 Env contract exported to children (reference names + their JAX equivalents):
   PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_MASTER
@@ -25,6 +26,8 @@ import subprocess
 import sys
 import time
 from typing import List, Optional
+
+from ...core.place import cpu_requested
 
 __all__ = ["main", "launch_procs", "write_rejoin_file",
            "read_rejoin_count", "consume_rejoin_file"]
@@ -46,9 +49,12 @@ def _parse(argv: Optional[List[str]] = None):
     p.add_argument("--node_rank", "--rank", type=int, default=0)
     p.add_argument("--nproc_per_node", type=int, default=1,
                    help="1 = single-controller TPU (default); >1 = "
-                        "multi-process CPU simulation")
+                        "multi-process CPU simulation (needs "
+                        "JAX_PLATFORMS=cpu in the environment)")
     p.add_argument("--devices", "--gpus", default=None,
-                   help="visible device ids (exported as TPU_VISIBLE_DEVICES)")
+                   help="visible chip ids (exported as TPU_VISIBLE_DEVICES "
+                        "with the subset's bounds; several ids need "
+                        "TPU_CHIPS_PER_PROCESS_BOUNDS in the environment)")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--job_id", default="default")
     p.add_argument("--max_restart", "--elastic_level", type=int, default=0,
@@ -104,6 +110,26 @@ def _parse(argv: Optional[List[str]] = None):
     return p.parse_args(argv)
 
 
+def _visible_chips_env(devices: str) -> dict:
+    """Env that hands a child a SUBSET of the host's chips. Measured on a
+    2x2 v5e host with libtpu 0.0.34: ``TPU_VISIBLE_DEVICES`` (like
+    ``TPU_VISIBLE_CHIPS``) is honoured, but alone it fails TPU start-up
+    ("the number of devices found in the host does not match the
+    topology"); the subset's own bounds must ride along. One chip is
+    always ``1,1,1``; the shape of a larger subset depends on which chips
+    they are (``1,2,1`` for chips 0,1), so it comes from the caller."""
+    env = {"TPU_VISIBLE_DEVICES": devices, "TPU_PROCESS_BOUNDS": "1,1,1"}
+    if len(devices.split(",")) == 1:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    elif "TPU_CHIPS_PER_PROCESS_BOUNDS" not in os.environ:
+        raise RuntimeError(
+            f"--devices {devices}: libtpu refuses a subset of a host's "
+            f"chips without its bounds; export "
+            f"TPU_CHIPS_PER_PROCESS_BOUNDS for these chips (e.g. 1,2,1 "
+            f"for chips 0,1 of a 2x2 host)")
+    return env
+
+
 class _Proc:
     def __init__(self, rank: int, popen: subprocess.Popen, log_path: str):
         self.rank = rank
@@ -117,6 +143,16 @@ def _spawn(args, restart_round: int,
     os.makedirs(args.log_dir, exist_ok=True)
     nproc = nproc_override if nproc_override is not None \
         else args.nproc_per_node
+    if nproc > 1 and not cpu_requested():
+        # several processes per node is the CPU SIMULATION of a multi-host
+        # job; a chip belongs to one process, so on a TPU host the children
+        # would fight over it — and steering them to the CPU unasked is
+        # training on the CPU without saying so
+        raise RuntimeError(
+            f"--nproc_per_node {nproc}: more than one process per node is "
+            f"the multi-process CPU simulation and runs only where the "
+            f"caller's environment says JAX_PLATFORMS=cpu. On a TPU host "
+            f"one process drives every local chip (--nproc_per_node 1)")
     world = args.nnodes * nproc
     # fresh rendezvous every round: a restarted job must not collide with
     # stale state from the previous coordinator (SURVEY §5 elastic)
@@ -143,11 +179,7 @@ def _spawn(args, restart_round: int,
         env["PADDLE_PREEMPT_GRACE"] = str(
             getattr(args, "preempt_grace", 15.0))
         if args.devices is not None:
-            env["TPU_VISIBLE_DEVICES"] = args.devices
-        if world > 1 and nproc > 1:
-            # multi-process CPU simulation: children must not fight over the
-            # single local TPU
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env.update(_visible_chips_env(args.devices))
         log_path = os.path.join(args.log_dir, f"workerlog.{rank}")
         logf = open(log_path, "ab", buffering=0)
         logf.write(f"==== launch rank {rank} round {restart_round} "

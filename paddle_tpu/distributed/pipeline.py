@@ -42,9 +42,7 @@ from typing import Callable, List, Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ..core.jax_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor, _wrap_value

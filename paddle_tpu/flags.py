@@ -154,37 +154,6 @@ define_flag("FLAGS_emergency_ckpt_deadline_s", 10.0,
             "PADDLE_PREEMPT_GRACE is not set; must sit inside the "
             "infrastructure's kill grace.", float)
 
-
-def _wire_compile_cache(path) -> None:
-    """Persistent XLA compilation cache: executables survive process
-    restarts, cutting the multi-second recompile every training script and
-    bench section pays on startup (docs/PERFORMANCE.md). An empty path
-    disables the cache again (jax_compilation_cache_dir=None)."""
-    import jax
-    try:
-        if not path:
-            jax.config.update("jax_compilation_cache_dir", None)
-            return
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        # cache even fast compiles: the win is warm restarts, not dedup of
-        # slow compiles only
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # the cache is an optimization, never a hard failure
-
-
-define_flag("FLAGS_compile_cache_dir", "",
-            "Directory for the persistent XLA compilation cache "
-            "(jax_compilation_cache_dir). Empty = disabled. Settable from "
-            "the environment (FLAGS_compile_cache_dir=...) or at runtime "
-            "via paddle.set_flags (docs/PERFORMANCE.md).", str,
-            on_change=_wire_compile_cache)
-# define_flag applies env overrides without firing on_change — wire the
-# env-provided value now so `FLAGS_compile_cache_dir=... python train.py`
-# works with zero code changes
-_wire_compile_cache(flag("FLAGS_compile_cache_dir"))
-
 # ---------------------------------------------------------------------------
 # Run-health sentinel / recovery (paddle_tpu.health; docs/FAULT_TOLERANCE.md
 # "Runtime anomalies"). The FLAGS_health_ prefix is the generated-docs key.
@@ -296,15 +265,18 @@ define_flag("FLAGS_serving_preempt", True,
 
 define_flag("FLAGS_serving_paged_kernel", "auto",
             "Decode attention path for the paged serving engine "
-            "(ServingConfig.paged_kernel): 'auto' runs the Pallas "
+            "(ServingConfig.paged_kernel), resolved once at engine "
+            "construction from the platform: 'auto' is the Pallas "
             "flash-decoding paged-attention kernel on TPU (block tables "
             "consumed in-kernel via scalar prefetch — no dense gather of "
             "the KV blocks is ever materialized; GQA grouped in-kernel; "
             "int8 dequant fused into the block loads) and the XLA "
-            "gather + masked-softmax fallback elsewhere; 'on' forces the "
-            "kernel (interpret mode off-TPU — how tier-1 exercises the "
-            "real kernel path on CPU); 'off' forces the gather fallback "
-            "(the parity oracle).", str)
+            "gather + masked-softmax path on every other platform; 'on' "
+            "selects the kernel anywhere (interpret mode off-TPU — how "
+            "tier-1 exercises the real kernel body on CPU); 'off' selects "
+            "the gather path anywhere (the parity oracle). A kernel the "
+            "TPU compiler refuses raises at the engine's first dispatch; "
+            "nothing retries it through the gather path.", str)
 define_flag("FLAGS_serving_kv_quant", "",
             "Paged KV-cache quantization (ServingConfig.kv_quant): "
             "'int8' stores K/V blocks as int8 with per-token-per-head "

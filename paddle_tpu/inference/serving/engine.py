@@ -773,8 +773,8 @@ class ServingEngine:
             # dispatch code below this point is identical at every tp.
             # The sampler (sample_fn) touches neither params nor pool and
             # stays a plain jit on the replicated prefill logits.
+            from jax import shard_map
             from jax.sharding import PartitionSpec
-            from ...core.jax_compat import shard_map
             from ...models.llama import serving_param_specs
             ps = serving_param_specs(self._params, self._mesh)
             zs = G.paged_pool_specs(self.cache.pool, self._mesh)

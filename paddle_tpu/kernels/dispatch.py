@@ -1,22 +1,23 @@
 """One backend gate for every Pallas kernel in :mod:`paddle_tpu.kernels`.
 
-Before this module each kernel file carried its own copy of the backend
-check (a private ``_interpret()``), and the serving/model layers re-derived
-``jax.default_backend() == "tpu"`` wherever they chose between a kernel and
-its XLA fallback. Those copies could — and did — drift. This is now the ONE
-place the platform / flag / interpret-mode resolution lives:
+This is the ONE place the platform / knob / interpret-mode resolution
+lives; no kernel file and no caller re-derives ``jax.default_backend()``:
 
-* :func:`interpret` — whether ``pl.pallas_call`` should run in interpret
-  mode: kernels compile natively on TPU and run interpreted everywhere else,
-  so tier-1 (CPU) exercises the REAL kernel code paths.
-* :func:`on_tpu` — the raw platform predicate, for callers that pick an
-  entirely different implementation off-TPU (e.g. the weight-only matmul's
-  XLA dequant fallback).
+* :func:`on_tpu` — the raw platform predicate, for callers whose
+  implementation is decided by the platform (the weight-only matmul, the
+  functional attention entry).
+* :func:`interpret` — whether ``pl.pallas_call`` runs in interpret mode:
+  kernels compile natively on TPU and run interpreted everywhere else, so
+  tier-1 (CPU) exercises the REAL kernel bodies. On TPU it is False; a
+  kernel the TPU compiler refuses raises there — nothing retries it
+  interpreted or through its reference.
 * :func:`use_pallas` — resolve an on/off/auto knob (a ``FLAGS_*`` value or
-  config field) to a kernel-dispatch decision. ``"auto"`` means "kernel on
-  TPU, fallback elsewhere"; ``True``/``"on"`` forces the kernel (interpret
-  mode off-TPU — how tests pin the kernel path on CPU); ``False``/``None``/
-  ``"off"`` forces the fallback.
+  config field) to a dispatch decision, once, at construction. ``"auto"``
+  is the platform's own path: the kernel on TPU, the XLA-composed path
+  off it. ``True``/``"on"`` selects the kernel anywhere (interpret mode
+  off-TPU — how tests pin the kernel path on CPU); ``False``/``None``/
+  ``"off"`` selects the XLA-composed path anywhere. The choice is made
+  from the platform and never from whether the kernel compiled.
 """
 
 from __future__ import annotations
@@ -45,10 +46,10 @@ def interpret() -> bool:
 def use_pallas(knob: Any = "auto") -> bool:
     """Resolve a kernel on/off/auto knob to a dispatch decision.
 
-    ``True``/``"on"`` -> run the Pallas kernel (interpret mode off-TPU);
-    ``False``/``None``/``"off"``/``""`` -> run the XLA fallback;
-    ``"auto"`` -> kernel on TPU, fallback elsewhere. Unknown values raise
-    a structured error naming the options.
+    ``True``/``"on"`` -> the Pallas kernel (interpret mode off-TPU);
+    ``False``/``None``/``"off"``/``""`` -> the XLA-composed path;
+    ``"auto"`` -> :func:`on_tpu`. Unknown values raise a structured error
+    naming the options.
     """
     k = knob.strip().lower() if isinstance(knob, str) else knob
     if isinstance(k, str):

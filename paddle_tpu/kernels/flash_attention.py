@@ -22,10 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-try:  # pltpu imports fail on non-TPU builds only at kernel-feature use time
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import interpret as _interpret
 

@@ -23,10 +23,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import interpret as _interpret
 
@@ -81,8 +78,6 @@ def weight_only_matmul(x, w_q, scale, *, block_m: Optional[int] = None,
             w_q.astype(jnp.bfloat16) * scale.astype(jnp.bfloat16)[None, :])
         return out.astype(out_dtype).reshape(*lead, N)
 
-    if pltpu is None:
-        return xla_fallback()        # no VMEM scratch without pallas.tpu
     if M % bm or N % bn or K % bk:
         return xla_fallback()        # shape not blockable
     nk = K // bk

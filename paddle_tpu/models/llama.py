@@ -33,9 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ..core.jax_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["LlamaConfig", "init_params", "forward", "loss_fn", "param_specs",
@@ -334,6 +332,36 @@ def _rope(x, cos, sin, use_kernels):
     return x * c + rot * s
 
 
+def _flash_attention(q, k, v, segment_ids=None):
+    """The causal Pallas flash kernel. Traced under a multi-device mesh
+    (``with jax.set_mesh(mesh):`` around the jitted step) it runs as an
+    explicit per-shard region: GSPMD cannot partition a Mosaic kernel
+    ("wrap the call in a shard_map"), so batch rows split over the data
+    axes and heads over ``mp`` — the hybrid mesh's own axis names — and
+    every device runs the kernel on its slice. A dimension an axis does
+    not divide stays whole on every device."""
+    from ..kernels.flash_attention import flash_attention
+
+    def kernel(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(q, k, v, segment_ids)
+    B, H, Hk = q.shape[0], q.shape[2], k.shape[2]
+    data = tuple(a for a in ("dp", "sharding") if mesh.shape.get(a, 1) > 1)
+    if B % math.prod(mesh.shape[a] for a in data):
+        data = ()
+    mp = mesh.shape.get("mp", 1)
+    heads = "mp" if mp > 1 and H % mp == 0 and Hk % mp == 0 else None
+    spec = P(data or None, None, heads, None)
+    args, specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args, specs = args + (segment_ids,), specs + (P(data or None, None),)
+    return shard_map(kernel, in_specs=specs, out_specs=spec,
+                     check_vma=False)(*args)
+
+
 def _attention(q, k, v, cfg: LlamaConfig, segment_ids=None):
     """Causal self-attention on [B, S, H(k), D]; ``segment_ids [B, S]``
     confines attention within packed sequences (varlen)."""
@@ -347,7 +375,6 @@ def _attention(q, k, v, cfg: LlamaConfig, segment_ids=None):
         # Ulysses attention as an explicit shard_map region inside the
         # compiled program (composes with dp GSPMD; mp must be 1 here)
         from jax.sharding import PartitionSpec as P
-        from ..core.jax_compat import shard_map
         from ..distributed.context_parallel import (ring_flash_attention,
                                                     ulysses_attention)
         from ..distributed.topology import get_hybrid_communicate_group
@@ -366,9 +393,7 @@ def _attention(q, k, v, cfg: LlamaConfig, segment_ids=None):
             check_vma=False)
         return region(q, k, v)
     if cfg.use_kernels:
-        from ..kernels.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True,
-                               segment_ids=segment_ids)
+        return _flash_attention(q, k, v, segment_ids)
     B, S, H, D = q.shape
     Hk = k.shape[2]
     if Hk != H:  # GQA: expand kv heads
@@ -681,11 +706,26 @@ def loss_fn(params: Dict, input_ids, labels, cfg: LlamaConfig,
 # ---------------------------------------------------------------------------
 
 def _adamw_init(params, opt_dtype=jnp.float32):
-    zeros = jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, opt_dtype), params)
-    return {"m": zeros,
-            "v": jax.tree_util.tree_map(jnp.copy, zeros),
-            "step": jnp.zeros((), jnp.int32)}
+    """Zero AdamW state laid out like ``params``: each moment is allocated
+    where its parameter lives (a sharded parameter gets a sharded moment,
+    never a full copy on the default device) and the step counter is
+    replicated over the same devices — so the first train step sees the
+    layout every later step produces and the step compiles once."""
+    def place(p):
+        # tracers and uncommitted arrays carry no placement to follow
+        if isinstance(p, jax.core.Tracer) or not p.committed:
+            return None
+        return p.sharding
+
+    def zeros():
+        return jax.tree_util.tree_map(
+            lambda p: jnp.zeros(p.shape, opt_dtype, device=place(p)), params)
+
+    where = place(jax.tree_util.tree_leaves(params)[0])
+    if isinstance(where, NamedSharding):
+        where = NamedSharding(where.mesh, P())
+    return {"m": zeros(), "v": zeros(),
+            "step": jnp.zeros((), jnp.int32, device=where)}
 
 
 def _adamw_apply(params, grads, opt_state, *, lr, beta1, beta2, eps,

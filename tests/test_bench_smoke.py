@@ -10,7 +10,10 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 
 def _run_bench(*flags, timeout=420):
@@ -18,8 +21,7 @@ def _run_bench(*flags, timeout=420):
            "PATH": os.environ.get("PATH", "/usr/bin:/bin:/usr/local/bin"),
            "PYTHONPATH": REPO,
            "HOME": os.environ.get("HOME", "/tmp"),
-           "BENCH_BUDGET_S": "3600",   # never self-skip in the smoke run
-           "BENCH_CACHE_DIR": os.path.join(REPO, ".jax_cache")}
+           "BENCH_BUDGET_S": "3600"}   # never self-skip in the smoke run
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), *flags],
         capture_output=True, text=True, cwd=REPO, env=env, timeout=timeout)
@@ -36,6 +38,59 @@ def _run_bench(*flags, timeout=420):
         if isinstance(d, dict) and "metric" in d:
             metrics[d["metric"]] = d
     return metrics, proc
+
+
+@pytest.fixture
+def bench_main(monkeypatch, compile_cache_config_restored):
+    """bench.main() in this process (already on the CPU platform; the
+    compile-cache settings it switches on are restored afterwards)."""
+    import bench
+
+    def run(*argv):
+        monkeypatch.setattr(sys, "argv", ["bench.py", *argv])
+        bench.main()
+
+    return bench, run
+
+
+def test_a_raising_section_exits_nonzero(bench_main, monkeypatch, capsys):
+    """Sections stay isolated from each other, but a run in which one
+    raised is a failed run — and a failed headline prints no value."""
+    bench, run = bench_main
+
+    def boom(*a, **k):
+        raise RuntimeError("kernel refused")
+
+    monkeypatch.setattr(bench, "_llama_point", boom)
+    with pytest.raises(SystemExit) as exc:
+        run("--llama", "--steps", "1")
+    assert exc.value.code not in (0, None) and "llama" in str(exc.value.code)
+    out = capsys.readouterr()
+    assert "llama_train_mfu" not in out.out
+    assert "kernel refused" in out.err        # the traceback is printed
+
+
+def test_resolving_to_the_cpu_unasked_is_refused(bench_main, monkeypatch):
+    """The toy preset runs only where the environment said
+    JAX_PLATFORMS=cpu; a run that merely found no chip must not print
+    numbers under the chip's metric names."""
+    _, run = bench_main
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit) as exc:
+        run("--llama", "--steps", "1")
+    assert "JAX_PLATFORMS=cpu was not set" in str(exc.value.code)
+
+
+def test_unknown_device_kind_has_no_peak():
+    import bench
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v9 experimental"
+
+    with pytest.raises(RuntimeError, match="TPU v9 experimental"):
+        bench._peak_tflops(Dev())
+    Dev.device_kind = "TPU v5 lite"
+    assert bench._peak_tflops(Dev()) == 197.0
 
 
 def test_bench_llama_entry_point():

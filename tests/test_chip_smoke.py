@@ -1,0 +1,212 @@
+"""chip_smoke.py stays runnable without a chip (ISSUE 21).
+
+The script itself has no CPU mode and must refuse to start here; what
+tier-1 can hold is everything around the device: its stage functions at
+toy size on the virtual CPU mesh (kernels in Pallas interpret mode, the
+``tp=2`` and ``dp=2 x mp=2`` legs on the virtual devices), every Pallas
+kernel pushed through the TPU lowering at the chip's real shapes, and the
+compile-cache contract (``JAX_COMPILATION_CACHE_DIR`` survives ``import
+paddle_tpu``; unset, the one resolver answers ``<checkout>/.jax_cache``).
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import bench  # noqa: E402
+import chip_smoke as cs  # noqa: E402
+from paddle_tpu.inference.serving import ServingConfig  # noqa: E402
+from paddle_tpu.models.llama import LlamaConfig  # noqa: E402
+
+TOY = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
+                  num_hidden_layers=2, num_attention_heads=4,
+                  num_key_value_heads=4, max_position_embeddings=64,
+                  use_kernels=True, remat=True, dtype=jnp.bfloat16,
+                  param_dtype=jnp.float32)
+# the chip's ServingConfig() with every size shrunk; "on" because the
+# platform-resolved "auto" is the gather path off the chip
+TOY_SC = dict(block_size=4, max_slots=2, max_model_len=32, prefill_chunk=8,
+              paged_kernel="on")
+TOY_TRAFFIC = dict(lengths=(4, 9, 18, 26), prefix=8, new_tokens=(2, 4))
+
+
+@pytest.fixture(scope="module")
+def clock():
+    return cs.CompileClock()
+
+
+def _rows(capsys):
+    return {r["stage"]: r for r in map(json.loads,
+                                       capsys.readouterr().out.splitlines())}
+
+
+class TestStagesAtToySize:
+    # one kernel of each family through the whole compile-run-compare
+    # path (varlen flash covers the causal and segment gates; the int8
+    # GQA multi-query and the NaN-poisoned fp decode are the paged
+    # kernel's two extremes); the other cases only trace
+    RUN = ("flash_attention varlen fwd+bwd",
+           "paged_attention int8 pool 8/1 heads Q=8",
+           "paged_attention fp pool 4/4 heads Q=1",
+           "quant_matmul M=8", "rms_norm fwd+bwd", "apply_rope")
+
+    def test_kernel_rollcall(self, monkeypatch):
+        sc = ServingConfig(**TOY_SC)
+        cases = cs.kernel_cases(TOY, 2, 16, sc)
+        names = [c[0] for c in cases]
+        assert len(set(names)) == 14 and set(self.RUN) <= set(names)
+        for family, n in (("flash_attention", 3), ("paged_attention", 8),
+                          ("quant_matmul", 1), ("rms_norm", 1),
+                          ("apply_rope", 1)):
+            assert sum(k.startswith(family) for k in names) == n
+        for name, fn, ref, build in cases:
+            args = jax.eval_shape(build)
+            got, want = jax.eval_shape(fn, *args), jax.eval_shape(ref, *args)
+            assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), got) \
+                == jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), want)
+        monkeypatch.setattr(cs, "kernel_cases", lambda *a: [
+            c for c in cases if c[0] in self.RUN])
+        rows = cs.kernel_rollcall(TOY, 2, 16, sc, interpret=True)
+        assert [r["kernel"] for r in rows] == [n for n in names
+                                               if n in self.RUN]
+        assert all(r["err_over_tol"] <= 1.0 for r in rows)
+        with pytest.raises(AssertionError, match="interpret"):
+            cs.kernel_rollcall(TOY, 2, 16, sc, interpret=False)
+
+    def test_trainer_dp2_mp2(self, clock, tp_platform):
+        """The sharded leg: the flash kernel as a per-shard region under
+        the mesh, AdamW state laid out like its parameters — one
+        compilation, shards on all four devices."""
+        row = cs.trainer(clock, TOY, 4, 16, 3, dp=2, mp=2)
+        assert row["losses"][-1] < row["losses"][0]
+        assert row["param_shard_devices"] == [0, 1, 2, 3]
+        assert row["compilations_after_first_step"] == 0
+        assert row["donated"] is False          # the CPU has no donation
+
+    def test_servers_and_oracle_tp2(self, clock, capsys, tp_platform):
+        sc = ServingConfig(tp=2, **TOY_SC)
+        int8 = ServingConfig(kv_quant="int8", quantize="int8", **TOY_SC)
+        cs.server_stages(clock, TOY, sc, int8, label="_tp2", **TOY_TRAFFIC)
+        rows = _rows(capsys)
+        srv = rows["server_tp2"]
+        assert srv["platform"] == "cpu" and srv["device_count"] >= 2
+        assert srv["paged_kernel"] is True and srv["tp_degree"] == 2
+        assert srv["restarts"] == 0 and srv["blocks_in_use"] == 0
+        assert srv["mixed_dispatches"] >= 1 and srv["prefix_hit_tokens"] > 0
+        assert rows["server_int8_tp2"]["kv_quant"] == "int8"
+        oracle = rows["oracle_tp2"]
+        assert len(oracle["bf16_worst_gap"]) == 4
+        assert len(oracle["int8_worst_gap"]) == 1
+
+
+def test_oracle_catches_a_wrong_token():
+    """The tolerance must be tight enough to fail on garbage: teacher-force
+    a stream whose tokens were NOT chosen by the model."""
+    import numpy as np
+    from paddle_tpu.models import llama
+    params = llama.init_params(TOY, jax.random.PRNGKey(3))
+    prompt = np.arange(1, 9, dtype=np.int32)
+    with pytest.raises(AssertionError, match="logit oracle"):
+        cs.logit_oracle(params, TOY, [(prompt, [5, 6, 7, 8])], 1e-3)
+
+
+def test_refuses_to_start_without_a_chip():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, cwd=REPO, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr and "no CPU mode" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+class TestCompileCachePlacement:
+    def test_env_dir_survives_import(self, tmp_path):
+        """``import paddle_tpu`` neither sets nor clears the cache dir,
+        and the resolver yields to the environment."""
+        code = ("import jax, paddle_tpu\n"
+                "from paddle_tpu.jit import enable_compile_cache\n"
+                "print(jax.config.jax_compilation_cache_dir)\n"
+                "print(enable_compile_cache())\n"
+                "print(jax.config.jax_compilation_cache_dir)\n")
+        d = str(tmp_path / "cc")
+        proc = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            cwd=REPO, timeout=120,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO,
+                 "JAX_COMPILATION_CACHE_DIR": d})
+        assert proc.returncode == 0, proc.stderr
+        assert proc.stdout.split() == [d, d, d]
+
+    def test_unset_resolves_to_checkout(self, monkeypatch,
+                                        compile_cache_config_restored):
+        from paddle_tpu.jit import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(REPO, ".jax_cache")
+
+
+def _preset_cases(monkeypatch):
+    """Every roll-call kernel at the chip's real shapes, with the ONE
+    dispatch gate saying 'TPU' so nothing lowers in interpret mode."""
+    from paddle_tpu.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    assert dispatch.interpret() is False
+    cfg, batch, seq = bench._presets("tpu")
+    return cs.kernel_cases(cfg, batch, seq, ServingConfig())
+
+
+def test_every_kernel_lowers_for_tpu(monkeypatch):
+    """The check that found ISSUE 21's refused paged-attention BlockSpecs
+    from a machine with no chip: the Pallas TPU lowering runs in
+    ``jax.export`` and rejects a tile the compiler cannot take."""
+    for name, fn, _ref, build in _preset_cases(monkeypatch):
+        exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+            *jax.eval_shape(build))
+        assert "tpu_custom_call" in exported.mlir_module(), name
+
+
+@pytest.mark.slow
+def test_every_kernel_compiles_for_v5e_without_a_chip(monkeypatch):
+    """Stronger than the lowering guard, and slower (~1 min): libtpu can
+    describe a v5e topology with no chip attached, and compiling against
+    it runs the whole Mosaic pipeline — vector layouts, VMEM limits —
+    exactly as the chip's compiler will."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:                    # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no TPU topology without a chip: {e}")
+    where = SingleDeviceSharding(topo.devices[0])
+    # the chip runs at jax's own default matmul precision, not conftest's
+    # "highest" — under which the flash backward kernel's 1024x1024 tiles
+    # outgrow the 16 MiB scoped-VMEM default (17.4 MiB asked)
+    with jax.default_matmul_precision("default"):
+        for name, fn, _ref, build in _preset_cases(monkeypatch):
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=where),
+                jax.eval_shape(build))
+            jax.jit(fn).lower(*args).compile()
+
+
+def test_preset_is_spelled_once():
+    """chip_smoke.py takes the 738M preset from bench.py, not a copy."""
+    src = open(os.path.join(REPO, "chip_smoke.py")).read()
+    assert 'bench._presets("tpu")' in src
+    assert "intermediate_size" not in src and "5504" not in src
+    cfg, batch, seq = bench._presets("tpu")
+    assert (cfg.hidden_size, cfg.num_hidden_layers, batch, seq) == \
+        (2048, 12, 8, 2048)
+    assert cfg.use_kernels and cfg.remat

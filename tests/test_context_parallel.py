@@ -59,7 +59,7 @@ class TestRingAttention:
         hcg = sep_mesh
 
         def ring_loss(qv, kv, vv):
-            from paddle_tpu.core.jax_compat import shard_map
+            from jax import shard_map
             f = shard_map.__wrapped__ if hasattr(shard_map, "__wrapped__") \
                 else shard_map
             sm = f(lambda a, b, c: ring_flash_attention(
@@ -93,7 +93,7 @@ class TestUlysses:
                                    atol=2e-5, rtol=2e-5)
 
     def test_head_divisibility_check(self, sep_mesh):
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         q, k, v = _qkv(H=2)  # 2 heads, sep=4 -> error
         sm = shard_map(
             lambda a, b, c: ulysses_attention(a, b, c, "sep", False, False),

@@ -192,7 +192,7 @@ class TestGroupSharded:
 
 class TestInGraphCollectives:
     def test_psum_inside_shard_map(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         fleet.init()

@@ -21,6 +21,34 @@ def _args(tmp_path, script, *extra):
     return _parse([*extra, "--log_dir", str(tmp_path / "log"), script])
 
 
+class TestChipOwnership:
+    """One process per chip (ISSUE 21): the launcher never steers
+    children to the CPU unasked, and a chip subset carries the bounds
+    libtpu needs."""
+
+    def test_cpu_simulation_must_be_asked_for(self, tmp_path, monkeypatch):
+        script = _script(tmp_path, "print('never runs')")
+        monkeypatch.delenv("JAX_PLATFORMS")
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            launch_procs(_args(tmp_path, script, "--nproc_per_node", "2"))
+        assert not (tmp_path / "log" / "workerlog.0").exists()
+
+    def test_devices_env(self, tmp_path, monkeypatch):
+        script = _script(tmp_path, """
+            import os
+            print({k: v for k, v in os.environ.items() if k.startswith("TPU_")})
+        """)
+        assert launch_procs(_args(tmp_path, script, "--devices", "2")) == 0
+        log = (tmp_path / "log" / "workerlog.0").read_text()
+        for kv in ("'TPU_VISIBLE_DEVICES': '2'",
+                   "'TPU_CHIPS_PER_PROCESS_BOUNDS': '1,1,1'",
+                   "'TPU_PROCESS_BOUNDS': '1,1,1'"):
+            assert kv in log
+        monkeypatch.delenv("TPU_CHIPS_PER_PROCESS_BOUNDS", raising=False)
+        with pytest.raises(RuntimeError, match="bounds"):
+            launch_procs(_args(tmp_path, script, "--devices", "0,1"))
+
+
 class TestLaunch:
     def test_single_proc_env_and_log(self, tmp_path):
         script = _script(tmp_path, """

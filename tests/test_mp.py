@@ -11,7 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax import lax
-from paddle_tpu.core.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle

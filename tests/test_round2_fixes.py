@@ -144,7 +144,7 @@ class TestFleetDegreeNormalization:
         assert hcg.get_stage_id() == 0
 
     def test_rank_inside_shard_region(self, reset_hcg):
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         set_hybrid_communicate_group(HybridCommunicateGroup(dp=8))
         hcg = fleet.get_hybrid_communicate_group()

@@ -403,6 +403,17 @@ class TestUtilsDevice:
         assert not device.cuda.is_available()
         assert device.cuda.device_count() == 0
 
+    def test_tpu_place_without_a_chip_is_an_error(self):
+        """A TPUPlace never resolves to a CPU device (ISSUE 21)."""
+        from paddle_tpu import device
+        from paddle_tpu.core import place
+        with pytest.raises(RuntimeError, match="no accelerator"):
+            place.get_jax_device(paddle.TPUPlace(0))
+        with pytest.raises(RuntimeError, match="no accelerator"):
+            paddle.to_tensor([1.0], place=paddle.TPUPlace(0))
+        assert place.get_jax_device(paddle.CPUPlace()).platform == "cpu"
+        device.synchronize()                  # a barrier, on any backend
+
     def test_static_shim(self):
         from paddle_tpu import static
         assert static.InputSpec([None, 8]).shape == [None, 8]

@@ -452,21 +452,6 @@ class TestPrefetch:
             paddle.set_flags({"FLAGS_profile_annotations": False})
 
 
-class TestCompileCacheFlag:
-    def test_flag_wires_jax_config(self, tmp_path):
-        import jax
-        d = str(tmp_path / "xla_cache")
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            paddle.set_flags({"FLAGS_compile_cache_dir": d})
-            assert jax.config.jax_compilation_cache_dir == d
-            # empty path DISABLES the cache again (not a silent no-op)
-            paddle.set_flags({"FLAGS_compile_cache_dir": ""})
-            assert jax.config.jax_compilation_cache_dir is None
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-
-
 class TestHapiJit:
     def test_model_fit_jit_matches_eager(self):
         """Model.prepare(jit=True): fused path trains through fit() and
