@@ -514,8 +514,3 @@ define_flag("FLAGS_serving_lora_pool", 16,
             "structured error naming this flag. Must be >= "
             "FLAGS_serving_lora_slots.", int)
 
-define_flag("FLAGS_profile_annotations", False,
-            "Emit jax.profiler.TraceAnnotation spans ('data', 'h2d', 'step', "
-            "'ckpt') around the input pipeline, the fused train step, and "
-            "checkpoint writes so XPlane traces attribute host time "
-            "(profiler.annotate; docs/PERFORMANCE.md).", bool)

@@ -96,6 +96,7 @@ import numpy as np
 
 from ...flags import flag
 from ...health import watchdog as _watchdog
+from ...profiler import SpanStats, histogram_percentile
 from .offload import block_crc as _block_crc
 from .paged_cache import PagedKVCache
 from .policies import resolve_policy
@@ -169,6 +170,30 @@ HEALTH_SNAPSHOT_FIELDS = {
                         "dispatched) — the prefill-stall this splits out "
                         "is exactly what mixed batching removes, so "
                         "operators can watch it",
+    "phase_ms_per_step": "where the engine thread's time goes: mean host "
+                         "milliseconds per engine step in each serve:* "
+                         "phase since start (idle / cmds / route / deliver "
+                         "from the server's pump, supervise from the "
+                         "supervisor, plan / operands / dispatch / fetch / "
+                         "commit / journal from the engine; fetch is the "
+                         "device's time as the host sees it). The same "
+                         "spans are in a profiler trace when one is taken; "
+                         "the cumulative totals, counters and histograms "
+                         "behind this row ride stats()['spans']",
+    "request_wait": "per-request waits from cumulative histograms: "
+                    "queue_wait_p50_s / queue_wait_p99_s (front-line "
+                    "enqueue -> first admission, the server's command "
+                    "queue included) and prefill_p50_s / prefill_p99_s "
+                    "(admission -> first token); null before the first "
+                    "sample",
+    "real_lane_pct": "how much of the padded dispatch shapes carries a "
+                     "real token, lifetime, in percent: mixed (sum of "
+                     "q_len over active rows against max_slots x Q lanes "
+                     "of the mixed step) and prefill (sum of prompt "
+                     "lengths against batch bucket x length bucket of the "
+                     "batched prefill); null until that kind has "
+                     "dispatched. A low share is compute spent on pad "
+                     "lanes: the case for a packed step or finer buckets",
     "offload": "host-RAM KV offload tier (FLAGS_serving_offload; ISSUE "
                "16): enabled + the tier's capacity / blocks (host-"
                "resident now) / swap_outs / swap_ins / tier_hits / "
@@ -207,6 +232,17 @@ HEALTH_SNAPSHOT_FIELDS = {
 # snapshot fields only the EngineSupervisor adds; the engine-level payload
 # is HEALTH_SNAPSHOT_FIELDS minus these (the shape test pins both layers)
 SUPERVISOR_SNAPSHOT_KEYS = ("supervisor", "autoscale")
+
+
+def _named(name: str, fn):
+    """``fn`` under a ``__name__``: jax names a jitted program (the HLO
+    module, the trace's module line) after its function, and a
+    ``functools.partial`` or a ``shard_map`` wrapper has none
+    (``jit__unknown``)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class AdoptError(RuntimeError):
@@ -438,7 +474,8 @@ class ServingEngine:
     def __init__(self, params, model_config, serving_config:
                  Optional[ServingConfig] = None, gen_config=None,
                  programs: Optional[EnginePrograms] = None,
-                 journal=None, embed_model=None):
+                 journal=None, embed_model=None,
+                 spans: Optional[SpanStats] = None):
         import jax
 
         from ...models.generation import GenerationConfig, validate_sampling
@@ -602,6 +639,16 @@ class ServingEngine:
         self._dispatch_ms = {k: collections.deque(maxlen=512)
                              for k in ("prefill", "decode", "mixed",
                                        "spec")}
+        # where the pump thread's time goes, always on: every phase of a
+        # step is one flat ``serve:*`` span (profiler.annotate, so it is
+        # in the trace when one is taken) summed here with the lane and
+        # iteration counters and the per-request wait histograms.
+        # Cumulative, never reset: stats()["spans"] read twice subtracts
+        # to a window. The supervisor and the server's pump write their
+        # phases into THIS aggregator, and a supervisor rebuild hands it
+        # to the next engine, so the totals survive a restart.
+        self.spans = spans if spans is not None else SpanStats()
+        self.step_no = 0          # the ``step=`` argument of every span
 
     # ---- compiled programs ------------------------------------------------
 
@@ -807,12 +854,17 @@ class ServingEngine:
                                  in_specs=(ps, zs) + (R,) * 10 + ls,
                                  out_specs=(zs, R), check_vma=False)
         donate = donation_supported()
-        jpre = jax.jit(prefill_fn, donate_argnums=(4,) if donate else ())
-        jchk = jax.jit(chunk_fn, donate_argnums=(5,) if donate else ())
-        jdec = jax.jit(decode_fn, donate_argnums=(1,) if donate else ())
-        jspec = jax.jit(spec_fn, donate_argnums=(1,) if donate else ())
-        jmix = jax.jit(mixed_fn, donate_argnums=(1,) if donate else ())
-        jsamp = jax.jit(sample_fn)
+
+        def jit(name, fn, *donated):
+            return jax.jit(_named(name, fn),
+                           donate_argnums=donated if donate else ())
+
+        jpre = jit("paged_prefill", prefill_fn, 4)
+        jchk = jit("paged_chunk", chunk_fn, 5)
+        jdec = jit("paged_decode", decode_fn, 1)
+        jspec = jit("paged_spec", spec_fn, 1)
+        jmix = jit("paged_mixed", mixed_fn, 1)
+        jsamp = jit("sample_tokens", sample_fn)
         return jpre, jchk, jdec, jspec, jsamp, jmix
 
     def _build_embed(self, jax):
@@ -824,11 +876,11 @@ class ServingEngine:
         from ...models.bert import bert_encode
         ecfg, stats = self._embed_cfg, self._stats
 
-        def embed_fn(params, ids, lengths):
+        def paged_embed(params, ids, lengths):
             stats["embed_traces"] += 1             # trace-time only
             return bert_encode(params, ecfg, ids, lengths)
 
-        return jax.jit(embed_fn)
+        return jax.jit(paged_embed)
 
     def _lora_operand(self, ids) -> tuple:
         """The trailing LoRA dispatch operand: per-row adapter pool slots
@@ -847,8 +899,14 @@ class ServingEngine:
             b *= 2
         return b
 
-    def _record_dispatch(self, kind: str, t0: float) -> None:
-        """Count + time ONE device dispatch by kind (ISSUE 20). Every
+    def _span(self, name: str, kind: Optional[str] = None):
+        """One flat ``serve:*`` phase span of the current step."""
+        return self.spans.span(name, kind, step=self.step_no)
+
+    def _record_dispatch(self, kind: str, t0: float, t1: float) -> None:
+        """Count + time ONE device dispatch by kind (ISSUE 20), from the
+        ``serve:dispatch`` span's start to the ``serve:fetch`` span's
+        end (the same two ``perf_counter`` stamps, no second pair). Every
         dispatch — batched prefill, prefill chunk, embed encode, decode
         loop, mixed step, spec verify — lands here, so ``chunks`` is the
         true all-kinds dispatch total (it previously only counted
@@ -858,7 +916,7 @@ class ServingEngine:
         dispatch-latency rows in stats()/health_snapshot()."""
         self._stats["chunks"] += 1
         self._stats[kind + "_dispatches"] += 1
-        self._dispatch_ms[kind].append((time.time() - t0) * 1e3)
+        self._dispatch_ms[kind].append((t1 - t0) * 1e3)
 
     def _dispatch_latency(self) -> Dict[str, Dict[str, float]]:
         """p50/p99 dispatch wall time per kind over the recent window —
@@ -885,7 +943,8 @@ class ServingEngine:
                tenant: Optional[str] = None, priority: int = 0,
                temperature: Any = "unset", top_k: Any = "unset",
                top_p: Any = "unset", seed: Any = "unset",
-               adapter_id: Optional[str] = None) -> int:
+               adapter_id: Optional[str] = None,
+               enqueue_t: Optional[float] = None) -> int:
         """Queue one prompt; returns the request id. ``eos_token_id``
         defaults to the engine's GenerationConfig (pass ``None`` explicitly
         to disable EOS for this request).
@@ -917,6 +976,11 @@ class ServingEngine:
         for the request's whole lifetime (preemption included), so its
         weights can never be evicted mid-stream.
 
+        ``enqueue_t`` (``time.time()``) is when a front line first saw
+        the request, if one stands before this call: the ``queue_wait_s``
+        histogram counts from it, so the wait in the server's command
+        queue is part of the number.
+
         Raises :class:`ServingQueueFull` — carrying ``queue_depth`` /
         ``live_slots`` / ``retry_after_s`` for the caller's backoff — when
         the bounded admission queue is full: the submit is SHED, not
@@ -930,6 +994,7 @@ class ServingEngine:
                                  temperature=temperature, top_k=top_k,
                                  top_p=top_p, seed=seed,
                                  adapter_id=adapter_id)
+        req.enqueue_t = enqueue_t
         with self._lock:
             rid = self._sched.submit(req)
             self._journal_submit(req)
@@ -992,7 +1057,8 @@ class ServingEngine:
                  temperature: Any = "unset", top_k: Any = "unset",
                  top_p: Any = "unset", seed: Any = "unset",
                  jid: Optional[int] = None,
-                 adapter_id: Optional[str] = None) -> int:
+                 adapter_id: Optional[str] = None,
+                 enqueue_t: Optional[float] = None) -> int:
         """Re-queue a request recovered from a torn-down engine with the
         tokens it had already emitted — the supervisor's restart path.
         Rides the preemption-recompute machinery: prefill recomputes KV
@@ -1018,6 +1084,7 @@ class ServingEngine:
                                  temperature=temperature, top_k=top_k,
                                  top_p=top_p, seed=seed,
                                  adapter_id=adapter_id)
+        req.enqueue_t = enqueue_t
         if req.finished:
             raise ValueError(
                 f"request is already finished ({len(req.tokens)} tokens of "
@@ -1622,6 +1689,8 @@ class ServingEngine:
     def _emit_first(self, req: Request, tok0: int, now: float,
                     emitted: Dict[int, List[int]]) -> None:
         req.first_token_t = now
+        if req.admit_t is not None:
+            self.spans.observe("prefill_s", now - req.admit_t)
         req.tokens.append(tok0)
         emitted.setdefault(req.rid, []).append(tok0)
         if req.eos_token_id is not None and tok0 == req.eos_token_id:
@@ -1631,34 +1700,44 @@ class ServingEngine:
         else:
             self._start_decode(req)
 
-    def _admit(self, emitted: Dict[int, List[int]]) -> None:
-        import jax.numpy as jnp
-        self._admit_embeds()
+    def _plan_admissions(self) -> List[Tuple[int, List[Request]]]:
+        """Admit every request the policy and the pool allow, then split
+        the wave: COLD short prompts take the batched bucketed prefill
+        (returned here as ``(length bucket, requests)`` waves — one
+        dispatch per power-of-2 length bucket, batch dim padded to the
+        wave-size bucket); prefix-cache hits (prefill starts at an
+        offset), long prompts (chunked), and readmissions (recompute) go
+        through the offset chunk path / the mixed step, one row each.
+        A request's FIRST admission lands its queue wait (enqueue ->
+        admit, the wait in the server's command queue included) in the
+        ``queue_wait_s`` histogram."""
         gate = self._lora_gate if self._lora is not None else None
         admitted: List[Request] = []
         while (req := self._sched.next_admission(gate=gate)) is not None:
             admitted.append(req)
-        if not admitted:
-            return
-        # split the wave: COLD short prompts take the batched bucketed
-        # prefill (one dispatch per power-of-2 length bucket, batch dim
-        # padded to the wave-size bucket); prefix-cache hits (prefill
-        # starts at an offset), long prompts (chunked), and readmissions
-        # (recompute) go through the offset chunk path, one row at a time
+            if not req.preemptions and not req.tokens:
+                self.spans.observe("queue_wait_s",
+                                   req.admit_t - req.enqueue_t)
         chunk = self.config.prefill_chunk
-        fast = [r for r in admitted
-                if r.num_computed == 0 and not r.tokens
-                and (chunk is None or r.prompt_len <= chunk)]
-        M = self.config.max_slots
         by_bucket: Dict[int, List[Request]] = {}
-        for req in fast:
-            by_bucket.setdefault(self._bucket(req.prompt_len), []).append(req)
-        for Sb, group in sorted(by_bucket.items()):
+        for req in admitted:
+            if req.num_computed == 0 and not req.tokens \
+                    and (chunk is None or req.prompt_len <= chunk):
+                by_bucket.setdefault(self._bucket(req.prompt_len),
+                                     []).append(req)
+        return sorted(by_bucket.items())
+
+    def _prefill_dispatch(self, Sb: int, group: List[Request],
+                          emitted: Dict[int, List[int]]) -> None:
+        """ONE batched bucketed prefill over a wave of cold short
+        prompts; every request in it emits its first token."""
+        import jax.numpy as jnp
+        with self._span("serve:operands", "prefill"):
             self._prefill_buckets.add(Sb)
             Bb = 1
             while Bb < len(group):
                 Bb *= 2
-            Bb = min(Bb, M)
+            Bb = min(Bb, self.config.max_slots)
             ids = np.zeros((Bb, Sb), np.int32)
             plens = np.ones((Bb,), np.int32)      # pad rows: harmless len 1
             tables = np.zeros((Bb, self.cache.blocks_per_seq), np.int32)
@@ -1670,14 +1749,19 @@ class ServingEngine:
                 tables[r] = self.cache.tables[req.slot]
                 act[r] = True
                 aids[r] = req.adapter_slot
-            t0 = time.time()
-            with _watchdog.section("serving.prefill"):
+            ops = (jnp.asarray(ids), jnp.asarray(plens), jnp.asarray(tables))
+            tail = (jnp.asarray(act), *self._lora_operand(aids))
+        with _watchdog.section("serving.prefill"):
+            with self._span("serve:dispatch", "prefill") as d:
                 logits, self.cache.pool, _ = self._jprefill(
-                    self._params, jnp.asarray(ids), jnp.asarray(plens),
-                    jnp.asarray(tables), self.cache.pool, jnp.asarray(act),
-                    *self._lora_operand(aids))
+                    self._params, *ops, self.cache.pool, *tail)
+            with self._span("serve:fetch", "prefill") as f:
                 first = self._first_tokens(logits, group, Bb)
-            self._record_dispatch("prefill", t0)
+        with self._span("serve:commit", "prefill"):
+            self._record_dispatch("prefill", d.t0, f.t1)
+            self.spans.count("prefill_lanes_real",
+                             sum(r.prompt_len for r in group))
+            self.spans.count("prefill_lanes_total", Bb * Sb)
             now = time.time()
             for r, req in enumerate(group):
                 req.num_computed = req.prompt_len
@@ -1685,27 +1769,26 @@ class ServingEngine:
                     req.prompt, req.blocks, req.prompt_len, req.reg_state,
                     tenant=req.tenant, namespace=req.adapter_id)
                 self._emit_first(req, int(first[r]), now, emitted)
-        # chunked/offset admissions advance via _advance_prefills
 
-    def _admit_embeds(self) -> None:
-        """Drain every queued embedding request (ISSUE 19) through the
-        batched encoder: one jitted ``bert_encode`` dispatch per
-        power-of-2 ``(batch, length)`` bucket, exactly the batched-
-        bucketed-prefill shape discipline. The whole batch admits,
-        encodes and FINISHES inside this locked step — embeds hold no
-        decode slot and no KV blocks, so no observer ever sees one
-        mid-flight."""
+    def _plan_embeds(self) -> List[Tuple[int, List[Request]]]:
+        """Take every queued embedding request (ISSUE 19) off the queue,
+        grouped into power-of-2 length buckets — exactly the batched-
+        bucketed-prefill shape discipline."""
         if self._embed_params is None:
-            return
-        import jax.numpy as jnp
-        group = self._sched.admit_embeds()
-        if not group:
-            return
+            return []
         by_bucket: Dict[int, List[Request]] = {}
-        for req in group:
+        for req in self._sched.admit_embeds():
             by_bucket.setdefault(self._bucket(req.prompt_len),
                                  []).append(req)
-        for Sb, grp in sorted(by_bucket.items()):
+        return sorted(by_bucket.items())
+
+    def _embed_dispatch(self, Sb: int, grp: List[Request]) -> None:
+        """One jitted ``bert_encode`` dispatch for one bucket of
+        embedding requests. The whole batch admits, encodes and FINISHES
+        inside one locked step — embeds hold no decode slot and no KV
+        blocks, so no observer ever sees one mid-flight."""
+        import jax.numpy as jnp
+        with self._span("serve:operands", "embed"):
             Bb = 1
             while Bb < len(grp):
                 Bb *= 2
@@ -1714,12 +1797,14 @@ class ServingEngine:
             for r, req in enumerate(grp):
                 ids[r, :req.prompt_len] = req.prompt
                 lens[r] = req.prompt_len
-            t0 = time.time()
-            with _watchdog.section("serving.prefill"):
-                pooled = np.asarray(self._jembed(
-                    self._embed_params, jnp.asarray(ids),
-                    jnp.asarray(lens)))
-            self._record_dispatch("prefill", t0)
+            ops = (jnp.asarray(ids), jnp.asarray(lens))
+        with _watchdog.section("serving.prefill"):
+            with self._span("serve:dispatch", "embed") as d:
+                pooled = self._jembed(self._embed_params, *ops)
+            with self._span("serve:fetch", "embed") as f:
+                pooled = np.asarray(pooled)
+        with self._span("serve:commit", "embed"):
+            self._record_dispatch("prefill", d.t0, f.t1)
             now = time.time()
             for r, req in enumerate(grp):
                 req.embedding = pooled[r]
@@ -1736,36 +1821,43 @@ class ServingEngine:
         import jax.numpy as jnp
         chunk = self.config.prefill_chunk
         for req in [r for r in self._sched.live if r.prefilling]:
-            total = len(req.prefill_ids)
-            n = total - req.num_computed
-            if chunk is not None:
-                n = min(n, chunk)
-            Sb = self._bucket(n)
-            ids = np.zeros((1, Sb), np.int32)
-            ids[0, :n] = req.prefill_ids[req.num_computed:
-                                         req.num_computed + n]
-            t0 = time.time()
+            with self._span("serve:operands", "chunk"):
+                total = len(req.prefill_ids)
+                n = total - req.num_computed
+                if chunk is not None:
+                    n = min(n, chunk)
+                Sb = self._bucket(n)
+                ids = np.zeros((1, Sb), np.int32)
+                ids[0, :n] = req.prefill_ids[req.num_computed:
+                                             req.num_computed + n]
+                ops = (jnp.asarray(ids),
+                       jnp.asarray(req.num_computed, jnp.int32),
+                       jnp.asarray(n, jnp.int32),
+                       jnp.asarray(self.cache.tables[req.slot][None]))
+                lora = self._lora_operand([req.adapter_slot])
+            # only the chunk that completes a FRESH prompt has a token
+            # to fetch; the others leave their dispatch in flight
+            last = req.num_computed + n >= total and not req.tokens
             with _watchdog.section("serving.prefill"):
-                logits, self.cache.pool, _ = self._jchunk(
-                    self._params, jnp.asarray(ids),
-                    jnp.asarray(req.num_computed, jnp.int32),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(self.cache.tables[req.slot][None]),
-                    self.cache.pool,
-                    *self._lora_operand([req.adapter_slot]))
-            self._record_dispatch("prefill", t0)
-            req.num_computed += n
-            req.reg_state = self.cache.register_prefix(
-                req.prefill_ids, req.blocks, req.num_computed,
-                req.reg_state, tenant=req.tenant,
-                namespace=req.adapter_id)
-            if req.prefilling:
-                continue                          # more chunks to go
-            if req.tokens:                        # readmission: resume
-                self._start_decode(req)
-            else:
-                tok0 = int(self._first_tokens(logits, [req], 1)[0])
-                self._emit_first(req, tok0, time.time(), emitted)
+                with self._span("serve:dispatch", "chunk") as d:
+                    logits, self.cache.pool, _ = self._jchunk(
+                        self._params, *ops, self.cache.pool, *lora)
+                with self._span("serve:fetch", "chunk") as f:
+                    tok0 = (int(self._first_tokens(logits, [req], 1)[0])
+                            if last else None)
+            with self._span("serve:commit", "chunk"):
+                self._record_dispatch("prefill", d.t0, f.t1)
+                req.num_computed += n
+                req.reg_state = self.cache.register_prefix(
+                    req.prefill_ids, req.blocks, req.num_computed,
+                    req.reg_state, tenant=req.tenant,
+                    namespace=req.adapter_id)
+                if req.prefilling:
+                    continue                      # more chunks to go
+                if req.tokens:                    # readmission: resume
+                    self._start_decode(req)
+                else:
+                    self._emit_first(req, tok0, time.time(), emitted)
 
     def _first_tokens(self, logits, group, Bb: int) -> np.ndarray:
         """Sample each admitted request's FIRST token (sample index 0)
@@ -2014,62 +2106,61 @@ class ServingEngine:
         freshly-filled prefix blocks and roll back the rejected tail's
         surplus blocks."""
         import jax.numpy as jnp
-        Q = self._spec_k + 1
-        M = self.config.max_slots
-        toks = np.zeros((M, Q), np.int32)
-        dl = np.zeros((M,), np.int32)
-        for req in decoding:
-            m = req.slot
-            d = drafts.get(req.rid, [])
-            toks[m, 0] = self._tokens[m]
-            toks[m, 1:1 + len(d)] = d
-            toks[m, 1 + len(d):] = self._tokens[m]   # pad: a real token
-            dl[m] = len(d)
-        t0 = time.time()
+        with self._span("serve:operands", "spec"):
+            Q = self._spec_k + 1
+            M = self.config.max_slots
+            toks = np.zeros((M, Q), np.int32)
+            dl = np.zeros((M,), np.int32)
+            for req in decoding:
+                m = req.slot
+                d = drafts.get(req.rid, [])
+                toks[m, 0] = self._tokens[m]
+                toks[m, 1:1 + len(d)] = d
+                toks[m, 1 + len(d):] = self._tokens[m]   # pad: a real token
+                dl[m] = len(d)
+            ops = (jnp.asarray(toks),
+                   jnp.asarray(self._seq_lens), jnp.asarray(dl),
+                   jnp.asarray(self._steps_left), jnp.asarray(self._done),
+                   jnp.asarray(self.cache.tables), jnp.asarray(self._keys),
+                   jnp.asarray(self._sample_idx), jnp.asarray(self._temp),
+                   jnp.asarray(self._topk), jnp.asarray(self._topp),
+                   *self._lora_operand(self._adapters))
         with _watchdog.section("serving.decode"):
-            self.cache.pool, cand, acc = self._jspec(
-                self._params, self.cache.pool, jnp.asarray(toks),
-                jnp.asarray(self._seq_lens), jnp.asarray(dl),
-                jnp.asarray(self._steps_left), jnp.asarray(self._done),
-                jnp.asarray(self.cache.tables), jnp.asarray(self._keys),
-                jnp.asarray(self._sample_idx), jnp.asarray(self._temp),
-                jnp.asarray(self._topk), jnp.asarray(self._topp),
-                *self._lora_operand(self._adapters))
-            cand = np.asarray(cand)
-            acc = np.asarray(acc)
-        self._record_dispatch("spec", t0)
-        for req in decoding:
-            m = req.slot
-            if self._done[m] or self._steps_left[m] <= 0:
-                continue
-            got = [int(t) for t in cand[m, :int(acc[m]) + 1]]
-            eos = req.eos_token_id
-            if eos is not None and eos in got:
-                got = got[:got.index(eos) + 1]
-                self._done[m] = True
-                req.eos_seen = True
-            e = len(got)
-            req.tokens.extend(got)
-            emitted.setdefault(req.rid, []).extend(got)
-            req.spec_drafted += int(dl[m])
-            req.spec_accepted += e - 1
-            self._sched.spec_drafted += int(dl[m])
-            self._sched.spec_accepted += e - 1
-            self._tokens[m] = got[-1]
-            self._seq_lens[m] += e
-            self._steps_left[m] -= e
-            self._sample_idx[m] = len(req.tokens)
-            sl = int(self._seq_lens[m])
-            base = req.reg_state[0] * self.config.block_size
-            if self.config.prefix_cache and \
-                    sl // self.config.block_size > req.reg_state[0]:
-                req.reg_state = self.cache.register_prefix(
-                    self._chain_ids(req, base, sl), req.blocks, sl,
-                    req.reg_state, base=base, tenant=req.tenant,
-                    namespace=req.adapter_id)
-            if not req.finished:
-                self._rollback_blocks(req)
-        self._stats["spec_steps"] += 1
+            with self._span("serve:dispatch", "spec") as d:
+                self.cache.pool, cand, acc = self._jspec(
+                    self._params, self.cache.pool, *ops)
+                del ops
+            with self._span("serve:fetch", "spec") as f:
+                cand = np.asarray(cand)
+                acc = np.asarray(acc)
+        with self._span("serve:commit", "spec"):
+            self._record_dispatch("spec", d.t0, f.t1)
+            for req in decoding:
+                m = req.slot
+                if self._done[m] or self._steps_left[m] <= 0:
+                    continue
+                got = [int(t) for t in cand[m, :int(acc[m]) + 1]]
+                eos = req.eos_token_id
+                if eos is not None and eos in got:
+                    got = got[:got.index(eos) + 1]
+                    self._done[m] = True
+                    req.eos_seen = True
+                e = len(got)
+                req.tokens.extend(got)
+                emitted.setdefault(req.rid, []).extend(got)
+                req.spec_drafted += int(dl[m])
+                req.spec_accepted += e - 1
+                self._sched.spec_drafted += int(dl[m])
+                self._sched.spec_accepted += e - 1
+                self._tokens[m] = got[-1]
+                self._seq_lens[m] += e
+                self._steps_left[m] -= e
+                self._sample_idx[m] = len(req.tokens)
+                self._register_filled(req)
+                if not req.finished:
+                    self._rollback_blocks(req)
+            self._stats["spec_steps"] += 1
+            self._sched.retire_finished()
 
     # ---- mixed batching (ISSUE 20) ----------------------------------------
 
@@ -2091,137 +2182,182 @@ class ServingEngine:
         import jax.numpy as jnp
 
         from ...models.generation import seed_key
-        chunk = self.config.prefill_chunk
-        M = self.config.max_slots
-        bs = self.config.block_size
-        decode_rows = [r for r in self._sched.decoding
-                       if include_decode and not self._done[r.slot]
-                       and self._steps_left[r.slot] > 0]
-        plan: List[Tuple[Request, int]] = []
-        qmax = 1
-        for req in prefills:
-            n = len(req.prefill_ids) - req.num_computed
-            if chunk is not None:
-                n = min(n, chunk)
-            plan.append((req, n))
-            qmax = max(qmax, n)
-        Q = self._bucket(qmax)
-        toks = np.zeros((M, Q), np.int32)
-        starts = np.zeros((M,), np.int32)
-        qlens = np.ones((M,), np.int32)           # pad rows: harmless q=1
-        active = np.zeros((M,), bool)
-        keys = np.zeros((M, 2), np.uint32)
-        sidx = np.zeros((M,), np.int32)
-        temp = np.zeros((M,), np.float32)
-        topk = np.zeros((M,), np.int32)
-        topp = np.ones((M,), np.float32)
-        adapters = np.array(self._adapters)
-        for r in decode_rows:
-            m = r.slot
-            toks[m, :] = self._tokens[m]          # pad lanes: a real token
-            starts[m] = self._seq_lens[m]
-            active[m] = True
-            keys[m] = self._keys[m]
-            sidx[m] = self._sample_idx[m]
-            temp[m] = self._temp[m]
-            topk[m] = self._topk[m]
-            topp[m] = self._topp[m]
-        for req, n in plan:
-            m = req.slot
-            ids = req.prefill_ids[req.num_computed:req.num_computed + n]
-            toks[m, :n] = ids
-            toks[m, n:] = ids[-1]                 # pad lanes: a real token
-            starts[m] = req.num_computed
-            qlens[m] = n
-            active[m] = True
-            # the completing chunk's sampled lane IS the prompt's first
-            # token: the same (seed, index 0) key _first_tokens uses
-            keys[m] = seed_key(req.seed)
-            sidx[m] = 0
-            temp[m] = req.temperature
-            topk[m] = req.top_k if req.top_k is not None else 0
-            topp[m] = req.top_p if req.top_p is not None else 1.0
-            adapters[m] = req.adapter_slot
-        t0 = time.time()
+        with self._span("serve:operands", "mixed"):
+            chunk = self.config.prefill_chunk
+            M = self.config.max_slots
+            decode_rows = [r for r in self._sched.decoding
+                           if include_decode and not self._done[r.slot]
+                           and self._steps_left[r.slot] > 0]
+            plan: List[Tuple[Request, int]] = []
+            qmax = 1
+            for req in prefills:
+                n = len(req.prefill_ids) - req.num_computed
+                if chunk is not None:
+                    n = min(n, chunk)
+                plan.append((req, n))
+                qmax = max(qmax, n)
+            Q = self._bucket(qmax)
+            toks = np.zeros((M, Q), np.int32)
+            starts = np.zeros((M,), np.int32)
+            qlens = np.ones((M,), np.int32)       # pad rows: harmless q=1
+            active = np.zeros((M,), bool)
+            keys = np.zeros((M, 2), np.uint32)
+            sidx = np.zeros((M,), np.int32)
+            temp = np.zeros((M,), np.float32)
+            topk = np.zeros((M,), np.int32)
+            topp = np.ones((M,), np.float32)
+            adapters = np.array(self._adapters)
+            for r in decode_rows:
+                m = r.slot
+                toks[m, :] = self._tokens[m]      # pad lanes: a real token
+                starts[m] = self._seq_lens[m]
+                active[m] = True
+                keys[m] = self._keys[m]
+                sidx[m] = self._sample_idx[m]
+                temp[m] = self._temp[m]
+                topk[m] = self._topk[m]
+                topp[m] = self._topp[m]
+            for req, n in plan:
+                m = req.slot
+                ids = req.prefill_ids[req.num_computed:req.num_computed + n]
+                toks[m, :n] = ids
+                toks[m, n:] = ids[-1]             # pad lanes: a real token
+                starts[m] = req.num_computed
+                qlens[m] = n
+                active[m] = True
+                # the completing chunk's sampled lane IS the prompt's
+                # first token: the same (seed, index 0) key
+                # _first_tokens uses
+                keys[m] = seed_key(req.seed)
+                sidx[m] = 0
+                temp[m] = req.temperature
+                topk[m] = req.top_k if req.top_k is not None else 0
+                topp[m] = req.top_p if req.top_p is not None else 1.0
+                adapters[m] = req.adapter_slot
+            ops = (jnp.asarray(toks),
+                   jnp.asarray(starts), jnp.asarray(qlens),
+                   jnp.asarray(active), jnp.asarray(self.cache.tables),
+                   jnp.asarray(keys), jnp.asarray(sidx), jnp.asarray(temp),
+                   jnp.asarray(topk), jnp.asarray(topp),
+                   *self._lora_operand(adapters))
         with _watchdog.section("serving.decode"):
-            self.cache.pool, nxt = self._jmixed(
-                self._params, self.cache.pool, jnp.asarray(toks),
-                jnp.asarray(starts), jnp.asarray(qlens),
-                jnp.asarray(active), jnp.asarray(self.cache.tables),
-                jnp.asarray(keys), jnp.asarray(sidx), jnp.asarray(temp),
-                jnp.asarray(topk), jnp.asarray(topp),
-                *self._lora_operand(adapters))
-            nxt = np.asarray(nxt)
-        self._record_dispatch("mixed", t0)
-        now = time.time()
-        # prefill rows first (the two-phase path's bookkeeping order:
-        # _advance_prefills before the decode dispatch's commits)
-        for req, n in plan:
-            m = req.slot
-            req.num_computed += n
-            req.reg_state = self.cache.register_prefix(
-                req.prefill_ids, req.blocks, req.num_computed,
-                req.reg_state, tenant=req.tenant,
-                namespace=req.adapter_id)
-            if req.prefilling:
-                continue                          # more chunks to go
-            if req.tokens:                        # readmission: resume
-                self._start_decode(req)
-            else:
-                self._emit_first(req, int(nxt[m]), now, emitted)
-        # decode rows: exactly one iteration of the decode loop's commit
-        for req in decode_rows:
-            m = req.slot
-            t = int(nxt[m])
-            req.tokens.append(t)
-            emitted.setdefault(req.rid, []).append(t)
-            self._tokens[m] = t
-            self._seq_lens[m] += 1
-            self._steps_left[m] -= 1
-            self._sample_idx[m] = len(req.tokens)
-            if req.eos_token_id is not None and t == req.eos_token_id:
-                self._done[m] = True
-                req.eos_seen = True
-            sl = int(self._seq_lens[m])
-            base = req.reg_state[0] * bs
-            if self.config.prefix_cache and sl // bs > req.reg_state[0]:
+            with self._span("serve:dispatch", "mixed") as d:
+                self.cache.pool, nxt = self._jmixed(
+                    self._params, self.cache.pool, *ops)
+                del ops
+            with self._span("serve:fetch", "mixed") as f:
+                nxt = np.asarray(nxt)
+        with self._span("serve:commit", "mixed"):
+            self._record_dispatch("mixed", d.t0, f.t1)
+            # query lanes that carried a real token against the M x Q the
+            # step computed: what a packed mixed step would save
+            self.spans.count("mixed_lanes_real", int(qlens[active].sum()))
+            self.spans.count("mixed_lanes_total", M * Q)
+            now = time.time()
+            # prefill rows first (the two-phase path's bookkeeping order:
+            # _advance_prefills before the decode dispatch's commits)
+            for req, n in plan:
+                m = req.slot
+                req.num_computed += n
                 req.reg_state = self.cache.register_prefix(
-                    self._chain_ids(req, base, sl), req.blocks, sl,
-                    req.reg_state, base=base, tenant=req.tenant,
+                    req.prefill_ids, req.blocks, req.num_computed,
+                    req.reg_state, tenant=req.tenant,
                     namespace=req.adapter_id)
+                if req.prefilling:
+                    continue                      # more chunks to go
+                if req.tokens:                    # readmission: resume
+                    self._start_decode(req)
+                else:
+                    self._emit_first(req, int(nxt[m]), now, emitted)
+            # decode rows: exactly one iteration of the decode loop's
+            # commit
+            for req in decode_rows:
+                m = req.slot
+                t = int(nxt[m])
+                req.tokens.append(t)
+                emitted.setdefault(req.rid, []).append(t)
+                self._tokens[m] = t
+                self._seq_lens[m] += 1
+                self._steps_left[m] -= 1
+                self._sample_idx[m] = len(req.tokens)
+                if req.eos_token_id is not None and t == req.eos_token_id:
+                    self._done[m] = True
+                    req.eos_seen = True
+                self._register_filled(req)
+            self._sched.retire_finished()
 
-    # ---- the scheduler iteration ------------------------------------------
+    def _register_filled(self, req: Request) -> None:
+        """Blocks a dispatch just completed become shareable; skip the
+        chain-ids build unless a block actually filled (``reg_state``
+        makes registration itself incremental)."""
+        bs = self.config.block_size
+        sl = int(self._seq_lens[req.slot])
+        base = req.reg_state[0] * bs
+        if self.config.prefix_cache and sl // bs > req.reg_state[0]:
+            req.reg_state = self.cache.register_prefix(
+                self._chain_ids(req, base, sl), req.blocks, sl,
+                req.reg_state, base=base, tenant=req.tenant,
+                namespace=req.adapter_id)
 
-    def step(self, max_iters: Optional[int] = None) -> Dict[int, List[int]]:
-        """One scheduler iteration: expire deadlines -> retire -> admit
-        (+ prefill) -> advance chunked prefills -> extend/preempt for
-        blocks -> one decode dispatch of up to ``_limit()`` iterations
-        (``max_iters`` caps it). Returns ``{rid: [tokens emitted]}``.
-        Each step stamps the global :mod:`~paddle_tpu.health.watchdog`
-        (progress tick + ``serving.step``/``serving.prefill``/
-        ``serving.decode`` section markers), so a frozen dispatch is
-        named in the hang diagnosis exactly like a training section."""
-        _watchdog.touch()
-        with self._lock, _watchdog.section("serving.step"):
-            emitted = self._step(max_iters)
-            self._lora_sweep()
-            self._journal_step(emitted)
-            return emitted
-
-    def _step(self, max_iters: Optional[int]) -> Dict[int, List[int]]:
+    def _decode_dispatch(self, decoding: List[Request], k: int,
+                         emitted: Dict[int, List[int]]) -> None:
+        """One dispatch of the decode loop over the slot table, up to
+        ``k`` iterations (a device scalar: no retrace)."""
         import jax.numpy as jnp
-        emitted: Dict[int, List[int]] = {}
-        self._expire_deadlines(time.time())
-        self._sched.retire_finished()
-        self._admit(emitted)
-        if not self.config.mixed_batch:
-            # two-phase path (the parity oracle): one B=1 chunk dispatch
-            # per mid-prefill slot BEFORE the decode dispatch, which
-            # _limit then clamps at decode_chunk while any prompt is
-            # mid-prefill. In mixed mode the chunks ride the mixed
-            # dispatch below instead, so the clamp never engages.
-            self._advance_prefills(emitted)
-        k = 0
+        with self._span("serve:operands", "decode"):
+            before = self._steps_left.copy()
+            ops = (jnp.asarray(self._tokens),
+                   jnp.asarray(self._seq_lens),
+                   jnp.asarray(self._steps_left),
+                   jnp.asarray(self._done), jnp.asarray(self.cache.tables),
+                   jnp.asarray(self._eos), jnp.asarray(k, jnp.int32),
+                   jnp.asarray(self._keys), jnp.asarray(self._sample_idx),
+                   jnp.asarray(self._temp), jnp.asarray(self._topk),
+                   jnp.asarray(self._topp),
+                   *self._lora_operand(self._adapters))
+        with _watchdog.section("serving.decode"):
+            with self._span("serve:dispatch", "decode") as d:
+                out = self._jdecode(self._params, self.cache.pool, *ops)
+                del ops      # released here, inside the span that used them
+            with self._span("serve:fetch", "decode") as f:
+                self.cache.pool = out[0]
+                tokens, seq_lens, steps_left, done, toks = (
+                    np.asarray(x) for x in out[1:])
+                del out
+        with self._span("serve:commit", "decode"):
+            self._record_dispatch("decode", d.t0, f.t1)
+            # np.array (copy): zero-copy views of jax outputs are
+            # read-only, and admission writes these slots in place next
+            # step
+            self._tokens = np.array(tokens)
+            self._seq_lens = np.array(seq_lens)
+            self._steps_left = np.array(steps_left)
+            self._done = np.array(done)
+            # iterations the loop actually ran (it exits early once every
+            # live row is done): wall time per iteration is one-valued
+            # where wall time per dispatch is not
+            self.spans.count("decode_iterations",
+                             int((before - self._steps_left).max()))
+            for req in decoding:
+                m = req.slot
+                n = int(before[m] - self._steps_left[m])
+                if n <= 0:
+                    continue
+                got = toks[m, :n].tolist()
+                req.tokens.extend(got)
+                self._sample_idx[m] = len(req.tokens)
+                if bool(self._done[m]):
+                    req.eos_seen = True
+                emitted.setdefault(req.rid, []).extend(got)
+                self._register_filled(req)
+            self._sched.retire_finished()
+
+    def _plan_dispatch(self, max_iters: Optional[int]
+                       ) -> Tuple[Optional[str], tuple]:
+        """Which ONE decode-side dispatch this step makes, with its block
+        planning done: ``("spec", (decoding, drafts))``, ``("mixed",
+        (prefills, include_decode))``, ``("decode", (decoding, k))`` or
+        ``(None, ())``."""
         decoding = self._sched.decoding
         if decoding and self._spec_k:
             # speculative path: draft by prompt lookup; with at least one
@@ -2236,10 +2372,7 @@ class ServingEngine:
             if any(drafts.values()):
                 decoding = self._ensure_blocks_spec(drafts)
                 if decoding and any(drafts.values()):
-                    self._spec_dispatch(decoding, drafts, emitted)
-                    self._sched.retire_finished()
-                    self._stats["steps"] += 1
-                    return emitted
+                    return "spec", (decoding, drafts)
             decoding = self._sched.decoding
         if self.config.mixed_batch and \
                 any(r.prefilling for r in self._sched.live):
@@ -2255,11 +2388,9 @@ class ServingEngine:
             kd = self._ensure_blocks(1) if decoding else 0
             prefills = [r for r in self._sched.live if r.prefilling]
             if prefills:
-                self._mixed_dispatch(prefills, kd >= 1, emitted)
-                self._sched.retire_finished()
-                self._stats["steps"] += 1
-                return emitted
+                return "mixed", (prefills, kd >= 1)
             decoding = self._sched.decoding
+        k = 0
         if decoding:
             want = self._limit(decoding, max_iters)
             k = self._ensure_blocks(want)
@@ -2271,51 +2402,61 @@ class ServingEngine:
                 # survivors' whole remaining budget (no-op otherwise)
                 k = min(k, self._limit(decoding, max_iters))
         if decoding and k >= 1:
-            before = self._steps_left.copy()
-            t0 = time.time()
-            with _watchdog.section("serving.decode"):
-                (self.cache.pool, tokens, seq_lens, steps_left, done,
-                 toks) = self._jdecode(
-                    self._params, self.cache.pool, jnp.asarray(self._tokens),
-                    jnp.asarray(self._seq_lens),
-                    jnp.asarray(self._steps_left),
-                    jnp.asarray(self._done), jnp.asarray(self.cache.tables),
-                    jnp.asarray(self._eos), jnp.asarray(k, jnp.int32),
-                    jnp.asarray(self._keys), jnp.asarray(self._sample_idx),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp),
-                    *self._lora_operand(self._adapters))
-                toks = np.asarray(toks)
-            self._record_dispatch("decode", t0)
-            # np.array (copy): zero-copy views of jax outputs are read-only,
-            # and admission writes these slots in place next step
-            self._tokens = np.array(tokens)
-            self._seq_lens = np.array(seq_lens)
-            self._steps_left = np.array(steps_left)
-            self._done = np.array(done)
-            for req in decoding:
-                m = req.slot
-                n = int(before[m] - self._steps_left[m])
-                if n <= 0:
-                    continue
-                got = toks[m, :n].tolist()
-                req.tokens.extend(got)
-                self._sample_idx[m] = len(req.tokens)
-                if bool(self._done[m]):
-                    req.eos_seen = True
-                emitted.setdefault(req.rid, []).extend(got)
-                # blocks the dispatch just completed become shareable;
-                # skip the chain-ids build unless a block actually filled
-                # (reg_state makes registration itself incremental)
-                sl = int(self._seq_lens[m])
-                base = req.reg_state[0] * self.config.block_size
-                if self.config.prefix_cache and \
-                        sl // self.config.block_size > req.reg_state[0]:
-                    req.reg_state = self.cache.register_prefix(
-                        self._chain_ids(req, base, sl), req.blocks, sl,
-                        req.reg_state, base=base, tenant=req.tenant,
-                        namespace=req.adapter_id)
+            return "decode", (decoding, k)
+        return None, ()
+
+    # ---- the scheduler iteration ------------------------------------------
+
+    def step(self, max_iters: Optional[int] = None) -> Dict[int, List[int]]:
+        """One scheduler iteration: expire deadlines -> retire -> admit
+        (+ prefill) -> advance chunked prefills -> extend/preempt for
+        blocks -> one decode dispatch of up to ``_limit()`` iterations
+        (``max_iters`` caps it). Returns ``{rid: [tokens emitted]}``.
+        Each step stamps the global :mod:`~paddle_tpu.health.watchdog`
+        (progress tick + ``serving.step``/``serving.prefill``/
+        ``serving.decode`` section markers), so a frozen dispatch is
+        named in the hang diagnosis exactly like a training section.
+
+        The step's wall time is covered by flat, disjoint ``serve:*``
+        spans (``plan`` / ``operands`` / ``dispatch`` / ``fetch`` /
+        ``commit`` / ``journal``; none encloses another, so the one
+        covering a device idle gap names its cause), each carrying
+        ``step=`` and, at a dispatch site, ``kind=``."""
+        _watchdog.touch()
+        with self._lock, _watchdog.section("serving.step"):
+            self.step_no += 1
+            emitted = self._step(max_iters)
+            with self._span("serve:journal"):
+                self._lora_sweep()
+                self._journal_step(emitted)
+            return emitted
+
+    def _step(self, max_iters: Optional[int]) -> Dict[int, List[int]]:
+        emitted: Dict[int, List[int]] = {}
+        with self._span("serve:plan"):
+            self._expire_deadlines(time.time())
             self._sched.retire_finished()
+            embeds = self._plan_embeds()
+            waves = self._plan_admissions()
+        for Sb, group in embeds:
+            self._embed_dispatch(Sb, group)
+        for Sb, group in waves:
+            self._prefill_dispatch(Sb, group, emitted)
+        if not self.config.mixed_batch:
+            # two-phase path (the parity oracle): one B=1 chunk dispatch
+            # per mid-prefill slot BEFORE the decode dispatch, which
+            # _limit then clamps at decode_chunk while any prompt is
+            # mid-prefill. In mixed mode the chunks ride the mixed
+            # dispatch below instead, so the clamp never engages.
+            self._advance_prefills(emitted)
+        with self._span("serve:plan"):
+            kind, args = self._plan_dispatch(max_iters)
+        if kind == "spec":
+            self._spec_dispatch(*args, emitted)
+        elif kind == "mixed":
+            self._mixed_dispatch(*args, emitted)
+        elif kind == "decode":
+            self._decode_dispatch(*args, emitted)
         self._stats["steps"] += 1
         return emitted
 
@@ -2434,6 +2575,7 @@ class ServingEngine:
                 "kv_pool_shard_bytes": self.cache.kv_bytes(per_shard=True),
                 "kv_pool_mb": round(self.cache.kv_bytes() / 2**20, 2),
                 "dispatch_latency": self._dispatch_latency(),
+                "spans": self.spans.snapshot(),
                 "offload": (self.cache.offload.stats()
                             if self.cache.offload is not None else None),
                 "lora": (self._lora.stats()
@@ -2479,6 +2621,22 @@ class ServingEngine:
         def pct(xs, q):
             return (round(float(np.percentile(np.asarray(xs), q)), 4)
                     if xs else None)
+
+        snap = self.spans.snapshot()
+        # one serve:journal span a step; unlike step_no the aggregator's
+        # count survives a supervisor rebuild, as the seconds do
+        steps = max(1, snap["spans"].get("serve:journal",
+                                         {}).get("count", 0))
+
+        def lanes(kind):
+            total = snap["counters"].get(f"{kind}_lanes_total", 0)
+            return (round(100.0 * snap["counters"][f"{kind}_lanes_real"]
+                          / total, 2) if total else None)
+
+        def wait(name, q):
+            h = snap["histograms"].get(name)
+            p = histogram_percentile(h, q) if h else None
+            return None if p is None else round(p, 4)
 
         # tenants past MAX_TENANTS were folded into the overflow record
         # at submit; by_tenant() folds queued/live the same way (or the
@@ -2528,6 +2686,17 @@ class ServingEngine:
                 "evictions": self.cache.manager.evictions,
             },
             "dispatch_latency": self._dispatch_latency(),
+            "phase_ms_per_step": {
+                name[len("serve:"):]: round(row["seconds"] * 1e3 / steps, 4)
+                for name, row in sorted(snap["spans"].items())
+                if name.startswith("serve:")},
+            "request_wait": {
+                f"{short}_p{q}_s": wait(name, q)
+                for name, short in (("queue_wait_s", "queue_wait"),
+                                    ("prefill_s", "prefill"))
+                for q in (50, 99)},
+            "real_lane_pct": {"mixed": lanes("mixed"),
+                              "prefill": lanes("prefill")},
             "offload": {
                 "enabled": self.cache.offload is not None,
                 **(self.cache.offload.stats()

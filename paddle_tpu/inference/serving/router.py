@@ -854,7 +854,8 @@ class ServingRouter:
                tenant: Optional[str] = None, priority: int = 0,
                temperature="unset", top_k="unset", top_p="unset",
                seed="unset", replica: Optional[int] = None,
-               adapter_id: Optional[str] = None) -> int:
+               adapter_id: Optional[str] = None,
+               enqueue_t: Optional[float] = None) -> int:
         """Route one prompt to a healthy replica; returns the ROUTER
         request id. ``replica`` pins the pick (an ops/canary hook — the
         pinned replica must still be routable). Raises
@@ -959,7 +960,7 @@ class ServingRouter:
                         deadline_s=deadline_s, tenant=tenant,
                         priority=priority, temperature=temperature,
                         top_k=top_k, top_p=top_p, seed=seed,
-                        adapter_id=adapter_id)
+                        adapter_id=adapter_id, enqueue_t=enqueue_t)
                     rep.breaker.record_success()
                     break
                 except ServingQueueFull as e:   # full: try the next pick

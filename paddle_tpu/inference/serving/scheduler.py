@@ -132,6 +132,13 @@ class Request:
     priority: int = 0
     deadline: Optional[float] = None
     state: str = QUEUED
+    # when the front line first saw the request (the server stamps it on
+    # its event loop, before the command queue; None -> submit_t) and
+    # when it was first admitted to a slot: queue wait = admit_t -
+    # enqueue_t, prefill = first_token_t - admit_t. A supervisor
+    # resubmission keeps the first enqueue_t.
+    enqueue_t: Optional[float] = None
+    admit_t: Optional[float] = None
     submit_t: float = 0.0
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
@@ -453,6 +460,8 @@ class Scheduler:
         req.rid = self._next_rid
         self._next_rid += 1
         req.submit_t = time.time()
+        if req.enqueue_t is None:
+            req.enqueue_t = req.submit_t
         req.state = QUEUED
         if req.deadline is not None:
             self.deadline_requests += 1
@@ -520,6 +529,8 @@ class Scheduler:
             self.recomputed_tokens += rec
         req.admit_seq = self._admit_seq
         self._admit_seq += 1
+        if req.admit_t is None:
+            req.admit_t = time.time()
         req.state = RUNNING
         self.cache.assign(slot, blocks)
         self.slots[slot] = req

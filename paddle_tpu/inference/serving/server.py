@@ -50,6 +50,7 @@ import time
 from typing import Any, AsyncIterator, Dict, Optional, Tuple
 
 from ...flags import flag
+from ...profiler import annotate
 from .scheduler import ServingQueueFull
 from .supervisor import EngineSupervisor, ServingUnavailable
 
@@ -227,9 +228,27 @@ class ServingServer:
                 self.pump_error = e
                 time.sleep(self._poll_s)
 
+    def _span(self, name: str):
+        """One phase of the pump thread, flat like the engine's own
+        (``serve:idle`` / ``cmds`` / ``route`` / ``deliver``): written
+        into the engine's aggregator so ``stats()["spans"]`` closes the
+        thread's whole time, tagged with the engine step it belongs to
+        (the last one run). Over a router there is no one engine to sum
+        them in: the span is then in the trace only."""
+        eng = getattr(self.sup, "engine", None)
+        if eng is None:
+            return annotate(name)
+        return eng.spans.span(name, step=eng.step_no)
+
     def _pump_once(self) -> None:
         busy = self.sup.pending
-        self._run_cmds(block=not busy)
+        drain = self._run_cmds(block=not busy)
+        if drain is not None:
+            _, deadline_s, _, fut = drain
+            self._drain_now(deadline_s)
+            if fut is not None:
+                fut.set_result(self.drain_report)
+            return
         if self.sup.drain_requested and self.drain_report is None:
             self._drain_now(None)
             return
@@ -239,13 +258,14 @@ class ServingServer:
         if not self.sup.pending:
             return
         emitted = self.sup.step(self._decode_chunk())
-        for srid, toks in emitted.items():
-            client = self._open.get(srid)
-            if client is None:
-                continue
-            for t in toks:
-                self._deliver(client, {"type": "token", "rid": srid,
-                                       "token": int(t)})
+        with self._span("serve:deliver"):
+            for srid, toks in emitted.items():
+                client = self._open.get(srid)
+                if client is None:
+                    continue
+                for t in toks:
+                    self._deliver(client, {"type": "token", "rid": srid,
+                                           "token": int(t)})
         self._route_finishes()
 
     def _decode_chunk(self) -> int:
@@ -257,18 +277,28 @@ class ServingServer:
             return int(chunk)
         return int(self.sup.engine.config.decode_chunk)
 
-    def _run_cmds(self, block: bool) -> None:
+    def _run_cmds(self, block: bool):
+        """Run the queued commands in order. A ``drain`` stops the sweep
+        and is handed back to the pump: it steps the engine, whose phases
+        must not nest inside ``serve:cmds``, and whatever is queued behind
+        it is answered after the drain, as it always was."""
         try:
-            cmd = self._cmds.get(timeout=self._poll_s) if block \
-                else self._cmds.get_nowait()
-        except _tqueue.Empty:
-            return
-        while True:
-            self._run_cmd(cmd)
-            try:
+            if block:
+                with self._span("serve:idle"):
+                    cmd = self._cmds.get(timeout=self._poll_s)
+            else:
                 cmd = self._cmds.get_nowait()
-            except _tqueue.Empty:
-                return
+        except _tqueue.Empty:
+            return None
+        with self._span("serve:cmds"):
+            while True:
+                if cmd[0] == "drain":
+                    return cmd
+                self._run_cmd(cmd)
+                try:
+                    cmd = self._cmds.get_nowait()
+                except _tqueue.Empty:
+                    return None
 
     def _run_cmd(self, cmd) -> None:
         kind, payload, client, fut = cmd
@@ -285,13 +315,9 @@ class ServingServer:
                     fut.set_exception(e)
         elif kind == "cancel":
             ok = self.sup.cancel(payload)
-            self._route_finishes()
+            self._route()
             if fut is not None:
                 fut.set_result(ok)
-        elif kind == "drain":
-            self._drain_now(payload)
-            if fut is not None:
-                fut.set_result(self.drain_report)
 
     def _drain_now(self, deadline_s) -> None:
         if self.drain_report is None:       # SIGTERM and close() can race
@@ -299,6 +325,10 @@ class ServingServer:
         self._route_finishes()
 
     def _route_finishes(self) -> None:
+        with self._span("serve:route"):
+            self._route()
+
+    def _route(self) -> None:
         """Terminal transitions -> finish events + end-of-stream
         sentinels for the affected clients."""
         for srid in list(self._open):
@@ -348,6 +378,9 @@ class ServingServer:
 
     async def submit(self, **kwargs) -> int:
         fut: concurrent.futures.Future = concurrent.futures.Future()
+        # stamped here, on the event loop: the wait in the command queue
+        # is part of the request's queue wait
+        kwargs.setdefault("enqueue_t", time.time())
         self._cmds.put(("submit", kwargs, None, fut))
         return await asyncio.wrap_future(fut)
 
@@ -363,6 +396,7 @@ class ServingServer:
         request)."""
         client = ClientStream(self.client_queue)
         fut: concurrent.futures.Future = concurrent.futures.Future()
+        kwargs.setdefault("enqueue_t", time.time())
         self._cmds.put(("submit", {"prompt": prompt, **kwargs}, client,
                         fut))
         srid = await asyncio.wrap_future(fut)

@@ -107,6 +107,9 @@ class TrackedRequest:
     #                                    resubmission re-selects it so the
     #                                    recovered stream runs the same
     #                                    adapted weights
+    enqueue_t: Optional[float] = None  # when the front line first saw
+    #                                    it; a crash resubmission keeps it
+    #                                    so queue wait spans the restart
     erid: int = -1                     # rid in the CURRENT engine
     jid: int = -1                      # journal record id (ISSUE 18);
     #                                    -1 = unjournaled/disowned
@@ -237,6 +240,7 @@ class EngineSupervisor:
         self._wd_seen: Optional[object] = None
         self._last_shed = 0
         self._programs = programs
+        self._spans = None
         # durable serving (ISSUE 18): 'unset' resolves through
         # FLAGS_serving_journal_dir (empty = off); an explicit journal
         # instance (the router shares ONE across its replicas) or an
@@ -259,10 +263,13 @@ class EngineSupervisor:
                             self._serving_config, self._gen_config,
                             programs=self._programs,
                             journal=self._journal,
-                            embed_model=self._embed_model)
+                            embed_model=self._embed_model,
+                            spans=self._spans)
         # reuse the first engine's compiled programs on every rebuild:
-        # restart must never pay a recompile (EnginePrograms docstring)
+        # restart must never pay a recompile (EnginePrograms docstring);
+        # the span aggregator rides along so its totals stay cumulative
         self._programs = eng.programs
+        self._spans = eng.spans
         for name, aparams in self._adapter_registry.items():
             eng.register_adapter(name, aparams)
         return eng
@@ -300,7 +307,8 @@ class EngineSupervisor:
                deadline_s: Optional[float] = None,
                tenant: Optional[str] = None, priority: int = 0,
                temperature="unset", top_k="unset", top_p="unset",
-               seed="unset", adapter_id: Optional[str] = None) -> int:
+               seed="unset", adapter_id: Optional[str] = None,
+               enqueue_t: Optional[float] = None) -> int:
         """Queue one prompt; returns the SUPERVISOR request id (stable
         across engine restarts). Sampling knobs pass through to
         :meth:`ServingEngine.submit` (resolved once there — the tracked
@@ -316,7 +324,7 @@ class EngineSupervisor:
                 eos_token_id=eos_token_id, timeout_s=timeout_s,
                 deadline_s=deadline_s, tenant=tenant, priority=priority,
                 temperature=temperature, top_k=top_k, top_p=top_p,
-                seed=seed, adapter_id=adapter_id)
+                seed=seed, adapter_id=adapter_id, enqueue_t=enqueue_t)
             return self._track(erid).srid
 
     def _track(self, erid: int, resubmits: int = 0) -> TrackedRequest:
@@ -333,7 +341,8 @@ class EngineSupervisor:
             priority=req.priority, deadline=req.deadline,
             temperature=req.temperature, top_k=req.top_k,
             top_p=req.top_p, seed=req.seed,
-            adapter_id=req.adapter_id, erid=erid, jid=req.jid)
+            adapter_id=req.adapter_id, enqueue_t=req.enqueue_t,
+            erid=erid, jid=req.jid)
         rec.tokens = [int(t) for t in req.tokens]
         rec.resubmits = resubmits
         self._next_srid += 1
@@ -680,18 +689,21 @@ class EngineSupervisor:
                 self._recover(f"engine step raised "
                               f"{type(e).__name__}: {e}")
                 return {}
-            if self._watchdog_tripped():
+            eng = self.engine
+            with eng.spans.span("serve:supervise", step=eng.step_no):
+                tripped = self._watchdog_tripped()
+                out: Dict[int, List[int]] = {}
+                if not tripped:
+                    for erid, toks in emitted.items():
+                        rec = self._by_erid.get(erid)
+                        if rec is None:
+                            continue
+                        rec.tokens.extend(int(t) for t in toks)
+                        out[rec.srid] = [int(t) for t in toks]
+                    self._sweep()
+            if tripped:
                 self._recover("hang watchdog fired inside a serving "
                               "section")
-                return {}
-            out: Dict[int, List[int]] = {}
-            for erid, toks in emitted.items():
-                rec = self._by_erid.get(erid)
-                if rec is None:
-                    continue
-                rec.tokens.extend(int(t) for t in toks)
-                out[rec.srid] = [int(t) for t in toks]
-            self._sweep()
             return out
 
     @property
@@ -800,7 +812,7 @@ class EngineSupervisor:
                 tenant=rec.tenant, priority=rec.priority,
                 temperature=rec.temperature, top_k=rec.top_k,
                 top_p=rec.top_p, seed=rec.seed, jid=rec.jid,
-                adapter_id=rec.adapter_id)
+                adapter_id=rec.adapter_id, enqueue_t=rec.enqueue_t)
             rec.resubmits += 1
             rec.state = QUEUED
             self.resubmitted += 1

@@ -297,8 +297,7 @@ def prefetch_to_device(iterable, size: int = 2, device=None):
     ``jax.device_put`` dispatch is async, so while the device runs step N
     the host is collating batch N+1 ("data" span) and its H2D transfer
     ("h2d" span) streams concurrently — the input pipeline disappears from
-    the step time once ``host+h2d < step``. Spans are emitted when
-    ``FLAGS_profile_annotations`` is on.
+    the step time once ``host+h2d < step``.
 
     CPU degradation: there is no host/device overlap to win and "transfers"
     are memcpys, so the buffer collapses to a plain convert-and-yield loop

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -50,13 +51,17 @@ def donation_supported(backend: Optional[str] = None) -> bool:
 
 
 def jit_step(fn: Callable, donate_argnums: Sequence[int] = (),
-             static_argnums: Sequence[int] = (), annotation: str = "step"):
+             static_argnums: Sequence[int] = (), annotation: str = "train"):
     """``jax.jit`` for functional train steps, with the perf-layer contract:
 
     * ``donate_argnums`` is applied only where the backend supports donation
       (CPU would warn on every dispatch and do nothing),
-    * each dispatch runs under an ``annotate(annotation)`` profiling span
-      (no-op unless ``FLAGS_profile_annotations``).
+    * each dispatch runs under ``profiler.annotate_step(annotation, n)``
+      so profiler tools group the trace by step (free unless a profiler
+      session is active). ``n`` counts the calls of THIS wrapper since it
+      was built: warm-up calls are in it and it restarts at 0 after a
+      resume, so it orders a trace's steps and is not the trainer's step
+      number.
 
     Used by bench.py's llama/tuned/checkpoint sections; the raw jitted
     callable is available as ``wrapped._jitted``.
@@ -65,10 +70,12 @@ def jit_step(fn: Callable, donate_argnums: Sequence[int] = (),
     jfn = jax.jit(fn, donate_argnums=donate,
                   static_argnums=tuple(static_argnums))
 
+    from ..profiler import annotate_step
+    calls = itertools.count()
+
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        from ..profiler import annotate
-        with annotate(annotation):
+        with annotate_step(annotation, next(calls)):
             return jfn(*args, **kwargs)
 
     wrapped._jitted = jfn

@@ -186,6 +186,7 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, block_q, block_k):
             _vmem((block_q, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(q, k, v, *seg_ops)
     return out, lse
 
@@ -349,6 +350,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[_vmem((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta, *seg_ops)
 
     # dk/dv accumulate over q blocks, one pass per kv head group member then sum
@@ -381,6 +383,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         scratch_shapes=[_vmem((block_k, D), jnp.float32),
                         _vmem((block_k, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta, *seg_ops2)
 
     if group > 1:  # GQA: fold query-head groups back onto kv heads

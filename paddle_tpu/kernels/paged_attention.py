@@ -297,6 +297,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
             vmem_limit_bytes=_vmem_bytes(Hk, QG, D, bs, q.dtype, out_dtype,
                                          k_pool.dtype)),
         interpret=_interpret(),
+        # the trace and the compiled text name the custom call after this:
+        # the decode form and the multi-query (mixed / verify) form are
+        # two kernels to a profile, so they carry two names
+        name="paged_attention_mq" if multi else "paged_attention_q1",
     )(*scalars, *ops)
     if multi:
         return out.reshape(M, Hk, Q, G, D).transpose(0, 2, 1, 3, 4) \

@@ -96,5 +96,6 @@ def weight_only_matmul(x, w_q, scale, *, block_m: Optional[int] = None,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=_interpret(),
+        name="quant_matmul",
     )(xm.astype(jnp.bfloat16), w_q, scale.reshape(1, N))
     return out.reshape(*lead, N)

@@ -86,6 +86,7 @@ def _fwd(x, weight, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="rms_norm_fwd",
     )(x2, weight.reshape(1, d))
     return out.reshape(shape), rstd
 
@@ -121,6 +122,7 @@ def _rms_bwd_rule(eps, res, g):
             jax.ShapeDtypeStruct((8, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="rms_norm_bwd",
     )(x2, weight.reshape(1, d), rstd, g2)
     dw = dwp[0].astype(weight.dtype)
     return dx.reshape(shape), dw

@@ -47,6 +47,7 @@ def _run(x, cos, sin):
         out_specs=pl.BlockSpec((1, S, D), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), x.dtype),
         interpret=_interpret(),
+        name="rope",
     )(xf, cos, sin)
     return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)
 

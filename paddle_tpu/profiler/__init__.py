@@ -5,42 +5,203 @@ Parity target: ``python/paddle/profiler/profiler.py`` in the reference
 spans, chrome-trace export; CUPTI device tracer). TPU redesign (SURVEY §5):
 the device side is the PJRT profiler — ``jax.profiler`` captures an XPlane
 trace viewable in TensorBoard/Perfetto; the host side keeps the reference's
-RecordEvent UX via ``jax.profiler.TraceAnnotation`` spans plus a lightweight
-wall-clock aggregator for ``summary()`` without TensorBoard.
+RecordEvent UX over ONE span primitive (:func:`annotate`, a
+``jax.profiler.TraceAnnotation``) and ONE cumulative aggregator
+(:class:`SpanStats`) that ``summary()``, ``RecordEvent`` and the serving
+engine's ``stats()["spans"]`` all read.
 """
 
 from __future__ import annotations
 
-import contextlib
+import bisect
 import enum
 import os
+import threading
 import time
-from collections import defaultdict
-from typing import Callable, Iterable, Optional
+from typing import Any, Callable, Dict, Iterable, Optional
+
+import jax.profiler
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "SummaryView", "annotate"]
+           "SummaryView", "annotate", "annotate_step", "SpanStats",
+           "snapshot_delta", "histogram_percentile"]
 
 
-def annotate(name: str):
-    """Zero-overhead-when-off profiling span, gated by
-    ``FLAGS_profile_annotations``.
+def annotate(name: str, **ids):
+    """The one way the program opens a profiling span: a
+    ``jax.profiler.TraceAnnotation(name, **ids)``. With no profiler
+    session active entering it costs about half a microsecond and records
+    nothing; with one active the span lands in the same XPlane as the
+    device's operations, on the same clock, so an idle gap on the device
+    can be laid against it. ``ids`` become the event's arguments (the
+    serving spans carry ``step=`` and ``kind=``)."""
+    return jax.profiler.TraceAnnotation(name, **ids)
 
-    The perf layer (fused train step, prefetch_to_device, async checkpoint)
-    wraps its stages in ``annotate("step")`` / ``annotate("data")`` /
-    ``annotate("h2d")`` / ``annotate("ckpt")`` so an XPlane capture shows
-    where host time goes without any code changes — flip the flag on and
-    trace. Off (the default) this returns a nullcontext and never imports
-    jax.profiler."""
-    from ..flags import flag
-    try:
-        if not flag("FLAGS_profile_annotations"):
-            return contextlib.nullcontext()
-    except KeyError:
-        return contextlib.nullcontext()
-    import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
+
+def annotate_step(name: str, step_num: int):
+    """A ``jax.profiler.StepTraceAnnotation``: profiler tools group the
+    device operations under it by ``step_num`` (the train step's span)."""
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+
+
+# -- the cumulative aggregator ------------------------------------------------
+
+# histogram bucket upper edges: 8 a decade from 10 us to 1000 s (each
+# bucket is 33% wide); one more bucket above catches the rest
+HISTOGRAM_EDGES = tuple(1e-5 * 10.0 ** (i / 8.0) for i in range(65))
+
+
+class _Span:
+    """One open span of a :class:`SpanStats`: the trace annotation plus
+    the two ``perf_counter`` stamps (``t0``/``t1``) its seconds come
+    from, left readable so a caller can time ACROSS spans from the same
+    stamps instead of taking a second pair. The stamps are taken outside
+    the annotation, so the span machinery's own cost is inside the
+    seconds it reports and back-to-back spans leave (almost) no time
+    between them unaccounted."""
+
+    __slots__ = ("_stats", "_name", "_kind", "_ids", "_ann", "t0", "t1")
+
+    def __init__(self, stats, name, kind, ids):
+        if kind is not None:
+            ids["kind"] = kind
+        self._stats, self._name, self._kind, self._ids = (
+            stats, name, kind, ids)
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self._ann = annotate(self._name, **self._ids)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self.t1 = time.perf_counter()
+        self._stats.add(self._name, self.t1 - self.t0, self._kind)
+        return False
+
+
+class SpanStats:
+    """Always-on cumulative totals: per span name a count and total
+    seconds (split per ``kind`` where one is given), plain monotonic
+    counters, and fixed log-spaced-bucket histograms. Nothing is ever
+    reset or windowed: :meth:`snapshot` returns plain dicts and two
+    snapshots SUBTRACT (:func:`snapshot_delta`) to give any window's
+    mean and percentiles. Safe from any thread."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._spans: Dict[str, list] = {}      # name -> [count, seconds]
+        self._kinds: Dict[str, Dict[str, list]] = {}
+        self._counters: Dict[str, int] = {}
+        self._hists: Dict[str, list] = {}      # name -> [buckets, sum]
+
+    def span(self, name: str, kind: Optional[str] = None, **ids) -> _Span:
+        """Context manager: an :func:`annotate` span whose wall seconds
+        are also added here under ``name`` (and under its ``kind``)."""
+        return _Span(self, name, kind, ids)
+
+    def add(self, name: str, seconds: float,
+            kind: Optional[str] = None) -> None:
+        with self._lock:
+            row = self._spans.get(name)
+            if row is None:
+                row = self._spans[name] = [0, 0.0]
+            row[0] += 1
+            row[1] += seconds
+            if kind is not None:
+                row = self._kinds.setdefault(name, {}).setdefault(
+                    kind, [0, 0.0])
+                row[0] += 1
+                row[1] += seconds
+
+    def count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self._counters[name] = self._counters.get(name, 0) + int(n)
+
+    def observe(self, name: str, value: float) -> None:
+        """One sample into the histogram ``name``."""
+        with self._lock:
+            h = self._hists.get(name)
+            if h is None:
+                h = self._hists[name] = [
+                    [0] * (len(HISTOGRAM_EDGES) + 1), 0.0]
+            h[0][bisect.bisect_left(HISTOGRAM_EDGES, value)] += 1
+            h[1] += value
+
+    def snapshot(self) -> Dict[str, Any]:
+        """Plain data (JSON-serializable). Histograms carry their bucket
+        edges (``le``) and CUMULATIVE bucket counts, so a reader needs
+        nothing from this module to subtract and read two of them."""
+        with self._lock:
+            spans = {}
+            for name, (n, s) in self._spans.items():
+                spans[name] = {"count": n, "seconds": s}
+                kinds = self._kinds.get(name)
+                if kinds:
+                    spans[name]["kinds"] = {
+                        k: {"count": kn, "seconds": ks}
+                        for k, (kn, ks) in kinds.items()}
+            hists = {}
+            for name, (buckets, total) in self._hists.items():
+                cum, acc = [], 0
+                for b in buckets:
+                    acc += b
+                    cum.append(acc)
+                hists[name] = {"le": list(HISTOGRAM_EDGES),
+                               "cumulative": cum, "count": acc,
+                               "sum": total}
+            return {"spans": spans, "counters": dict(self._counters),
+                    "histograms": hists}
+
+
+def snapshot_delta(after: Dict[str, Any],
+                   before: Dict[str, Any]) -> Dict[str, Any]:
+    """``after - before`` for two :meth:`SpanStats.snapshot` results: what
+    happened between them, in the same shape."""
+
+    def row(a, b):
+        out = {"count": a["count"] - b.get("count", 0),
+               "seconds": a["seconds"] - b.get("seconds", 0.0)}
+        if "kinds" in a:
+            out["kinds"] = {k: row(v, b.get("kinds", {}).get(k, {}))
+                            for k, v in a["kinds"].items()}
+        return out
+
+    spans = {k: row(v, before["spans"].get(k, {}))
+             for k, v in after["spans"].items()}
+    counters = {k: v - before["counters"].get(k, 0)
+                for k, v in after["counters"].items()}
+    hists = {}
+    for k, a in after["histograms"].items():
+        b = before["histograms"].get(k)
+        cum = a["cumulative"] if b is None else [
+            x - y for x, y in zip(a["cumulative"], b["cumulative"])]
+        hists[k] = {"le": a["le"], "cumulative": cum, "count": cum[-1],
+                    "sum": a["sum"] - (b["sum"] if b else 0.0)}
+    return {"spans": spans, "counters": counters, "histograms": hists}
+
+
+def histogram_percentile(hist: Dict[str, Any], q: float) -> Optional[float]:
+    """The ``q``-th percentile (0-100) of a snapshot histogram (or of a
+    difference of two), linear inside the bucket it falls in; None when
+    it holds no sample. The bucket above the last edge reads as that
+    edge."""
+    n = hist["count"]
+    if n <= 0:
+        return None
+    rank = q / 100.0 * n
+    le, cum = hist["le"], hist["cumulative"]
+    for i, c in enumerate(cum):
+        if c >= rank and c > 0:
+            if i >= len(le):
+                return le[-1]
+            lo = le[i - 1] if i else 0.0
+            below = cum[i - 1] if i else 0
+            return lo + (le[i] - lo) * (rank - below) / (c - below)
+    return le[-1]
 
 
 class ProfilerTarget(enum.Enum):
@@ -117,33 +278,27 @@ def load_profiler_result(path: str):
 
 # -- host-side spans ---------------------------------------------------------
 
-_host_stats = defaultdict(lambda: [0, 0.0])  # name -> [count, total_s]
-_collecting = False
+# the process-wide aggregator RecordEvent spans land in; Profiler.summary()
+# prints what it gained since that profiler's start()
+host_events = SpanStats()
 
 
 class RecordEvent:
     """Host span (ref: paddle.profiler.RecordEvent): shows up in the XPlane
-    timeline via TraceAnnotation and in Profiler.summary() aggregates."""
+    timeline via :func:`annotate` and in Profiler.summary() aggregates."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = None
-        self._t0 = None
+        self._span = None
 
     def begin(self):
-        import jax.profiler
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self._span = host_events.span(self.name)
+        self._span.__enter__()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            if _collecting and self._t0 is not None:
-                st = _host_stats[self.name]
-                st[0] += 1
-                st[1] += time.perf_counter() - self._t0
-            self._ann = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()
@@ -186,20 +341,18 @@ class Profiler:
         self._tracing = False
         self._step_t0 = None
         self._step_times = []
+        self._events_at_start = host_events.snapshot()
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
-        global _collecting
-        _collecting = True
+        self._events_at_start = host_events.snapshot()
         self.current_state = self.scheduler(self.step_num)
         self._maybe_toggle_trace()
         self._step_t0 = time.perf_counter()
 
     def stop(self):
-        global _collecting
         if self._tracing:
             self._stop_trace()
-        _collecting = False
         if self.on_trace_ready is not None:
             self.on_trace_ready(self)
 
@@ -225,7 +378,6 @@ class Profiler:
             self._stop_trace()
 
     def _start_trace(self):
-        import jax.profiler
         os.makedirs(self.trace_dir, exist_ok=True)
         try:
             jax.profiler.start_trace(self.trace_dir)
@@ -234,7 +386,6 @@ class Profiler:
             self._tracing = False
 
     def _stop_trace(self):
-        import jax.profiler
         try:
             jax.profiler.stop_trace()
         finally:
@@ -258,11 +409,15 @@ class Profiler:
             ts = np.asarray(self._step_times) * 1e3
             lines.append(f"step time ms: avg {ts.mean():.2f}  min {ts.min():.2f}"
                          f"  max {ts.max():.2f}")
-        if _host_stats:
+        spans = snapshot_delta(host_events.snapshot(),
+                               self._events_at_start)["spans"]
+        spans = {k: v for k, v in spans.items() if v["count"]}
+        if spans:
             lines.append(f"{'host span':<40}{'calls':>8}{'total ms':>12}")
-            for name, (cnt, tot) in sorted(_host_stats.items(),
-                                           key=lambda kv: -kv[1][1]):
-                lines.append(f"{name:<40}{cnt:>8}{tot * 1e3:>12.2f}")
+            for name, row in sorted(spans.items(),
+                                    key=lambda kv: -kv[1]["seconds"]):
+                lines.append(f"{name:<40}{row['count']:>8}"
+                             f"{row['seconds'] * 1e3:>12.2f}")
         if self._tracing or self._last_export_dir or not self.timer_only:
             lines.append(f"device trace (XPlane): {self.trace_dir} — open "
                          f"with TensorBoard's profile plugin")

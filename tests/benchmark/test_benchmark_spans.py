@@ -1,0 +1,205 @@
+"""The layer metrics that read the program's own spans and counters
+(ISSUE 24): each reader on a hand-made ``run``, a program that has no
+``stats()["spans"]`` (the parent commit) reading as nothing, and the CPU
+rehearsal's traced ``tiny.sat`` and ``tiny.steady`` runs (from
+``rehearsal_spans/``, the accepted rehearsal's serving cells plus the six
+new entries) printing the five that need no device plane."""
+
+import json
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmark import harness, run as bench_run  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REHEARSAL = os.path.join(HERE, "rehearsal")
+# the accepted rehearsal's serving cells with the new readers' entries:
+# ``python benchmark/run.py --root tests/benchmark/rehearsal_spans``
+REHEARSAL_SPANS = os.path.join(HERE, "rehearsal_spans")
+ROOTS = [os.path.join(REPO, "benchmark")]
+EDGES = [1e-5 * 10.0 ** (i / 8.0) for i in range(65)]
+
+
+def reader(name):
+    return harness.load_by_name("layer_metrics", name, ROOTS).read
+
+
+def span(seconds, count, **kinds):
+    row = {"count": count, "seconds": seconds}
+    if kinds:
+        row["kinds"] = {k: {"count": c, "seconds": s}
+                        for k, (c, s) in kinds.items()}
+    return row
+
+
+def hist(samples):
+    cum = [sum(1 for v in samples if v <= e) for e in EDGES] + [len(samples)]
+    return {"le": EDGES, "cumulative": cum, "count": len(samples),
+            "sum": sum(samples)}
+
+
+def stats(chunks, spans, counters=None, histograms=None):
+    return {"chunks": chunks,
+            "spans": {"spans": spans, "counters": counters or {},
+                      "histograms": histograms or {}}}
+
+
+BEFORE = stats(
+    100,
+    {"serve:cmds": span(1.0, 10), "serve:route": span(0.5, 200),
+     "serve:deliver": span(2.0, 100), "serve:supervise": span(0.25, 100),
+     "serve:plan": span(1.0, 200), "serve:operands": span(3.0, 100),
+     "serve:commit": span(2.0, 100), "serve:journal": span(0.5, 100),
+     "serve:dispatch": span(5.0, 100, decode=(40, 1.0), mixed=(60, 4.0)),
+     "serve:fetch": span(50.0, 100, decode=(40, 9.0), mixed=(60, 41.0))},
+    {"decode_iterations": 300, "mixed_lanes_real": 1000,
+     "mixed_lanes_total": 40000},
+    {"queue_wait_s": hist([0.001] * 30)})
+AFTER = stats(
+    150,
+    {"serve:cmds": span(1.1, 12), "serve:route": span(0.6, 300),
+     "serve:deliver": span(2.2, 150), "serve:supervise": span(0.35, 150),
+     "serve:plan": span(1.05, 300), "serve:operands": span(3.15, 150),
+     "serve:commit": span(2.2, 150), "serve:journal": span(0.6, 150),
+     "serve:dispatch": span(5.5, 150, decode=(60, 1.1), mixed=(90, 4.4)),
+     "serve:fetch": span(65.0, 150, decode=(60, 12.9), mixed=(90, 52.1))},
+    {"decode_iterations": 460, "mixed_lanes_real": 3000,
+     "mixed_lanes_total": 80000},
+    {"queue_wait_s": hist([0.001] * 30 + [0.010] * 80 + [0.5] * 20)})
+RUN = {"stats_before": BEFORE, "stats_after": AFTER}
+
+
+@pytest.mark.parametrize("name,want", [
+    # (0.1 + 0.1 + 0.2 + 0.1) s over 50 dispatches
+    ("frontline_host_ms.sat", 10.0),
+    # (0.05 + 0.15 + 0.2 + 0.1) s over 50 dispatches
+    ("step_host_ms.sat", 10.0),
+    # decode (0.1 + 3.9) s over 160 iterations
+    ("decode_iter_wall_ms.sat", 25.0),
+    # 2000 real lanes of 40000
+    ("mixed_real_lane_pct.sat", 5.0),
+])
+def test_span_and_counter_readers_by_hand(name, want):
+    assert reader(name)(RUN) == pytest.approx(want)
+
+
+def test_queue_wait_percentile_is_the_windows_not_the_lifetimes():
+    # the window holds 80 waits of 10 ms and 20 of 0.5 s; the 30 early
+    # 1 ms waits are subtracted out. The 90th is the 90th of 100: the
+    # bucket holding 0.5 s (33% wide), read linearly inside it
+    got = reader("queue_wait_p90_ms.steady")(RUN)
+    assert 500 / 1.34 <= got <= 500.0
+    half = dict(RUN, stats_after=stats(150, {}, {}, {
+        "queue_wait_s": hist([0.001] * 30 + [0.010] * 80)}))
+    assert 10 / 1.34 <= reader("queue_wait_p90_ms.steady")(half) <= 10.0
+
+
+def test_paged_attention_share_is_found_by_kernel_name():
+    op = ('%{} = bf16[32,8,{},128]{{3,2,1,0}} custom-call(...), '
+          'custom_call_target="tpu_custom_call"')
+    trace = {"busy_s": 4.0, "window_s": 4.1, "op_self_s": {
+        op.format("paged_attention_mq.1", 512): 1.9,
+        op.format("paged_attention_q1.1", 4): 0.5,
+        "%fusion.191 = bf16[32,128,14336]{2,1,0} fusion(...)": 0.9,
+        op.format("flash_attention_fwd.1", 4): 0.3}}
+    assert reader("paged_attn_device_pct.sat")({"trace": trace}) == \
+        pytest.approx(60.0)
+    # the parent's trace calls the kernel closed_call.10: nothing to read
+    parent = dict(trace, op_self_s={op.format("closed_call.10", 512): 1.9})
+    assert reader("paged_attn_device_pct.sat")({"trace": parent}) is None
+    assert reader("paged_attn_device_pct.sat")({"trace": None}) is None
+
+
+@pytest.mark.parametrize("name", [
+    "frontline_host_ms.sat", "step_host_ms.sat", "decode_iter_wall_ms.sat",
+    "mixed_real_lane_pct.sat", "queue_wait_p90_ms.steady"])
+def test_a_program_without_spans_reads_as_nothing(name):
+    """The driver runs these readers over the parent commit too, whose
+    ``stats()`` has no ``"spans"`` key: the reader returns nothing and
+    does not raise, and the line leaves the metric out."""
+    parent = {"stats_before": {"chunks": 100}, "stats_after": {"chunks": 150}}
+    assert reader(name)(parent) is None
+    assert reader(name)({}) is None
+    idle = {"stats_before": AFTER, "stats_after": AFTER}   # nothing happened
+    assert reader(name)(idle) is None
+
+
+def test_every_new_reader_is_an_entry_with_its_layer_and_unit():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    want = {"frontline_host_ms.sat": ("front line", "program_span"),
+            "step_host_ms.sat": ("engine step", "program_span"),
+            "decode_iter_wall_ms.sat": ("engine step", "program_span"),
+            "mixed_real_lane_pct.sat": ("paged programs", "program_counter"),
+            "paged_attn_device_pct.sat": ("kernels", "device_trace")}
+    for name, (layer, source) in want.items():
+        m = by_name[name]
+        assert (m["layer"], m["source"], m["moves"], m["workloads"]) == (
+            layer, source, "out_tokens_per_s", ["mistral7b-serve-chat-sat"])
+    # they were appended: what the benchmark had keeps its place
+    assert [m["name"] for m in bench["per_layer"]][-5:] == list(want)
+
+
+def test_the_span_rehearsal_is_the_accepted_one_plus_six_entries():
+    """``rehearsal_spans/BENCHMARK.json`` runs the accepted rehearsal's
+    serving cells from the accepted tree's own data files; it differs by
+    the six new readers alone, appended."""
+    with open(os.path.join(REHEARSAL, "BENCHMARK.json")) as f:
+        accepted = json.load(f)
+    with open(os.path.join(REHEARSAL_SPANS, "BENCHMARK.json")) as f:
+        spans = json.load(f)
+    cells = ["tiny.sat", "tiny.steady"]
+    assert [w["name"] for w in spans["workloads"]] == cells
+    had = [m for m in accepted["per_layer"]
+           if set(m["workloads"]) <= set(cells)]
+    assert spans["per_layer"][:len(had)] == had
+    added = spans["per_layer"][len(had):]
+    assert [(m["name"], m["workloads"]) for m in added] == [
+        ("frontline_host_ms.sat", ["tiny.sat"]),
+        ("step_host_ms.sat", ["tiny.sat"]),
+        ("decode_iter_wall_ms.sat", ["tiny.sat"]),
+        ("mixed_real_lane_pct.sat", ["tiny.sat"]),
+        ("paged_attn_device_pct.sat", ["tiny.sat"]),
+        ("queue_wait_p90_ms.steady", ["tiny.steady"])]
+    for m in added:
+        reader(m["name"])           # each has its file
+
+
+def _traced_rehearsal(cell, capsys):
+    rc = bench_run.main(["--root", REHEARSAL_SPANS, "--workload", cell,
+                         "--seed", str(2 ** 31 + 11), "--seconds", "1.5",
+                         "--trace", "1"])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    line = json.loads(out[-1])
+    assert line["correct"] is True
+    return line["metrics"]
+
+
+def test_rehearsal_traced_run_prints_the_span_metrics(
+        capsys, tmp_path, monkeypatch, compile_cache_config_restored):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    got = _traced_rehearsal("tiny.sat", capsys)
+    # no device plane on a CPU: the trace-backed reader finds nothing
+    assert "paged_attn_device_pct.sat" not in got
+    assert got["frontline_host_ms.sat"]["value"] > 0
+    assert got["step_host_ms.sat"]["value"] > 0
+    # one iteration costs less than a dispatch of up to decode_chunk
+    assert 0 < got["decode_iter_wall_ms.sat"]["value"] <= \
+        got["decode_wall_p50_ms.sat"]["value"] * 1.5
+    assert 0 < got["mixed_real_lane_pct.sat"]["value"] <= 100
+    assert got["compiles_in_window.sat"]["value"] == 0
+
+
+def test_rehearsal_steady_run_prints_the_queue_wait(
+        capsys, tmp_path, monkeypatch, compile_cache_config_restored):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    got = _traced_rehearsal("tiny.steady", capsys)
+    assert got["queue_wait_p90_ms.steady"]["value"] > 0

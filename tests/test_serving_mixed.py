@@ -227,19 +227,19 @@ class TestMixedDispatchShape:
         draft-less steps dispatch mixed. The two counters never move
         together within one step."""
         cfg, params, prompts, outs = setup
-        eng = mk(params, cfg, True, spec_decode=3, spec_ngram=2)
-        # self-continuation prompt: seeded with the model's own greedy
-        # stream so n-gram prompt lookup actually finds drafts (the
-        # spec suite's _cycled_prompts trick)
-        base = np.random.default_rng(0).integers(
-            0, cfg.vocab_size, (8,)).astype(np.int32)
-        cont = np.asarray(G.generate(params, jnp.asarray(base[None]), cfg,
-                                     max_new_tokens=24))[0]
-        rep = np.concatenate([base, cont[:24]])
+        eng = mk(params, cfg, True, spec_decode=3, spec_ngram=2,
+                 max_model_len=256, prefill_chunk=16)
+        # a prompt in which prompt lookup MUST find a draft, whatever the
+        # model emits: "c 0 c 1 c 2 ... c V-1 c" holds the bigram (c, x)
+        # for every token x, so the context's tail (c, first token) has
+        # occurred before, with a continuation to draft from. (A prompt
+        # seeded with the toy model's own greedy stream only drafts if
+        # that stream happens to repeat an n-gram.)
+        c = 5
+        rep = np.full((2 * cfg.vocab_size + 1,), c, np.int32)
+        rep[1::2] = np.arange(cfg.vocab_size)
         eng.submit(rep, max_new_tokens=8, eos_token_id=None)
-        for _ in range(30):
-            if not eng.pending:
-                break
+        while eng.pending:
             before = eng.stats()
             eng.step()
             after = eng.stats()

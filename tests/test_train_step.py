@@ -10,6 +10,7 @@ produce NCHW-identical outputs (bitwise in eval on CPU) with an
 interchangeable state_dict.
 """
 
+import time
 import warnings
 
 import numpy as np
@@ -429,27 +430,39 @@ class TestPrefetch:
         for x, y in zip(a, b):
             np.testing.assert_array_equal(x, y)
 
-    def test_profile_annotations_flag(self):
-        """annotate() is a nullcontext when the flag is off and a real
-        TraceAnnotation when on."""
-        import contextlib
+    def test_profile_annotations_flag(self, tmp_path):
+        """The flag is gone: annotate() is always a real TraceAnnotation,
+        and under a profiler trace the span is recorded with its
+        arguments, no flag set."""
+        import glob
 
-        from paddle_tpu.profiler import annotate
-        assert paddle.get_flags("FLAGS_profile_annotations")[
-            "FLAGS_profile_annotations"] is False
-        assert isinstance(annotate("step"), contextlib.nullcontext)
-        paddle.set_flags({"FLAGS_profile_annotations": True})
+        import jax
+
+        from paddle_tpu.profiler import annotate, annotate_step
+        with pytest.raises(KeyError):
+            paddle.get_flags("FLAGS_profile_annotations")
+        assert isinstance(annotate("step"), jax.profiler.TraceAnnotation)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
         try:
-            span = annotate("step")
-            assert not isinstance(span, contextlib.nullcontext)
-            with span:   # usable as a context manager
-                pass
+            with annotate("unit:span", step=3, kind="decode"):
+                time.sleep(0.002)
+            with annotate_step("train", 7):
+                time.sleep(0.002)
             # spans wrap the prefetch path without breaking it
             out = list(prefetch_to_device(
                 [np.zeros((2, 2), np.float32)], size=2))
             assert len(out) == 1
         finally:
-            paddle.set_flags({"FLAGS_profile_annotations": False})
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        events = {e.name: dict(e.stats)
+                  for p in jax.profiler.ProfileData.from_file(path).planes
+                  for ln in p.lines for e in ln.events}
+        assert events["unit:span"] == {"step": 3, "kind": "decode"}
+        assert events["train"]["step_num"] == 7
 
 
 class TestHapiJit:
