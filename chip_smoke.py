@@ -298,7 +298,11 @@ def kernel_cases(cfg, batch, seq, serving_config):
         out = _masked_sdpa(q.astype(jnp.float32), kk.astype(jnp.float32),
                            vv.astype(jnp.float32), mask)
         out = out.astype(jnp.float32 if "ks" in o else o["k"].dtype)
-        return out if multi else out[:, 0]
+        if not multi:
+            return out[:, 0]
+        # the kernel runs query rows q <= dl and writes zeros past them
+        real = jnp.arange(Q)[None, :] <= o["dl"][:, None]
+        return jnp.where(real[:, :, None, None], out, 0)
 
     for Hq, Hkv in ((H, cfg.kv_heads), gqa):
         for quant in (False, True):

@@ -194,6 +194,13 @@ HEALTH_SNAPSHOT_FIELDS = {
                      "batched prefill); null until that kind has "
                      "dispatched. A low share is compute spent on pad "
                      "lanes: the case for a packed step or finer buckets",
+    "short_row_pct": "active rows of the mixed step that carried ONE query "
+                     "position (q_len 1: decoding slots), lifetime, in "
+                     "percent of its active rows; null until a mixed step "
+                     "has dispatched. These rows take the paged-attention "
+                     "kernel's short query tile (the decode step's work); "
+                     "the rest run a sub-tile per 128 query rows of their "
+                     "chunk (kernels/paged_attention.py)",
     "offload": "host-RAM KV offload tier (FLAGS_serving_offload; ISSUE "
                "16): enabled + the tier's capacity / blocks (host-"
                "resident now) / swap_outs / swap_ins / tier_hits / "
@@ -2253,6 +2260,11 @@ class ServingEngine:
             # step computed: what a packed mixed step would save
             self.spans.count("mixed_lanes_real", int(qlens[active].sum()))
             self.spans.count("mixed_lanes_total", M * Q)
+            # rows that take the paged kernel's short query tile (one
+            # query position), of the rows it runs
+            self.spans.count("attn_rows_short",
+                             int((qlens[active] == 1).sum()))
+            self.spans.count("attn_rows", int(active.sum()))
             now = time.time()
             # prefill rows first (the two-phase path's bookkeeping order:
             # _advance_prefills before the decode dispatch's commits)
@@ -2628,10 +2640,13 @@ class ServingEngine:
         steps = max(1, snap["spans"].get("serve:journal",
                                          {}).get("count", 0))
 
+        def share(part, whole):
+            total = snap["counters"].get(whole, 0)
+            return (round(100.0 * snap["counters"][part] / total, 2)
+                    if total else None)
+
         def lanes(kind):
-            total = snap["counters"].get(f"{kind}_lanes_total", 0)
-            return (round(100.0 * snap["counters"][f"{kind}_lanes_real"]
-                          / total, 2) if total else None)
+            return share(f"{kind}_lanes_real", f"{kind}_lanes_total")
 
         def wait(name, q):
             h = snap["histograms"].get(name)
@@ -2697,6 +2712,7 @@ class ServingEngine:
                 for q in (50, 99)},
             "real_lane_pct": {"mixed": lanes("mixed"),
                               "prefill": lanes("prefill")},
+            "short_row_pct": share("attn_rows_short", "attn_rows"),
             "offload": {
                 "enabled": self.cache.offload is not None,
                 **(self.cache.offload.stats()

@@ -9,53 +9,73 @@ materializes a dense ``[slots, W * block_size, Hk, D]`` gather of every
 sequence's blocks and then masks most of it away — at long contexts decode
 is bandwidth-bound on KV bytes the mask immediately discards.
 
-TPU redesign, not a translation:
+TPU redesign, not a translation. One rule: **the kernel's work follows
+the real tokens** — pages a slot holds, query rows a slot carries.
 
-* **Block tables consumed IN-KERNEL.** The ``[M, W]`` block table and
-  ``[M]`` sequence lengths ride in as scalar-prefetch operands
-  (``pltpu.PrefetchScalarGridSpec``), so each grid step's K/V BlockSpec
-  index map reads ``table[m, w]`` and DMAs exactly that physical block from
-  the pool — the ``[slots, W*bs, ...]`` gather is never materialized in HBM.
-* **Split-K across KV blocks, online-softmax merge.** The grid is
-  ``(M, W)`` with the KV-block dimension innermost: each slot streams its
-  blocks through VMEM accumulators (running max ``m``, normalizer ``l``,
-  weighted-value ``acc``, one row set per kv head) and merges partials
-  with the flash-decoding rescale ``alpha = exp(m_prev - m_cur)`` — the
-  sequential spelling of split-K whose parallelism lives in the ``M`` grid
-  cells (the same accumulator scheme as ``flash_attention.py``'s fwd
-  kernel).
-* **One block DMA serves every kv head.** The pool keeps the engine's
-  ``[N, bs, Hk, D]`` layout and a K/V tile is the whole ``(1, bs, Hk, D)``
-  block: its last two dims are the array's own, which is the only form
-  of a one-block tile the TPU lowering accepts (a per-head ``(1, bs, 1,
-  D)`` tile is refused — second-to-last block dim 1 against ``Hk``). The
-  kv heads are a static loop INSIDE the grid cell, each head reading its
-  ``[bs, D]`` rows out of the resident block, so the grid has ``Hk``
-  times fewer steps and each step moves ``Hk`` times the bytes.
-* **GQA grouped IN-KERNEL.** Queries arrive as ``[M, Hk, G, D]`` (the
-  ``G = H // Hk`` query heads sharing one kv head form one tile), so each
-  K/V block is read ONCE and each head's rows are scored against all its
-  query heads — the gather path pays the ``jnp.repeat`` expansion instead.
-* **int8 KV dequant fused into the loads.** Quantized pools
-  (``kv_quant="int8"``: int8 blocks + per-token-per-head fp32 scales stored
-  alongside, see ``models.generation.init_paged_pool``) dequantize in VMEM
-  right after the block DMA — HBM only ever streams the int8 bytes, which
-  is the capacity AND bandwidth win at once. A dense dequantized pool never
-  exists anywhere.
+* **Grid ``(M,)``, and inside a slot a loop over its LIVE cells.** A cell
+  is ``P`` KV pages, ``P`` chosen from the shapes so that ``P * bs`` is
+  about 128 KV positions (8 pages of 16; ``_tiling``). A slot attending
+  ``j <= sl + dl`` runs ``ceil(pages / P)`` cells, ``pages = (sl + dl) //
+  bs + 1``: no cell, no copy and no branch exists for the rest of the
+  ``W``-wide table (a grid over the table's width paid 0.16 us for every
+  page a slot did NOT hold, more than for the ones it did). ``W`` need
+  not divide by ``P``; the last cell's missing pages mask by position.
+* **Block tables consumed IN-KERNEL, pages copied by the kernel.** The
+  ``[M, W]`` block table and ``[M]`` lengths ride in as scalar-prefetch
+  operands; the K and V pools stay in HBM (``memory_space=ANY``) and the
+  kernel copies each live page ``tbl[m, c * P + i]`` — the engine's whole
+  ``(bs, Hk, D)`` block, one copy serving every kv head — into a two-slot
+  VMEM buffer, starting cell ``c + 1``'s copies before it computes cell
+  ``c``. The ``[slots, W*bs, ...]`` gather is never materialized in HBM.
+* **One matmul pair a kv head a cell.** The kv heads are a static loop
+  inside the cell; a head stacks its ``P * bs`` rows out of the resident
+  pages and does ONE score matmul, ONE value matmul and ONE online-softmax
+  update (running max ``m``, normalizer ``l``, weighted values ``acc``,
+  merged with ``alpha = exp(m_prev - m_cur)`` — the sequential spelling
+  of split-K, as in ``flash_attention.py``'s forward kernel).
+* **GQA grouped IN-KERNEL.** Queries arrive as ``[M, Hk, Q * G, D]`` (row
+  ``q * G + g`` of kv head ``kh`` is query offset ``q``'s head ``kh * G +
+  g``), so each page is read ONCE and each head's rows are scored against
+  all its query heads — the gather path pays the ``jnp.repeat`` instead.
+* **A query tile sized by the slot's own length.** The multi-query entry
+  point reads ``dl`` for its slot and runs only the rows ``q <= dl``: a
+  ``dl == 0`` slot (a decoding slot inside a mixed step) runs the
+  ``R0``-row tile — its ``G`` heads padded to the query dtype's sublane
+  tile, exactly the decode step's work; a longer one runs ``ceil((dl + 1)
+  * G / TQ)`` sub-tiles of ``TQ <= 128`` rows. The branch is on
+  ``draft_lens`` alone. **Output rows past ``dl`` are zeros** (until PR
+  25 they held a capped-window result nobody read): ``paged_mixed_step``
+  takes row ``dl``, the speculative verify masks by ``draft_lens``.
+* **Passes that multiply by zero are not run.** bf16 queries against a
+  bf16 or int8 pool contract as bf16 — one MXU pass, exact products, fp32
+  accumulation: the sum ``HIGHEST`` computes over the cast operands — and
+  the fp32 softmax weights meet such values in three bf16 terms
+  (``_weighted_values``). fp32 operands keep ``HIGHEST``. By dtype only.
+* **int8 KV: scales on the columns.** Quantized pools (``kv_quant=
+  "int8"``: int8 blocks + per-token-per-head fp32 scales, see
+  ``models.generation.init_paged_pool``) stream int8 pages — the capacity
+  AND bandwidth win at once — and dequantize as ``(q · kᵀ) * ks`` and ``(p
+  * vs) · v``: the scale of KV position ``j`` multiplies column ``j``. A
+  dense dequantized pool never exists. The scale planes are ``[N, bs,
+  Hk]`` and a page's ``(bs, Hk)`` tile is narrower than a kernel's own
+  copy may slice from HBM, so the caller-side wrapper gathers them by
+  table into ``[M, cells, Hk, P * bs]`` (1/32 of the pool's bytes; the
+  one dense thing left — revisit with the scale layout, PERF.md §7).
 * **Poison containment.** V rows at positions no query may attend
-  (``j > seq_len``: the null block, stale tails of reused blocks) are
-  zeroed before the PV matmul — the same containment contract as
-  ``llama._masked_sdpa`` (0-weight * NaN would otherwise wipe the row), and
-  bit-invisible for finite KV since those weights are exact 0.0.
+  (``j > sl + dl``: the null block, stale tails of reused blocks, pages
+  of a cell that were never copied) are zeroed before the PV matmul —
+  for an int8 pool the V scale is, the value being finite — the same
+  containment contract as ``llama._masked_sdpa`` (0-weight * NaN would
+  otherwise wipe the row), and bit-invisible for finite KV since those
+  weights are exact 0.0.
 
 The kernel compiles natively on TPU and runs in Pallas interpret mode
 elsewhere (:mod:`paddle_tpu.kernels.dispatch`), so tier-1 exercises this
 exact kernel body on the CPU; ``tests/test_chip_smoke.py`` additionally
-pushes it through the TPU lowering at the serving preset's shapes.
-Scale layout note: scales are stored ``[N, bs, Hk]`` to match the scatter
-writes and ride in as ``(1, bs, Hk)`` tiles — ``Hk`` of 128 lanes used,
-one lane-broadcast per head; revisit the layout if the scale DMA ever
-shows up in profiles (the K/V streams dominate by ``D/4``).
+pushes it through the TPU lowering at the serving preset's shapes and at
+the benchmark's serving cell's. The engine counts the rows that take the
+short tile (``attn_rows_short`` of ``attn_rows``;
+``health_snapshot()["short_row_pct"]``, docs/OPS.md).
 """
 
 from __future__ import annotations
@@ -73,115 +93,275 @@ from .dispatch import interpret as _interpret
 __all__ = ["paged_attention"]
 
 _NEG_INF = -1e30
-# both in-kernel matmuls contract in fp32: the MXU's default single bf16
-# pass would round the softmax weights (and an int8 pool's dequantized
-# K/V) to 8 mantissa bits, which the CPU interpret-mode parity suites
-# never see — the chip must compute the function the tests pin
+# fp32 operands contract in fp32: the MXU's default single bf16 pass would
+# round them to 8 mantissa bits, which the CPU interpret-mode parity suites
+# never see — the chip must compute the function the tests pin. bf16
+# operands need no such care (see ``_scores`` / ``_weighted_values``).
 _F32 = jax.lax.Precision.HIGHEST
+_LANES = 128
+_KV_TILE = 128        # KV positions a cell attends, about
+_MAX_PAGES = 16       # pages a cell holds at most (its copies are unrolled)
+_ROW_TILE = 128       # query rows a sub-tile of a chunk row holds, at most
 
 
-def _kernel(*refs, bs, num_blocks_per_seq, scale, quant, Hk, G, Q):
-    """One grid cell = (slot m, KV block w); the kv heads are a static
-    loop inside it, so the ``[bs, Hk, D]`` block is DMA'd ONCE and every
-    head reads its ``[bs, D]`` rows out of VMEM. ``Q = 1`` is the
-    single-token decode step; ``Q > 1`` is the multi-query entry point —
-    each head's query tile is ``[Q * G, D]`` (Q positions x G grouped
-    query heads per kv head) and a third scalar-prefetch operand
-    ``dl_ref`` carries each slot's draft length: query offset ``i``
-    attends ``j <= sl + min(i, dl)`` (its committed KV plus the in-pass
-    draft prefix; garbage rows past ``dl`` cap at ``dl`` so no row's
-    window ever reaches an unwritten position)."""
-    if Q > 1:
-        tbl_ref, sl_ref, dl_ref = refs[:3]
-        refs = refs[3:]
-    else:
-        tbl_ref, sl_ref = refs[:2]
-        refs = refs[2:]
-    if quant:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
-            refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    m = pl.program_id(0)
-    w = pl.program_id(1)
+def _sublanes(dtype) -> int:
+    """Rows of one vector tile of ``dtype``: 8 at 4 bytes, 16 at 2, 32
+    at 1 — the alignment a static slice of a tile's rows must keep."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _tiling(bs, W, G, Q, q_dtype):
+    """The three tile sizes, from the shapes alone. ``P`` pages a cell,
+    so a cell attends about ``_KV_TILE`` KV positions; ``R0`` query
+    rows for a row that carries ONE query position (its ``G`` grouped
+    heads padded to the query dtype's sublane tile); ``TQ`` query rows a
+    sub-tile of a longer row — the largest aligned divisor of ``Q * G``
+    up to ``_ROW_TILE``, or the whole row where there is none."""
+    P = max(1, min(_KV_TILE // bs, _MAX_PAGES, W))
     QG = Q * G
+    sub = _sublanes(q_dtype)
+    R0 = min(_round_up(G, sub), QG)
+    TQ = next((d for d in range(min(_ROW_TILE, QG), R0 - 1, -1)
+               if QG % d == 0 and d % sub == 0), QG)
+    return P, R0, TQ
 
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+
+def _scores(q, k):
+    """``q [R, D] · k [C, D]ᵀ`` in fp32. Two bf16 operands go through the
+    MXU as they are, ONE pass: a bf16 x bf16 product is exact in fp32 and
+    the accumulator is fp32, so it is the same sum as ``HIGHEST`` over
+    the cast operands. fp32 operands contract in fp32."""
+    precision = None if q.dtype == k.dtype == jnp.bfloat16 else _F32
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _weighted_values(p, v):
+    """``p [R, C] · v [C, D]`` with the softmax weights at their full fp32
+    width. Against bf16 values the weights split into three bf16 terms
+    (``p = hi + mid + lo``, 24 bits) and each meets ``v`` in one exact
+    pass — three passes for the six ``HIGHEST`` spends splitting a ``v``
+    that has nothing below its first term."""
+    dn = (((1,), (0,)), ((), ()))
+    if v.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(p, v, dn, precision=_F32,
+                                   preferred_element_type=jnp.float32)
+    out = None
+    for _ in range(3):
+        term = p.astype(jnp.bfloat16)
+        part = jax.lax.dot_general(term, v, dn,
+                                   preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+        p = p - term.astype(jnp.float32)
+    return out
+
+
+def _kernel(*refs, bs, W, P, scale, quant, Hk, G, Q, R0, TQ, kv_dtype):
+    """One grid step = one slot ``m``; inside it a loop over the slot's
+    LIVE cells of ``P`` KV pages. The pools stay in HBM: the kernel copies
+    a cell's live pages into a two-slot VMEM buffer itself, the next
+    cell's while this one computes; per kv head the cell reads its
+    ``P * bs`` rows out of the buffer as ``kv_dtype`` and does ONE score
+    matmul, ONE value matmul and ONE update of ``m``/``l``/``acc``. An
+    int8 pool's scales arrive laid out by cell (``[1, cells, Hk, P *
+    bs]``, positions along lanes) and scale the scores' and the weights'
+    COLUMNS: ``(q · kᵀ) * ks`` and ``(p * vs) · v`` are the dequantized
+    sums with the int8 values, exact in either float type, as operands.
+
+    ``Q = 1`` is the single-token decode step. ``Q > 1`` is the
+    multi-query entry point: a third scalar-prefetch operand carries each
+    slot's draft length ``dl`` and the slot runs only the query rows
+    ``q <= dl`` — a ``dl == 0`` slot the ``R0``-row tile (exactly the
+    decode step's work), a longer one as many ``TQ``-row sub-tiles as
+    ``(dl + 1) * G`` rows fill. Query offset ``i`` attends ``j <= sl +
+    min(i, dl)``; output rows past ``dl`` are written as zeros."""
+    multi = Q > 1
+    tbl_ref, sl_ref = refs[:2]
+    dl_ref = refs[2] if multi else None
+    refs = refs[3 if multi else 2:]
+    q_ref, hbm = refs[0], refs[1:3]      # the K and V pools, in HBM
+    ks_ref, vs_ref = refs[3:5] if quant else (None, None)
+    o_ref, acc_ref, m_ref, l_ref, kbuf, vbuf, ksem, vsem = refs[-8:]
+    m = pl.program_id(0)
+    QG = Q * G
+    C = P * bs
 
     sl = sl_ref[m]
-    dl = dl_ref[m] if Q > 1 else 0
-    base = w * bs
+    dl = dl_ref[m] if multi else 0
+    # the slot's attendable window is j <= sl + dl: pages and cells past
+    # it are never copied and never computed
+    pages = jnp.minimum((sl + dl) // bs + 1, W)
+    cells = (pages + (P - 1)) // P
 
-    # skip blocks entirely past the attendable window (their table entries
-    # point at the null block; compute is gated, accumulators pass through)
-    @pl.when(base <= sl + dl)
-    def _run():
-        # every index vector stays rank 2 (Mosaic has no rank-1 layout):
-        # jcol/jrow are the block's KV positions down sublanes / along lanes
-        jcol = base + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-        jrow = base + jax.lax.broadcasted_iota(jnp.int32, (QG, bs), 1)
-        if Q > 1:                      # per-query-row causal draft window
-            qi = jax.lax.broadcasted_iota(jnp.int32, (QG, bs), 0)
-            if G > 1:
-                qi = qi // G
-            valid = jrow <= sl + jnp.minimum(qi, dl)     # [Q*G, bs]
-        else:
-            valid = jrow <= sl                           # [G, bs]
-        # containment: V at never-attendable positions must be ZEROED, not
-        # merely zero-weighted — a poisoned request can park NaN there
-        # (see llama._masked_sdpa); exact 0.0 weights make this bit-invisible
-        # for finite KV. The widest window any query row reaches is
-        # j <= sl + dl (every position there was written this dispatch or
-        # earlier), so the union can never touch a stale block tail.
-        keep = jcol <= sl + dl                           # [bs, 1]
-        for h in range(Hk):
-            q = q_ref[0, h].astype(jnp.float32)          # [Q*G, D]
-            k = k_ref[0, :, h, :].astype(jnp.float32)    # [bs, D]
-            v = v_ref[0, :, h, :].astype(jnp.float32)
-            if quant:                  # dequant fused into the block load
-                k = k * ks_ref[0, :, h:h + 1]
-                v = v * vs_ref[0, :, h:h + 1]
-            v = jnp.where(keep, v, 0.0)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    precision=_F32,
-                                    preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, _NEG_INF)
-            m_prev = m_ref[h]                            # [Q*G, 1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_cur)
-            alpha = jnp.exp(m_prev - m_cur)
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), precision=_F32,
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_cur
+    def unrolled(n, body):
+        """``body(i)`` for ``i < n``, traced ONCE and unrolled when the
+        kernel lowers (each copy sees its index as a constant): a Python
+        loop would trace the body ``n`` times, and tracing is what a warm
+        start pays for every program that holds this kernel."""
+        jax.lax.fori_loop(0, n, lambda i, carry: (body(i), carry)[1], 0,
+                          unroll=True)
 
-    @pl.when(w == num_blocks_per_seq - 1)
-    def _finalize():
-        l = l_ref[...]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+    def copy_pages(c, slot, go):
+        """Start (or wait for) the copies of cell ``c``'s live pages into
+        buffer slot ``slot``; one semaphore a pool and slot."""
+        def page(i):
+            @pl.when(c * P + i < pages)
+            def _live():
+                blk = tbl_ref[m, c * P + i]
+                for pool, buf, sem in zip(hbm, (kbuf, vbuf), (ksem, vsem)):
+                    go(pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, i], sem.at[slot]))
+        unrolled(P, page)
+
+    def head_rows(buf, slot, h):
+        """Head ``h``'s rows of the ``P`` pages in buffer slot ``slot``,
+        stacked in the cell's order: ``[P * bs, D]`` of ``kv_dtype``."""
+        parts = [buf[slot, i, :, h, :] for i in range(P)]
+        x = parts[0] if P == 1 else jnp.concatenate(parts, axis=0)
+        if quant:                      # int8 -> float goes through fp32
+            x = x.astype(jnp.float32)
+        return x.astype(kv_dtype)
+
+    def row_pos(r0, nr, width):
+        """Query offset of each of the ``nr`` tile rows from ``r0`` on
+        (row ``q * G + g`` is query offset ``q``), rank 2 like every
+        index vector here (Mosaic has no rank-1 layout)."""
+        r = r0 + jax.lax.broadcasted_iota(jnp.int32, (nr, width), 0)
+        return r // G if G > 1 else r
+
+    def init(r0, nr):
+        rows = pl.ds(r0, nr)
+        acc_ref[:, rows, :] = jnp.zeros((Hk, nr, acc_ref.shape[-1]),
+                                        jnp.float32)
+        m_ref[:, rows, :] = jnp.full((Hk, nr, 1), _NEG_INF, jnp.float32)
+        l_ref[:, rows, :] = jnp.zeros((Hk, nr, 1), jnp.float32)
+
+    def attend(c, slot):
+        """Fold cell ``c``'s ``C`` KV positions, resident in buffer slot
+        ``slot``, into the accumulators of a tile of query rows."""
+        base = c * C
+
+        def tile(r0, nr):
+            rows = pl.ds(r0, nr)
+            # jcol/jrow: the cell's KV positions down sublanes / along
+            # lanes. A page of the cell that was not copied (past the
+            # window) holds whatever the buffer held: it masks by its
+            # logical position like any block's stale tail.
+            jcol = base + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+            jrow = base + jax.lax.broadcasted_iota(jnp.int32, (nr, C), 1)
+            if multi:                  # per-query-row causal draft window
+                valid = jrow <= sl + jnp.minimum(row_pos(r0, nr, C), dl)
+            else:
+                valid = jrow <= sl
+            # containment: V at never-attendable positions must be ZEROED,
+            # not merely zero-weighted — a poisoned request can park NaN
+            # there (see llama._masked_sdpa); exact 0.0 weights make this
+            # bit-invisible for finite KV. The widest window any query row
+            # reaches is j <= sl + dl (every position there was written
+            # this dispatch or earlier), so the union can never touch a
+            # stale block tail. An int8 value is finite; there it is the
+            # V SCALE that can hold the NaN, and takes the zero.
+            keep = (jrow[:1] if quant else jcol) <= sl + dl
+
+            def head(h):
+                q = q_ref[0, h, rows, :].astype(kv_dtype)    # [nr, D]
+                k = head_rows(kbuf, slot, h)                 # [C, D]
+                v = head_rows(vbuf, slot, h)
+                s = _scores(q, k) * scale
+                if quant:              # dequant: one scale a KV column
+                    s = s * ks_ref[0, c, pl.ds(h, 1), :]     # [1, C]
+                else:
+                    v = jnp.where(keep, v, jnp.zeros_like(v))
+                s = jnp.where(valid, s, _NEG_INF)
+                m_prev = m_ref[h, rows, :]               # [nr, 1]
+                m_cur = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_cur)
+                alpha = jnp.exp(m_prev - m_cur)
+                l_ref[h, rows, :] = l_ref[h, rows, :] * alpha + \
+                    jnp.sum(p, axis=1, keepdims=True)
+                if quant:
+                    p = p * jnp.where(keep, vs_ref[0, c, pl.ds(h, 1), :],
+                                      0.0)
+                acc_ref[h, rows, :] = acc_ref[h, rows, :] * alpha + \
+                    _weighted_values(p, v)
+                m_ref[h, rows, :] = m_cur
+            unrolled(Hk, head)
+        return tile
+
+    def finalize(r0, nr):
+        rows = pl.ds(r0, nr)
+        l = l_ref[:, rows, :]
+        out = acc_ref[:, rows, :] / jnp.where(l == 0.0, 1.0, l)
+        if multi:                      # tile rows past the slot's draft
+            real = row_pos(r0, nr, 1) <= dl              # [nr, 1]
+            out = jnp.where(real[None], out, 0.0)
+        o_ref[0, :, rows, :] = out.astype(o_ref.dtype)
+
+    def over_rows(fn):
+        """Run ``fn(r0, nr)`` over the query tiles this slot fills."""
+        if R0 == QG:                   # one tile is the whole row
+            fn(0, QG)
+            return
+
+        pl.when(dl == 0)(lambda: fn(0, R0))
+
+        @pl.when(dl > 0)
+        def _chunk():
+            if TQ == QG:
+                fn(0, QG)
+                return
+
+            def tile(t, carry):
+                fn(pl.multiple_of(t * TQ, TQ), TQ)
+                return carry
+            jax.lax.fori_loop(0, ((dl + 1) * G + (TQ - 1)) // TQ, tile, 0)
+
+    def cell(c, carry):
+        slot = jax.lax.rem(c, 2)
+        pl.when(c + 1 < cells)(
+            lambda: copy_pages(c + 1, 1 - slot, lambda cp: cp.start()))
+        copy_pages(c, slot, lambda cp: cp.wait())
+        over_rows(attend(c, slot))
+        return carry
+
+    copy_pages(0, 0, lambda cp: cp.start())
+    over_rows(init)
+    jax.lax.fori_loop(0, cells, cell, 0)
+    if R0 != QG:                       # rows no tile reaches read zero
+        o_ref[...] = jnp.zeros_like(o_ref)
+    over_rows(finalize)
 
 
-def _vmem_bytes(Hk, QG, D, bs, q_dtype, out_dtype, pool_dtype) -> int:
-    """Scoped-VMEM request for one grid cell, from the shapes: the query
-    and output tiles and the K/V blocks are double-buffered by the
-    pipeline, the three accumulators are resident (``m``/``l`` pad their
-    one column to a 128-lane tile), and the head loop's fp32 temporaries
-    (query tile, scores, weights) each pad to 128 lanes. A mixed step's
-    prefill chunk under GQA (``Q * G`` in the thousands) needs more than
-    the compiler's 16 MiB default; a decode step far less."""
-    lanes = 128
+def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
+                pool_dtype) -> int:
+    """Scoped-VMEM request for one grid step, from the shapes: the query
+    and output tiles (and an int8 pool's by-cell scales) are
+    double-buffered by the pipeline, each padded to its dtype's vector
+    tile; the kernel's own two-slot K and V page buffers of ``C = P * bs``
+    positions; the three accumulators, resident (``m``/``l`` pad their
+    one column to a 128-lane tile); the head loop's temporaries, sized by
+    the widest query tile a cell runs (``rows = max(R0, TQ)`` against
+    ``C`` positions) and no longer by ``Q``. A mixed step's prefill chunk
+    under GQA needs more than the compiler's 16 MiB default for its
+    resident tiles; a decode step far less."""
     isz = lambda dt: jnp.dtype(dt).itemsize
-    rows = Hk * QG
-    tiles = 2 * rows * D * (isz(q_dtype) + isz(out_dtype))
-    kv = 2 * 2 * bs * max(Hk, 32) * (D * isz(pool_dtype) + 4)
-    scratch = rows * (D + 2 * lanes) * 4
-    temps = QG * (2 * D + 4 * lanes) * 4 + 4 * bs * max(D, lanes) * 4
+    pad = lambda n, dt: _round_up(n, _sublanes(dt))
+    lanes = lambda n: _round_up(n, _LANES)
+    tiles = 2 * Hk * lanes(D) * (pad(QG, q_dtype) * isz(q_dtype) +
+                                 pad(QG, out_dtype) * isz(out_dtype))
+    if jnp.dtype(pool_dtype) == jnp.int8:
+        tiles += 2 * 2 * cells * pad(Hk, jnp.float32) * lanes(C) * 4
+    kv = 2 * 2 * C * pad(Hk, pool_dtype) * lanes(D) * isz(pool_dtype)
+    scratch = Hk * pad(QG, jnp.float32) * (lanes(D) + 2 * _LANES) * 4
+    rows = pad(rows, jnp.float32)
+    temps = 2 * 4 * (rows * (2 * lanes(D) + 6 * lanes(C)) +
+                     6 * pad(C, jnp.float32) * lanes(D))
     return max(16 << 20, int(1.25 * (tiles + kv + scratch + temps)))
 
 
@@ -193,18 +373,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     ``q [M, H, D]`` — one query token per slot (the decode entry point) —
     or ``q [M, Q, H, D]`` with ``draft_lens [M]`` — ``Q`` query tokens
     per slot, the MULTI-QUERY entry point (speculative verify, and the
-    mixed step's prefill chunks): query offset ``i`` of slot ``m`` sits at
-    KV position ``seq_lens[m] + i`` and attends ``j <= seq_lens[m] +
-    min(i, draft_lens[m])`` (committed KV plus the in-pass draft prefix;
-    rows past the slot's real draft cap at ``draft_lens`` so no window
-    reaches an unwritten position). ``k_pool``/``v_pool``
+    mixed step's prefill chunks): query offset ``i <= draft_lens[m]`` of
+    slot ``m`` sits at KV position ``seq_lens[m] + i`` and attends ``j <=
+    seq_lens[m] + i`` (committed KV plus the in-pass draft prefix). Rows
+    ``i > draft_lens[m]`` are not computed and come back as ZEROS: no
+    caller reads them. ``k_pool``/``v_pool``
     ``[N, bs, Hk, D]`` — ONE layer's physical block pool (fp, or int8 with
     ``k_scale``/``v_scale [N, bs, Hk]`` fp32 per-token-per-head scales);
     ``block_tables [M, W]`` int32 — slot ``m``'s KV position ``j`` lives in
     physical block ``block_tables[m, j // bs]`` at offset ``j % bs``;
     ``seq_lens [M]`` int32 — slot ``m`` attends positions ``j <=
     seq_lens[m]`` (its new token's KV was just scattered at ``seq_lens[m]``).
-    Unassigned table entries must point at the null block 0. Returns
+    Table entries past a slot's window are never read. Returns
     ``[M, H, D]`` (or ``[M, Q, H, D]``) in ``out_dtype`` (default: the
     pool dtype for fp pools, fp32 for int8 pools — matching the gather
     path's ``_masked_sdpa`` output dtype).
@@ -245,6 +425,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     else:
         qg = q.reshape(M, Hk, G, D)
     QG = Q * G
+    P, R0, TQ = _tiling(bs, W, G, Q, q.dtype)
+    cells = -(-W // P)
+    # the matmuls' operand type, by dtype alone: bf16 queries against a
+    # pool whose values bf16 holds exactly (bf16 itself, int8) go through
+    # the MXU as bf16 (``_scores``); anything else contracts in fp32
+    exact = (q.dtype == jnp.bfloat16 and
+             k_pool.dtype in (jnp.bfloat16, jnp.int8))
+    kv_dtype = jnp.bfloat16 if exact else jnp.float32
     tbl = jnp.asarray(block_tables, jnp.int32)
     sl = jnp.asarray(seq_lens, jnp.int32)
     # scalar-prefetch operands: (tbl, sl) for decode, + dl for multi-query
@@ -252,49 +440,63 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     scalars = (tbl, sl, jnp.asarray(draft_lens, jnp.int32)) if multi \
         else (tbl, sl)
 
-    def qmap(m, w, tbl, *_):
+    def qmap(m, *_):
         return (m, 0, 0, 0)
 
-    def kvmap(m, w, tbl, *_):
-        return (tbl[m, w], 0, 0, 0)
-
-    def smap(m, w, tbl, *_):
-        return (tbl[m, w], 0, 0)
-
-    # every block's last two dims equal the array's own ((Hk, D) for the
-    # pool, (bs, Hk) for the scale planes, (QG, D) for the query tile):
-    # the TPU lowering accepts a full-extent tile at any size, which a
-    # one-head (1, D) slice of the pool is not
-    in_specs = [
-        pl.BlockSpec((1, Hk, QG, D), qmap),
-        pl.BlockSpec((1, bs, Hk, D), kvmap),
-        pl.BlockSpec((1, bs, Hk, D), kvmap),
-    ]
+    # the query and output tiles' last two dims equal the array's own
+    # ((QG, D)): the TPU lowering accepts a full-extent tile at any size.
+    # The pools stay where they are (the kernel copies the pages it needs
+    # into its own two-slot buffers).
+    in_specs = [pl.BlockSpec((1, Hk, QG, D), qmap),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    # a kernel's own copy slices HBM in whole 32-bit rows of the (Hk, D)
+    # plane. A pool with fewer kv heads than one such row packs (bf16
+    # under 2, int8 under 4: a TP shard of a GQA model) is padded up to it
+    # here — a copy of the shard's pool a call, the price of that shape
+    narrow = -Hk % max(1, 4 // k_pool.dtype.itemsize)
+    if narrow:
+        k_pool, v_pool = (jnp.pad(x, ((0, 0), (0, 0), (0, narrow), (0, 0)))
+                          for x in (k_pool, v_pool))
     ops = [qg, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, Hk), smap),
-                     pl.BlockSpec((1, bs, Hk), smap)]
-        ops += [k_scale, v_scale]
+        # a page's scale tile is (bs, Hk) fp32, narrower than anything a
+        # kernel's own copy may slice out of HBM (Mosaic wants 128 lanes),
+        # so the scales — 1/32 of the pool's bytes — are gathered here, by
+        # table, into the layout a cell reads: [M, cells, Hk, P * bs]
+        whole_cells = jnp.pad(tbl, ((0, 0), (0, cells * P - W)))
+
+        def by_cell(plane):
+            return plane[whole_cells].reshape(M, cells, P * bs, Hk) \
+                             .transpose(0, 1, 3, 2)
+        in_specs += [pl.BlockSpec((1, cells, Hk, P * bs), qmap)] * 2
+        ops += [by_cell(k_scale), by_cell(v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(M, W),
+        grid=(M,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hk, QG, D), qmap),
         scratch_shapes=[
             pltpu.VMEM((Hk, QG, D), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
+            pltpu.VMEM((2, P) + k_pool.shape[1:], k_pool.dtype),
+            pltpu.VMEM((2, P) + v_pool.shape[1:], v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, num_blocks_per_seq=W, scale=scale,
-                          quant=quant, Hk=Hk, G=G, Q=Q),
+        functools.partial(_kernel, bs=bs, W=W, P=P, scale=scale,
+                          quant=quant, Hk=Hk, G=G, Q=Q, R0=R0, TQ=TQ,
+                          kv_dtype=kv_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, Hk, QG, D), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_bytes(Hk, QG, D, bs, q.dtype, out_dtype,
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem_bytes(Hk, QG, D, P * bs, cells,
+                                         max(R0, TQ), q.dtype, out_dtype,
                                          k_pool.dtype)),
         interpret=_interpret(),
         # the trace and the compiled text name the custom call after this:

@@ -964,9 +964,9 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
       reference oracle, and the runtime path off-TPU by default.
     * ``use_kernel=True`` — the Pallas flash-decoding kernel
       (:func:`paddle_tpu.kernels.paged_attention`): block tables are
-      consumed inside the kernel (each K/V block DMA'd once per kv head,
-      int8 dequant fused into the load), split-K over KV blocks with the
-      online-softmax merge. No gather is ever materialized — the
+      consumed inside the kernel (each live K/V page copied once for
+      every kv head, int8 dequant fused in), split-K over cells of pages
+      with the online-softmax merge. No gather is ever materialized — the
       long-context bandwidth win. STATIC: bake it per compiled program
       (``ServingConfig.paged_kernel`` / ``FLAGS_serving_paged_kernel``).
 
@@ -1067,15 +1067,20 @@ def paged_spec_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
     the new ``seq_len`` overwrites (position ``seq_len``) or the
     ``j <= seq_len`` mask hides (beyond), and surplus BLOCKS return to
     the ref-counted manager via the preemption free path. Garbage query
-    rows (``q > draft_lens[m]``) attend the CAPPED window ``j <=
-    seq_lens + draft_lens`` so the union of attendable positions never
-    reaches unwritten block tails — the poison-containment contract
-    (``_masked_sdpa``/kernel V-zeroing) extends unchanged.
+    rows (``q > draft_lens[m]``) are unspecified and finite: the gather
+    path attends them to the CAPPED window ``j <= seq_lens + draft_lens``,
+    the kernel does not compute them and returns zeros there — either
+    way the union of attendable positions never reaches unwritten block
+    tails, so the poison-containment contract (``_masked_sdpa``/kernel
+    V-zeroing) extends unchanged, and no caller reads those rows (the
+    verify masks by ``draft_lens``; :func:`paged_mixed_step` takes row
+    ``draft_lens``).
 
     ``use_kernel=True`` runs the Pallas flash-decoding kernel's
     multi-query entry point (:func:`paddle_tpu.kernels.paged_attention`
-    with ``draft_lens``) — block tables consumed in-kernel, one K/V block
-    DMA per kv head scored against all ``Q`` query rows. Returns
+    with ``draft_lens``) — block tables consumed in-kernel, each live K/V
+    page copied once and scored against the slot's ``draft_lens + 1``
+    real query rows. Returns
     (logits ``[M, Q, V]``, pool, dropped_tokens)."""
     x, pool, drops = _paged_multiquery_forward(
         params, cfg, tokens, seq_lens, draft_lens, block_tables, pool,

@@ -165,11 +165,40 @@ def _preset_cases(monkeypatch):
     return cs.kernel_cases(cfg, batch, seq, ServingConfig())
 
 
+def _sat_cell_cases(cases, more_chunks=(), shards=(1,)):
+    """The paged kernel at the shapes the benchmark's serving cell runs
+    (32 slots, GQA 32/8 heads of 128, 256 pages of 16, bf16), read from
+    the cell's own configuration: the decode step and the mixed step at
+    the cell's ``prefill_chunk`` and at ``more_chunks``; for ``shards``
+    past 1, one TP shard's heads of the mixed step, on a bf16 and an int8
+    pool (at 8 shards a pool holds ONE kv head: narrower than the
+    kernel's own copies can slice)."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mistral-7b-v0.3-d16.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    H, Hk = c["num_attention_heads"], c["num_key_value_heads"]
+    bs, M = eng["block_size"], eng["max_slots"]
+    chunks = (eng["prefill_chunk"],) + tuple(more_chunks)
+    paged = next(fn for name, fn, _, _ in cases
+                 if name.startswith("paged_attention"))
+    key = jax.random.key(0, impl="rbg")
+    return [(f"paged_attention sat cell {H // tp}/{Hk // tp} heads Q={Q} "
+             f"{'int8' if quant else 'bf16'}", paged, None,
+             lambda Q=Q, tp=tp, quant=quant: (cs._paged_operands(
+                 key, M, Q, H // tp, Hk // tp, c["hidden_size"] // H, bs,
+                 eng["max_model_len"] // bs, quant, jnp.bfloat16),))
+            for tp in shards
+            for quant in ((False, True) if tp > 1 else (False,))
+            for Q in ((1,) + chunks if tp == 1 else chunks[:1])]
+
+
 def test_every_kernel_lowers_for_tpu(monkeypatch):
     """The check that found ISSUE 21's refused paged-attention BlockSpecs
     from a machine with no chip: the Pallas TPU lowering runs in
     ``jax.export`` and rejects a tile the compiler cannot take."""
-    for name, fn, _ref, build in _preset_cases(monkeypatch):
+    cases = _preset_cases(monkeypatch)
+    for name, fn, _ref, build in cases + _sat_cell_cases(cases):
         exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
             *jax.eval_shape(build))
         assert "tpu_custom_call" in exported.mlir_module(), name
@@ -192,8 +221,13 @@ def test_every_kernel_compiles_for_v5e_without_a_chip(monkeypatch):
     # the chip runs at jax's own default matmul precision, not conftest's
     # "highest" — under which the flash backward kernel's 1024x1024 tiles
     # outgrow the 16 MiB scoped-VMEM default (17.4 MiB asked)
+    # beside the cell's chunk (128), ServingConfig()'s default 256, which
+    # the compiler refused before the query tile stopped growing with Q
+    # (PERF.md, PR 23 finding 2)
+    cases = _preset_cases(monkeypatch)
     with jax.default_matmul_precision("default"):
-        for name, fn, _ref, build in _preset_cases(monkeypatch):
+        for name, fn, _ref, build in cases + _sat_cell_cases(
+                cases, more_chunks=(256,), shards=(1, 2, 4, 8)):
             args = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=where),

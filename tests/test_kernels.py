@@ -416,6 +416,17 @@ class TestPagedAttention:
         mask = jnp.arange(C)[None, None, :] <= hi[:, :, None]
         return _masked_sdpa(q, kk, vv, mask)
 
+    @staticmethod
+    def _check_multi(out, want, dl, **tol):
+        """The multi-query contract: rows ``q <= dl`` match the oracle,
+        rows past the slot's draft are written as zeros."""
+        out = np.asarray(out, np.float32)
+        real = np.arange(out.shape[1])[None, :] <= np.asarray(dl)[:, None]
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out[real], np.asarray(
+            want, np.float32)[real], **tol)
+        assert not out[~real].any()
+
     @pytest.mark.parametrize("trial", range(3))
     def test_multiquery_verify_fuzz(self, trial):
         """The speculative-verify entry point (ISSUE 11): q [M, Q, H, D]
@@ -458,9 +469,7 @@ class TestPagedAttention:
             pool = {"k": kf, "v": vf}
             out = paged_attention(q, kf, vf, tbl, sl, draft_lens=dl)
         want = self._oracle_multi(q, pool, tbl, sl, dl)
-        assert np.isfinite(np.asarray(out)).all()
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=3e-5, atol=3e-5)
+        self._check_multi(out, want, dl, rtol=3e-5, atol=3e-5)
         # dl=0 rows of the verify tile must match the decode entry point
         # on the same pool (row 0 attends exactly j <= sl)
         if quant:
@@ -471,6 +480,84 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(out[:, 0]),
                                    np.asarray(single), rtol=3e-5,
                                    atol=3e-5)
+
+    @pytest.mark.parametrize("pool_kind", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("G", [1, 4])
+    @pytest.mark.parametrize("Q", [1, 64])
+    def test_tiling_follows_real_tokens(self, Q, G, pool_kind):
+        """ISSUE 25's tiling at its edges, one call a case. ``W`` (37) is
+        not a multiple of ``P`` (16 at ``bs`` 4: three cells a slot, the
+        last holding 5 pages); ``sl`` sits at 0, ``bs - 1``, ``P*bs - 1``,
+        ``P*bs`` and the last position a window of ``Q`` fits. At ``Q`` 64
+        the call mixes ``dl == 0`` slots (the short tile) with a full
+        chunk, and two partial ones: under ``G`` 4 (256 query rows a kv
+        head) those run 2, 2 and 1 sub-tiles of 128 rows, under ``G`` 1
+        the whole 64-row tile. Every position past ``sl + dl`` — unowned
+        blocks, the null block, the live cells' own tails — then takes
+        NaN, which must change no bit; ``dl == 0`` slots equal the
+        decode entry point."""
+        import importlib
+        pa = importlib.import_module("paddle_tpu.kernels.paged_attention")
+        bs, W, Hk, D = 4, 37, 2, 16
+        dt = jnp.bfloat16 if pool_kind == "bf16" else jnp.float32
+        P, R0, TQ = pa._tiling(bs, W, G, Q, dt)
+        assert P == 16 and W % P and R0 <= TQ
+        if Q > 1:
+            assert TQ == (128 if G == 4 else 64)
+        C = W * bs
+        sl = np.array([0, bs - 1, P * bs - 1, P * bs, C - Q], np.int32)
+        dl = (np.array([0, Q - 1, 40, 0, 20], np.int32) if Q > 1
+              else np.zeros(5, np.int32))
+        M, N = len(sl), len(sl) * W + 3
+        rng = np.random.default_rng(25)
+        q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        tbl = rng.permutation(np.arange(1, M * W + 1)).reshape(M, W)
+        need = (sl + dl) // bs + 1
+        tbl = np.where(np.arange(W)[None, :] < need[:, None], tbl, 0)
+        dead = np.ones((N, bs), bool)            # no query may attend
+        for m in range(M):
+            dead[tbl[m]] &= (np.arange(C) > sl[m] + dl[m]).reshape(W, bs)
+        dead[0] = True
+        dead = jnp.asarray(dead)
+        tbl, sl, dl = (jnp.asarray(a, jnp.int32) for a in (tbl, sl, dl))
+
+        def run(qq, poison):
+            kw = {"draft_lens": dl} if qq.ndim == 4 else {}
+            if pool_kind == "int8":
+                kq, ks = self._quantize(kf)
+                vq, vs = self._quantize(vf)
+                if poison:                       # poison the QUANT layout
+                    ks = jnp.where(dead[:, :, None], jnp.nan, ks)
+                    vs = jnp.where(dead[:, :, None], jnp.nan, vs)
+                pool = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+                kw.update(k_scale=ks, v_scale=vs)
+            else:
+                k, v = ((jnp.where(dead[:, :, None, None], jnp.nan, x)
+                         for x in (kf, vf)) if poison else (kf, vf))
+                pool = {"k": k, "v": v}
+            return pool, pa.paged_attention(qq, pool["k"], pool["v"], tbl,
+                                            sl, **kw)
+
+        pool, out = run(q if Q > 1 else q[:, 0], poison=False)
+        assert out.dtype == (jnp.float32 if pool_kind == "int8" else dt)
+        wide = {k: v.astype(jnp.float32) if k in ("k", "v") and
+                pool_kind != "int8" else v for k, v in pool.items()}
+        want = self._oracle_multi(q.astype(jnp.float32), wide, tbl, sl, dl)
+        # a bf16 output is rounded once, to 8 bits; the sums are fp32
+        tol = (dict(rtol=1e-2, atol=1e-2) if pool_kind == "bf16"
+               else dict(rtol=3e-5, atol=3e-5))
+        self._check_multi(out if Q > 1 else out[:, None], want, dl, **tol)
+        _, poisoned = run(q if Q > 1 else q[:, 0], poison=True)
+        np.testing.assert_array_equal(np.asarray(poisoned, np.float32),
+                                      np.asarray(out, np.float32))
+        if Q > 1:                                # the short tile IS decode
+            _, single = run(q[:, 0], poison=False)
+            short = np.asarray(dl) == 0
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32)[short, 0],
+                np.asarray(single, np.float32)[short], **tol)
 
     def test_multiquery_requires_draft_lens(self):
         """Both halves of the entry-point contract: rank-4 q needs
