@@ -194,6 +194,17 @@ HEALTH_SNAPSHOT_FIELDS = {
                      "batched prefill); null until that kind has "
                      "dispatched. A low share is compute spent on pad "
                      "lanes: the case for a packed step or finer buckets",
+    "family": "what the served model's family reports from the counters "
+              "its own programs keep (the ``health(counters, cfg)`` of the "
+              "module the config object names, models.paged_family); null "
+              "for a family that reports nothing. The latent-attention "
+              "family with routed experts: local_pair_pct, the (token, "
+              "pick) pairs that fell on experts held HERE in percent of all "
+              "pairs of real tokens (the others are left to the chips that "
+              "hold them and are not computed), and load_max_over_mean, the "
+              "largest row count of one held expert over the mean, averaged "
+              "over layer calls (1.0 = even load); each null before the "
+              "first dispatch",
     "short_row_pct": "active rows of the mixed step that carried ONE query "
                      "position (q_len 1: decoding slots), lifetime, in "
                      "percent of its active rows; null until a mixed step "
@@ -498,6 +509,18 @@ class ServingEngine:
         # the engine-default sampling knobs must themselves be servable
         # (per-request overrides are validated again at submit)
         validate_sampling(self._gen)
+        # the family of model (ISSUE 27): the paged entry points, the pool
+        # and the per-dispatch counters are the ones the config object
+        # names (models.paged_family); what this family does not serve
+        # raises here, at construction
+        from ...models import paged_family
+        self._family = paged_family(model_config)
+        self._family.validate_serving(model_config, self.config)
+        self._counter_names = tuple(self._family.PAGED_COUNTERS)
+        # the served model's widths and counts under its family's names
+        # (what turns the family's counters into bytes and shares); None
+        # for a family that describes nothing
+        self._model = self._family.describe(model_config)
         from ...models.llama import ensure_quantized
         self._params = ensure_quantized(params, self.config.quantize)
         self._cfg = model_config
@@ -665,6 +688,9 @@ class ServingEngine:
 
         from ...jit.train_step import donation_supported
         from ...models import generation as G
+        F = self._family          # the family's paged entry points
+        counted = bool(self._counter_names)
+        use_kernel = self.config.paged_kernel
         cfg, stats, Cmax = self._cfg, self._stats, self._out_width
         if self._mesh is not None:
             # the LOCAL config the shard_map'd programs close over: head
@@ -681,16 +707,16 @@ class ServingEngine:
         def prefill_fn(params, ids, prompt_lens, block_tables, pool, active,
                        lora):
             stats["prefill_traces"] += 1           # trace-time only
-            return G.paged_prefill(params, cfg, ids, prompt_lens,
-                                   block_tables, pool, active, lora=lora)
+            return F.paged_prefill(params, cfg, ids, prompt_lens,
+                                   block_tables, pool, active, lora=lora,
+                                   use_kernel=use_kernel)
 
         def chunk_fn(params, ids, start, chunk_len, block_tables, pool,
                      lora):
             stats["chunk_prefill_traces"] += 1     # trace-time only
-            return G.paged_prefill_chunk(params, cfg, ids, start, chunk_len,
-                                         block_tables, pool, lora=lora)
-
-        use_kernel = self.config.paged_kernel
+            return F.paged_prefill_chunk(params, cfg, ids, start, chunk_len,
+                                         block_tables, pool, lora=lora,
+                                         use_kernel=use_kernel)
 
         def _next_tokens(logits, keys, sample_idx, temp, topk, topp):
             """One compiled sampling step over per-slot DEVICE operands:
@@ -719,11 +745,12 @@ class ServingEngine:
             # retirement; drain the tail in one go) without retracing
             def body(carry):
                 i, tokens, seq_lens, steps_left, done, sample_idx, pool, \
-                    out = carry
+                    out, *counts = carry
                 active = (~done) & (steps_left > 0)
-                logits, pool, _drops = G.paged_decode_step(
+                logits, pool, aux = F.paged_decode_step(
                     params, cfg, tokens, seq_lens, block_tables, pool,
                     active, use_kernel=use_kernel, lora=lora)
+                counts = [c + aux for c in counts]
                 nxt = _next_tokens(logits, keys, sample_idx, temp, topk,
                                    topp)
                 nxt = jnp.where(active, nxt, tokens)
@@ -733,18 +760,22 @@ class ServingEngine:
                 steps_left = steps_left - active.astype(jnp.int32)
                 out = lax.dynamic_update_slice(out, nxt[:, None], (0, i))
                 return (i + 1, nxt, seq_lens, steps_left, done, sample_idx,
-                        pool, out)
+                        pool, out, *counts)
 
             def cond(carry):
-                i, _, _, steps_left, done, _, _, _ = carry
+                i, _, _, steps_left, done = carry[:5]
                 return (i < limit) & ((~done) & (steps_left > 0)).any()
 
             out0 = jnp.zeros((M, Cmax), jnp.int32)
-            (_, tokens, seq_lens, steps_left, done, _, pool, out) = \
-                lax.while_loop(cond, body, (jnp.int32(0), tokens, seq_lens,
-                                            steps_left, done, sample_idx,
-                                            pool, out0))
-            return pool, tokens, seq_lens, steps_left, done, out
+            # a family that counts on the device carries the sums of its
+            # iterations through the loop and returns them last
+            counts0 = ([jnp.zeros((len(self._counter_names),), jnp.int32)]
+                       if counted else [])
+            (_, tokens, seq_lens, steps_left, done, _, pool, out,
+             *counts) = lax.while_loop(
+                cond, body, (jnp.int32(0), tokens, seq_lens, steps_left,
+                             done, sample_idx, pool, out0, *counts0))
+            return (pool, tokens, seq_lens, steps_left, done, out, *counts)
 
         def spec_fn(params, pool, tokens, seq_lens, draft_lens, steps_left,
                     done, block_tables, keys, sample_idx, temp, topk, topp,
@@ -757,7 +788,7 @@ class ServingEngine:
             stats["spec_traces"] += 1              # trace-time only
             M, Q = tokens.shape
             active = (~done) & (steps_left > 0)
-            logits, pool, _drops = G.paged_spec_step(
+            logits, pool, _drops = F.paged_spec_step(
                 params, cfg, tokens, seq_lens, draft_lens, block_tables,
                 pool, active, use_kernel=use_kernel, lora=lora)
             V = logits.shape[-1]
@@ -795,11 +826,11 @@ class ServingEngine:
             completes it, discarded otherwise. Role churn never
             retraces: one executable per Q bucket serves every mix."""
             stats["mixed_traces"] += 1             # trace-time only
-            logits, pool, _drops = G.paged_mixed_step(
+            logits, pool, aux = F.paged_mixed_step(
                 params, cfg, tokens, starts, q_lens, block_tables, pool,
                 active, use_kernel=use_kernel, lora=lora)
-            return pool, _next_tokens(logits, keys, sample_idx, temp,
-                                      topk, topp)
+            nxt = _next_tokens(logits, keys, sample_idx, temp, topk, topp)
+            return (pool, nxt, aux) if counted else (pool, nxt)
 
         def sample_fn(logits, keys, idx, temp, topk, topp):
             """First-token sampler over a prefill wave's logits (one
@@ -831,7 +862,7 @@ class ServingEngine:
             from jax.sharding import PartitionSpec
             from ...models.llama import serving_param_specs
             ps = serving_param_specs(self._params, self._mesh)
-            zs = G.paged_pool_specs(self.cache.pool, self._mesh)
+            zs = F.paged_pool_specs(self.cache.pool, self._mesh)
             R = PartitionSpec()
             if self._lora is not None:
                 # the adapter pool shards like the projections it feeds
@@ -924,6 +955,16 @@ class ServingEngine:
         self._stats["chunks"] += 1
         self._stats[kind + "_dispatches"] += 1
         self._dispatch_ms[kind].append((t1 - t0) * 1e3)
+
+    def _count_dispatch(self, aux=None) -> None:
+        """Add one dispatch's device counters (the small array a counting
+        family's program returns, fetched here inside the ``serve:fetch``
+        the dispatch makes anyway) to the span aggregator under the
+        family's names. A family that counts nothing passes nothing."""
+        if aux is None or not self._counter_names:
+            return
+        for name, n in zip(self._counter_names, np.asarray(aux).tolist()):
+            self.spans.count(name, n)
 
     def _dispatch_latency(self) -> Dict[str, Dict[str, float]]:
         """p50/p99 dispatch wall time per kind over the recent window —
@@ -1760,12 +1801,15 @@ class ServingEngine:
             tail = (jnp.asarray(act), *self._lora_operand(aids))
         with _watchdog.section("serving.prefill"):
             with self._span("serve:dispatch", "prefill") as d:
-                logits, self.cache.pool, _ = self._jprefill(
+                logits, self.cache.pool, aux = self._jprefill(
                     self._params, *ops, self.cache.pool, *tail)
             with self._span("serve:fetch", "prefill") as f:
                 first = self._first_tokens(logits, group, Bb)
+                self._count_dispatch(aux)
         with self._span("serve:commit", "prefill"):
             self._record_dispatch("prefill", d.t0, f.t1)
+            self.spans.count("prefill_tokens",
+                             sum(r.prompt_len for r in group))
             self.spans.count("prefill_lanes_real",
                              sum(r.prompt_len for r in group))
             self.spans.count("prefill_lanes_total", Bb * Sb)
@@ -1847,13 +1891,15 @@ class ServingEngine:
             last = req.num_computed + n >= total and not req.tokens
             with _watchdog.section("serving.prefill"):
                 with self._span("serve:dispatch", "chunk") as d:
-                    logits, self.cache.pool, _ = self._jchunk(
+                    logits, self.cache.pool, aux = self._jchunk(
                         self._params, *ops, self.cache.pool, *lora)
                 with self._span("serve:fetch", "chunk") as f:
                     tok0 = (int(self._first_tokens(logits, [req], 1)[0])
                             if last else None)
+                    self._count_dispatch(aux)
             with self._span("serve:commit", "chunk"):
                 self._record_dispatch("prefill", d.t0, f.t1)
+                self.spans.count("prefill_tokens", n)
                 req.num_computed += n
                 req.reg_state = self.cache.register_prefix(
                     req.prefill_ids, req.blocks, req.num_computed,
@@ -2249,13 +2295,16 @@ class ServingEngine:
                    *self._lora_operand(adapters))
         with _watchdog.section("serving.decode"):
             with self._span("serve:dispatch", "mixed") as d:
-                self.cache.pool, nxt = self._jmixed(
+                self.cache.pool, nxt, *aux = self._jmixed(
                     self._params, self.cache.pool, *ops)
                 del ops
             with self._span("serve:fetch", "mixed") as f:
                 nxt = np.asarray(nxt)
+                self._count_dispatch(*aux)
         with self._span("serve:commit", "mixed"):
             self._record_dispatch("mixed", d.t0, f.t1)
+            self.spans.count("prefill_tokens", sum(n for _, n in plan))
+            self.spans.count("decode_tokens", len(decode_rows))
             # query lanes that carried a real token against the M x Q the
             # step computed: what a packed mixed step would save
             self.spans.count("mixed_lanes_real", int(qlens[active].sum()))
@@ -2334,7 +2383,8 @@ class ServingEngine:
             with self._span("serve:fetch", "decode") as f:
                 self.cache.pool = out[0]
                 tokens, seq_lens, steps_left, done, toks = (
-                    np.asarray(x) for x in out[1:])
+                    np.asarray(x) for x in out[1:6])
+                self._count_dispatch(*out[6:])
                 del out
         with self._span("serve:commit", "decode"):
             self._record_dispatch("decode", d.t0, f.t1)
@@ -2350,6 +2400,8 @@ class ServingEngine:
             # where wall time per dispatch is not
             self.spans.count("decode_iterations",
                              int((before - self._steps_left).max()))
+            self.spans.count("decode_tokens",
+                             int((before - self._steps_left).sum()))
             for req in decoding:
                 m = req.slot
                 n = int(before[m] - self._steps_left[m])
@@ -2588,6 +2640,7 @@ class ServingEngine:
                 "kv_pool_mb": round(self.cache.kv_bytes() / 2**20, 2),
                 "dispatch_latency": self._dispatch_latency(),
                 "spans": self.spans.snapshot(),
+                "model": self._model,
                 "offload": (self.cache.offload.stats()
                             if self.cache.offload is not None else None),
                 "lora": (self._lora.stats()
@@ -2713,6 +2766,7 @@ class ServingEngine:
             "real_lane_pct": {"mixed": lanes("mixed"),
                               "prefill": lanes("prefill")},
             "short_row_pct": share("attn_rows_short", "attn_rows"),
+            "family": self._family.health(snap["counters"], self._cfg),
             "offload": {
                 "enabled": self.cache.offload is not None,
                 **(self.cache.offload.stats()

@@ -323,7 +323,8 @@ class PagedKVCache:
                  tenant_quota: Optional[int] = None, kv_quant=None,
                  mesh=None, offload: bool = False,
                  offload_blocks: int = 0):
-        from ...models.generation import init_paged_pool
+        from ...models import paged_family
+        init_paged_pool = paged_family(model_config).init_paged_pool
         self.block_size = int(block_size)
         self.max_model_len = int(max_model_len)
         self.prefix_cache = bool(prefix_cache)

@@ -14,10 +14,12 @@ between a kernel and its XLA fallback) resolves through is
 from . import flash_attention as flash_attention_mod
 from .dispatch import interpret, on_tpu, use_pallas
 from .flash_attention import flash_attention, flash_attention_with_lse
-from .paged_attention import paged_attention
+from .grouped_matmul import grouped_matmul
+from .paged_attention import paged_attention, paged_attention_latent
 from .rms_norm import rms_norm
 from .rope import apply_rope, rope_cos_sin
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "rms_norm",
-           "apply_rope", "rope_cos_sin", "paged_attention", "use_pallas",
+           "apply_rope", "rope_cos_sin", "paged_attention",
+           "paged_attention_latent", "grouped_matmul", "use_pallas",
            "interpret", "on_tpu"]

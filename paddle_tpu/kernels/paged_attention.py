@@ -76,6 +76,22 @@ pushes it through the TPU lowering at the serving preset's shapes and at
 the benchmark's serving cell's. The engine counts the rows that take the
 short tile (``attn_rows_short`` of ``attn_rows``;
 ``health_snapshot()["short_row_pct"]``, docs/OPS.md).
+
+**The latent form** (:func:`paged_attention_latent`, a model whose cache
+holds one compressed vector a token a layer, multi-head latent attention
+in its absorbed form): ONE pool ``[L, N, bs, D]`` whose page is key and
+value at once (lanes ``0..R-1`` the compressed vector, which is the key's
+first part AND the value; lanes ``R..R+Dr-1`` the rotary key all heads
+share; ``D`` is ``R + Dr`` rounded up to whole 128-lane tiles, because a
+kernel's own copy slices HBM in whole tiles and XLA lays the array out so
+in any case), one kv head, and every query head of a lane as the row tile. Its grid is the
+query LANES (a decode slot is one lane, a prefill chunk one lane a
+token), each with the table row it reads and the length it attends, so
+its work follows the real tokens like the forms above: a lane of length 0
+copies nothing, computes nothing and returns zeros. It shares the tiling
+rule, the in-kernel page copies, ``_scores``/``_weighted_values`` and the
+online softmax with them, and takes the layer as an operand so that the
+pool is never sliced.
 """
 
 from __future__ import annotations
@@ -90,7 +106,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import interpret as _interpret
 
-__all__ = ["paged_attention"]
+__all__ = ["paged_attention", "paged_attention_latent"]
 
 _NEG_INF = -1e30
 # fp32 operands contract in fp32: the MXU's default single bf16 pass would
@@ -508,3 +524,132 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
         return out.reshape(M, Hk, Q, G, D).transpose(0, 2, 1, 3, 4) \
                   .reshape(M, Q, H, D)
     return out.reshape(M, H, D)
+
+
+def _latent_kernel(tbl_ref, slot_ref, len_ref, layer_ref, ql_ref, qr_ref,
+                   pool, o_ref, acc_ref, m_ref, l_ref, buf, sem, *, bs, W, P,
+                   R, scale, kv_dtype):
+    """One grid step = one query lane ``t``: its ``H`` heads are the row
+    tile, its table row is ``slot[t]``, it attends ``j < len[t]``. The
+    pool stays in HBM; a cell's live pages of layer ``layer`` are copied
+    into a two-slot buffer of ``P * bs`` positions, the next cell's while
+    this one computes. A page is ``[bs, D]``: lanes ``0..R-1`` the
+    compressed vector (key part AND value), the next ``Dr`` the shared
+    rotary key, the rest padding. Scores are ``q_lat . c + q_rope . k_rope``; one online-softmax
+    update a cell."""
+    t = pl.program_id(0)
+    n = len_ref[t]
+    row = slot_ref[t]
+    layer = layer_ref[0]
+    pages = jnp.minimum((n + (bs - 1)) // bs, W)
+    cells = (pages + (P - 1)) // P
+    C = P * bs
+    H = ql_ref.shape[1]
+
+    def copy_pages(c, slot, go):
+        def page(i, carry):
+            @pl.when(c * P + i < pages)
+            def _live():
+                blk = tbl_ref[row, c * P + i]
+                go(pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[slot, pl.ds(i * bs, bs)],
+                    sem.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, P, page, 0, unroll=True)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def cell(c, carry):
+        slot = jax.lax.rem(c, 2)
+        pl.when(c + 1 < cells)(
+            lambda: copy_pages(c + 1, 1 - slot, lambda cp: cp.start()))
+        copy_pages(c, slot, lambda cp: cp.wait())
+        base = c * C
+        jcol = base + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+        jrow = base + jax.lax.broadcasted_iota(jnp.int32, (H, C), 1)
+        lat = buf[slot, :, pl.ds(0, R)].astype(kv_dtype)         # [C, R]
+        rot = buf[slot, :, pl.ds(R, qr_ref.shape[-1])].astype(kv_dtype)
+        # containment, as in the forms above: what no query may attend
+        # (a page never copied, a block's stale tail) is zeroed where it
+        # is a VALUE; a score there is replaced below
+        lat = jnp.where(jcol < n, lat, jnp.zeros_like(lat))
+        s = (_scores(ql_ref[0].astype(kv_dtype), lat) +
+             _scores(qr_ref[0].astype(kv_dtype), rot)) * scale
+        s = jnp.where(jrow < n, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_cur)
+        alpha = jnp.exp(m_prev - m_cur)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _weighted_values(p, lat)
+        m_ref[...] = m_cur
+        return carry
+
+    copy_pages(0, 0, lambda cp: cp.start())
+    jax.lax.fori_loop(0, cells, cell, 0)
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+def paged_attention_latent(q_lat, q_rope, pool, layer, block_tables,
+                           lane_slot, lane_len, scale: float,
+                           out_dtype=None):
+    """Attention of ``T`` query lanes straight off a LATENT block pool.
+
+    ``q_lat [T, H, R]`` (each head's query carried into the compressed
+    space) and ``q_rope [T, H, Dr]``; ``pool [L, N, bs, D]``, ``D >= R +
+    Dr``, every layer's pool, of which layer ``layer`` (an int32 scalar,
+    traced) is read: position ``j`` of table row ``s`` is ``pool[layer,
+    block_tables[s, j // bs], j % bs]``, lanes ``0..R-1`` the compressed
+    vector and the next ``Dr`` the rotary key all heads share. Lane ``t`` reads
+    table row ``lane_slot[t]`` and attends ``j < lane_len[t]``; a lane of
+    length 0 is not computed and returns zeros. Returns ``ctx [T, H, R]``:
+    ``softmax((q_lat . c + q_rope . k_rope) * scale) . c``."""
+    T, H, R = q_lat.shape
+    _, N, bs, D = pool.shape
+    W = block_tables.shape[1]
+    Dr = q_rope.shape[-1]
+    if q_rope.shape != (T, H, Dr) or R + Dr > D:
+        raise ValueError(f"paged_attention_latent: q_rope {q_rope.shape} "
+                         f"against a pool of width {D} and q_lat of {R}")
+    if out_dtype is None:
+        out_dtype = pool.dtype
+    P, _, _ = _tiling(bs, W, H, 1, q_lat.dtype)
+    exact = q_lat.dtype == pool.dtype == jnp.bfloat16
+    kv_dtype = jnp.bfloat16 if exact else jnp.float32
+    scalars = (jnp.asarray(block_tables, jnp.int32),
+               jnp.asarray(lane_slot, jnp.int32),
+               jnp.asarray(lane_len, jnp.int32),
+               jnp.asarray(layer, jnp.int32).reshape(1))
+
+    def lane(t, *_):
+        return (t, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(T,),
+        in_specs=[pl.BlockSpec((1, H, R), lane),
+                  pl.BlockSpec((1, H, Dr), lane),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, R), lane),
+        scratch_shapes=[
+            pltpu.VMEM((H, R), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((2, P * bs, D), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, W=W, P=P, R=R,
+                          scale=float(scale), kv_dtype=kv_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, H, R), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret(),
+        name="paged_attention_latent",
+    )(*scalars, q_lat, q_rope, pool)

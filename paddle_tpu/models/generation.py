@@ -65,7 +65,8 @@ __all__ = ["GenerationConfig", "init_cache", "prefill", "decode_step",
            "paged_prefill", "paged_prefill_chunk", "paged_decode_step",
            "paged_spec_step", "paged_mixed_step", "sample_tokens",
            "seed_key",
-           "validate_sampling", "validate_tp"]
+           "validate_sampling", "validate_tp",
+           "PAGED_COUNTERS", "validate_serving", "describe", "health"]
 
 
 # ---------------------------------------------------------------------------
@@ -634,6 +635,25 @@ def _local_heads(cfg: LlamaConfig, pool: Dict) -> Tuple[int, int]:
     return Hk * (cfg.num_attention_heads // cfg.kv_heads), Hk
 
 
+# The rest of what the serving engine asks of a family's module
+# (``models.paged_family``), for the family this module serves: its paged
+# programs count nothing on the device, every engine feature is served, and
+# there is nothing to describe or report beside the engine's own numbers.
+PAGED_COUNTERS = ()
+
+
+def validate_serving(cfg: LlamaConfig, serving_config) -> None:
+    return None
+
+
+def describe(cfg: LlamaConfig) -> None:
+    return None
+
+
+def health(counters: Dict, cfg: LlamaConfig) -> None:
+    return None
+
+
 def paged_pool_specs(pool: Dict, mesh, axis: str = "tp") -> Dict:
     """PartitionSpecs splitting every pool leaf's kv-heads axis over mesh
     ``axis``: K/V ``[L, N, bs, Hk, D]`` and scale ``[L, N, bs, Hk]``
@@ -795,8 +815,11 @@ def _lora_unpack(xs):
 
 
 def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
-                  block_tables, pool: Dict, active, lora=None):
-    """Prefill a BATCH of admitted sequences into the paged pool.
+                  block_tables, pool: Dict, active, lora=None,
+                  use_kernel: bool = False):
+    """Prefill a BATCH of admitted sequences into the paged pool
+    (``use_kernel`` is the family interface's: a prompt with no cached
+    prefix attends over itself here and reads no pool, so it is unused).
 
     ``ids [B, Sb]`` right-padded to the (power-of-2 bucketed) length
     ``Sb``; ``prompt_lens [B]`` the real token counts; ``block_tables
@@ -868,8 +891,11 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
 
 
 def paged_prefill_chunk(params: Dict, cfg: LlamaConfig, ids, start,
-                        chunk_len, block_tables, pool: Dict, lora=None):
-    """Prefill-from-offset: one sequence's token chunk against the pool.
+                        chunk_len, block_tables, pool: Dict, lora=None,
+                        use_kernel: bool = False):
+    """Prefill-from-offset: one sequence's token chunk against the pool
+    (``use_kernel`` is the family interface's: the chunk attends over a
+    dense gather of its table here, so it is unused).
 
     The entry point behind CHUNKED PREFILL and PREFIX-CACHE HITS
     (``inference.serving``): compute KV for positions ``[start, start +

@@ -235,6 +235,102 @@ def test_every_kernel_compiles_for_v5e_without_a_chip(monkeypatch):
             jax.jit(fn).lower(*args).compile()
 
 
+def _moe_cell_cases():
+    """The two kernels the latent-attention family with routed experts
+    brought, at the shapes its serving cell runs, read from the cell's own
+    configuration: the latent paged attention at a decode step's lanes
+    (one a slot) and at one wave of a packed mixed step, and both grouped
+    matmuls of the held experts at the (token, pick) pairs of each."""
+    from paddle_tpu.kernels import grouped_matmul, paged_attention_latent
+    from paddle_tpu.models.pangu_ultra_moe import _WAVE_ROWS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "openpangu-ultra-moe-718b-ep16-d5.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    H, R, dr = (c["num_attention_heads"], c["kv_lora_rank"],
+                c["qk_rope_head_dim"])
+    E, I, held = c["hidden_size"], c["moe_intermediate_size"], \
+        c["n_routed_experts"]
+    M, bs, W = (eng["max_slots"], eng["block_size"],
+                eng["max_model_len"] // eng["block_size"])
+    D = -(-(R + dr) // 128) * 128
+    bf, i32 = jnp.bfloat16, jnp.int32
+    cases = []
+    for T in (M, _WAVE_ROWS * M):
+        cases.append((
+            f"paged_attention_latent {T} lanes",
+            lambda ql, qr, pool, tbl, slot, lens: paged_attention_latent(
+                ql, qr, pool, jnp.int32(2), tbl, slot, lens, 192 ** -0.5),
+            lambda T=T: (jnp.zeros((T, H, R), bf), jnp.zeros((T, H, dr), bf),
+                         jnp.zeros((c["num_hidden_layers"],
+                                    eng["num_blocks"], bs, D), bf),
+                         jnp.zeros((M, W), i32), jnp.zeros((T,), i32),
+                         jnp.zeros((T,), i32))))
+        rows = T * c["num_experts_per_tok"]
+        for K, N, gated in ((E, 2 * I, True), (I, E, False)):
+            cases.append((
+                f"moe_grouped_matmul {rows} rows {K}x{N}",
+                # the expert layers' weights stacked, one layer read in place
+                lambda x, w, g, gated=gated: grouped_matmul(
+                    x, w, g, layer=jnp.int32(2), gated=gated,
+                    use_kernel=True),
+                lambda rows=rows, K=K, N=N: (
+                    jnp.zeros((rows, K), bf),
+                    jnp.zeros((c["num_hidden_layers"] -
+                               c["first_k_dense_replace"], held, K, N), bf),
+                    jnp.zeros((held,), i32))))
+    return cases
+
+
+MOE_CELL_CASES = ["paged_attention_latent 64 lanes",
+                  "moe_grouped_matmul 512 rows 7680x4096",
+                  "moe_grouped_matmul 512 rows 2048x7680",
+                  "paged_attention_latent 256 lanes",
+                  "moe_grouped_matmul 2048 rows 7680x4096",
+                  "moe_grouped_matmul 2048 rows 2048x7680"]
+
+
+@pytest.mark.parametrize("case", MOE_CELL_CASES)
+def test_latent_and_grouped_kernels_lower_for_tpu(case, monkeypatch):
+    """The latent paged attention and the grouped matmul through the Pallas
+    TPU lowering at the serving cell's shapes."""
+    from paddle_tpu.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cases = {name: (fn, build) for name, fn, build in _moe_cell_cases()}
+    assert list(cases) == MOE_CELL_CASES
+    fn, build = cases[case]
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *jax.eval_shape(build))
+    text = exported.mlir_module()
+    assert "tpu_custom_call" in text, case
+    assert case.split()[0] in text        # the name a device trace shows
+
+
+@pytest.mark.slow
+def test_latent_and_grouped_kernels_compile_for_v5e_without_a_chip(
+        monkeypatch):
+    """The same through libtpu's whole compiler for a described v5e: what
+    found that a kernel's own copy cannot slice a 576-lane page (the
+    latent pool is 640 wide for it)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.kernels import dispatch
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:                    # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no TPU topology without a chip: {e}")
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    where = SingleDeviceSharding(topo.devices[0])
+    with jax.default_matmul_precision("default"):
+        for name, fn, build in _moe_cell_cases():
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=where),
+                jax.eval_shape(build))
+            jax.jit(fn).lower(*args).compile()
+
+
 def test_preset_is_spelled_once():
     """chip_smoke.py takes the 738M preset from bench.py, not a copy."""
     src = open(os.path.join(REPO, "chip_smoke.py")).read()

@@ -486,16 +486,19 @@ def test_train_step_and_its_kernels_carry_names_in_the_tpu_lowering(
 
 
 def test_every_pallas_call_has_a_name():
-    """Eight call sites, nine names (the paged kernel has two, by form)."""
+    """Ten call sites, thirteen names (the paged kernel has two, by form,
+    and a latent form; the grouped matmul two, gated or plain)."""
     import os
     import re
     root = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "paddle_tpu", "kernels")
     src = "".join(open(os.path.join(root, f)).read()
                   for f in sorted(os.listdir(root)) if f.endswith(".py"))
-    assert len(re.findall(r"pl\.pallas_call\(", src)) == 8
+    assert len(re.findall(r"pl\.pallas_call\(", src)) == 10
     names = re.findall(r'"(\w+)"', "".join(re.findall(r" name=(.*)", src)))
     assert sorted(names) == sorted([
         "paged_attention_q1", "paged_attention_mq", "flash_attention_fwd",
         "flash_attention_bwd_dq", "flash_attention_bwd_dkv",
-        "quant_matmul", "rms_norm_fwd", "rms_norm_bwd", "rope"])
+        "quant_matmul", "rms_norm_fwd", "rms_norm_bwd", "rope",
+        "paged_attention_latent", "moe_grouped_matmul_gated",
+        "moe_grouped_matmul"])
