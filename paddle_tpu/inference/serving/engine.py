@@ -188,8 +188,10 @@ HEALTH_SNAPSHOT_FIELDS = {
                     "sample",
     "real_lane_pct": "how much of the padded dispatch shapes carries a "
                      "real token, lifetime, in percent: mixed (sum of "
-                     "q_len over active rows against max_slots x Q lanes "
-                     "of the mixed step) and prefill (sum of prompt "
+                     "q_len over active rows against the lanes the mixed "
+                     "step computed: its waves x their lanes for a family "
+                     "that packs the step and counts lanes_computed, "
+                     "max_slots x Q otherwise) and prefill (sum of prompt "
                      "lengths against batch bucket x length bucket of the "
                      "batched prefill); null until that kind has "
                      "dispatched. A low share is compute spent on pad "
@@ -197,7 +199,19 @@ HEALTH_SNAPSHOT_FIELDS = {
     "family": "what the served model's family reports from the counters "
               "its own programs keep (the ``health(counters, cfg)`` of the "
               "module the config object names, models.paged_family); null "
-              "for a family that reports nothing. The latent-attention "
+              "for a family that reports nothing. The dense (Llama-shaped) "
+              "family: lanes_computed, the lanes its programs ran through "
+              "the per-token parts of the model, real or not (a prefill's "
+              "bucket, a decode iteration's slots, a verify step's M x Q, "
+              "a mixed step's waves x their lanes), and mixed_waves, the "
+              "waves its PACKED mixed steps took (a step runs its real "
+              "lanes in waves of min(M x Q, 16 x max_slots) lanes: one "
+              "wave in steady state, a step's decoding slots and a chunk "
+              "or two). mixed_waves well above mixed dispatches in steady "
+              "state means the chunks a step carries outrun a wave: each "
+              "further wave streams the weights again, so lower "
+              "prefill_chunk or the number of prompts admitted at once. "
+              "The latent-attention "
               "family with routed experts: local_pair_pct, the (token, "
               "pick) pairs that fell on experts held HERE in percent of all "
               "pairs of real tokens (the others are left to the chips that "
@@ -517,6 +531,7 @@ class ServingEngine:
         self._family = paged_family(model_config)
         self._family.validate_serving(model_config, self.config)
         self._counter_names = tuple(self._family.PAGED_COUNTERS)
+        self._uncounted: List[Any] = []   # counters of dispatches in flight
         # the served model's widths and counts under its family's names
         # (what turns the family's counters into bytes and shares); None
         # for a family that describes nothing
@@ -788,7 +803,7 @@ class ServingEngine:
             stats["spec_traces"] += 1              # trace-time only
             M, Q = tokens.shape
             active = (~done) & (steps_left > 0)
-            logits, pool, _drops = F.paged_spec_step(
+            logits, pool, aux = F.paged_spec_step(
                 params, cfg, tokens, seq_lens, draft_lens, block_tables,
                 pool, active, use_kernel=use_kernel, lora=lora)
             V = logits.shape[-1]
@@ -812,7 +827,7 @@ class ServingEngine:
             ok = (cand[:, :-1] == tokens[:, 1:]) & \
                 (jnp.arange(Q - 1)[None, :] < draft_lens[:, None])
             acc = jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
-            return pool, cand, acc
+            return (pool, cand, acc, aux) if counted else (pool, cand, acc)
 
         def mixed_fn(params, pool, tokens, starts, q_lens, active,
                      block_tables, keys, sample_idx, temp, topk, topp,
@@ -864,6 +879,9 @@ class ServingEngine:
             ps = serving_param_specs(self._params, self._mesh)
             zs = F.paged_pool_specs(self.cache.pool, self._mesh)
             R = PartitionSpec()
+            # a counting family's programs return their counters last
+            # (computed from replicated operands: the same on every shard)
+            cs = (R,) if counted else ()
             if self._lora is not None:
                 # the adapter pool shards like the projections it feeds
                 # (qB/kB/vB on their output-feature axis, the rest
@@ -883,14 +901,14 @@ class ServingEngine:
                                  out_specs=(R, zs, R), check_vma=False)
             decode_fn = shard_map(decode_fn, mesh=self._mesh,
                                   in_specs=(ps, zs) + (R,) * 12 + ls,
-                                  out_specs=(zs, R, R, R, R, R),
+                                  out_specs=(zs, R, R, R, R, R) + cs,
                                   check_vma=False)
             spec_fn = shard_map(spec_fn, mesh=self._mesh,
                                 in_specs=(ps, zs) + (R,) * 11 + ls,
-                                out_specs=(zs, R, R), check_vma=False)
+                                out_specs=(zs, R, R) + cs, check_vma=False)
             mixed_fn = shard_map(mixed_fn, mesh=self._mesh,
                                  in_specs=(ps, zs) + (R,) * 10 + ls,
-                                 out_specs=(zs, R), check_vma=False)
+                                 out_specs=(zs, R) + cs, check_vma=False)
         donate = donation_supported()
 
         def jit(name, fn, *donated):
@@ -956,15 +974,24 @@ class ServingEngine:
         self._stats[kind + "_dispatches"] += 1
         self._dispatch_ms[kind].append((t1 - t0) * 1e3)
 
-    def _count_dispatch(self, aux=None) -> None:
+    def _count_dispatch(self, aux=None, fetch: bool = True) -> Dict[str, int]:
         """Add one dispatch's device counters (the small array a counting
         family's program returns, fetched here inside the ``serve:fetch``
         the dispatch makes anyway) to the span aggregator under the
-        family's names. A family that counts nothing passes nothing."""
+        family's names, and return them. ``fetch=False`` is for a dispatch
+        that is left in flight: its counters wait for the next fetch. A
+        family that counts nothing passes nothing."""
         if aux is None or not self._counter_names:
-            return
-        for name, n in zip(self._counter_names, np.asarray(aux).tolist()):
-            self.spans.count(name, n)
+            return {}
+        if not fetch:
+            self._uncounted.append(aux)
+            return {}
+        counts = dict(zip(self._counter_names, np.asarray(aux).tolist()))
+        late = [np.asarray(a).tolist() for a in self._uncounted]
+        self._uncounted.clear()
+        for name, *ns in zip(self._counter_names, counts.values(), *late):
+            self.spans.count(name, sum(ns))
+        return counts
 
     def _dispatch_latency(self) -> Dict[str, Dict[str, float]]:
         """p50/p99 dispatch wall time per kind over the recent window —
@@ -1896,7 +1923,7 @@ class ServingEngine:
                 with self._span("serve:fetch", "chunk") as f:
                     tok0 = (int(self._first_tokens(logits, [req], 1)[0])
                             if last else None)
-                    self._count_dispatch(aux)
+                    self._count_dispatch(aux, fetch=last)
             with self._span("serve:commit", "chunk"):
                 self._record_dispatch("prefill", d.t0, f.t1)
                 self.spans.count("prefill_tokens", n)
@@ -2180,12 +2207,13 @@ class ServingEngine:
                    *self._lora_operand(self._adapters))
         with _watchdog.section("serving.decode"):
             with self._span("serve:dispatch", "spec") as d:
-                self.cache.pool, cand, acc = self._jspec(
+                self.cache.pool, cand, acc, *aux = self._jspec(
                     self._params, self.cache.pool, *ops)
                 del ops
             with self._span("serve:fetch", "spec") as f:
                 cand = np.asarray(cand)
                 acc = np.asarray(acc)
+                self._count_dispatch(*aux)
         with self._span("serve:commit", "spec"):
             self._record_dispatch("spec", d.t0, f.t1)
             for req in decoding:
@@ -2300,15 +2328,17 @@ class ServingEngine:
                 del ops
             with self._span("serve:fetch", "mixed") as f:
                 nxt = np.asarray(nxt)
-                self._count_dispatch(*aux)
+                counts = self._count_dispatch(*aux)
         with self._span("serve:commit", "mixed"):
             self._record_dispatch("mixed", d.t0, f.t1)
             self.spans.count("prefill_tokens", sum(n for _, n in plan))
             self.spans.count("decode_tokens", len(decode_rows))
-            # query lanes that carried a real token against the M x Q the
-            # step computed: what a packed mixed step would save
+            # query lanes that carried a real token against the lanes the
+            # step computed: the family's own count where it reports one
+            # (a packed step's waves), the M x Q handed over otherwise
             self.spans.count("mixed_lanes_real", int(qlens[active].sum()))
-            self.spans.count("mixed_lanes_total", M * Q)
+            self.spans.count("mixed_lanes_total",
+                             counts.get("lanes_computed", M * Q))
             # rows that take the paged kernel's short query tile (one
             # query position), of the rows it runs
             self.spans.count("attn_rows_short",

@@ -636,10 +636,21 @@ def _local_heads(cfg: LlamaConfig, pool: Dict) -> Tuple[int, int]:
 
 
 # The rest of what the serving engine asks of a family's module
-# (``models.paged_family``), for the family this module serves: its paged
-# programs count nothing on the device, every engine feature is served, and
-# there is nothing to describe or report beside the engine's own numbers.
-PAGED_COUNTERS = ()
+# (``models.paged_family``), for the family this module serves: every engine
+# feature is served and there is nothing to describe. What one dispatch
+# counts on the device, in this order (int32, summed over a decode
+# dispatch's iterations): lanes run through the model's per-token parts,
+# real or not (a prefill's bucket, a decode step's slots, a verify step's
+# ``M x Q``, a mixed step's waves x their lanes), and the waves of a mixed
+# step.
+PAGED_COUNTERS = ("lanes_computed", "mixed_waves")
+# lanes of one wave of a packed mixed step, in multiples of the slots
+_WAVE_ROWS = 16
+
+
+def _lane_counts(lanes, waves=0):
+    return jnp.stack([jnp.asarray(lanes, jnp.int32),
+                      jnp.asarray(waves, jnp.int32)])
 
 
 def validate_serving(cfg: LlamaConfig, serving_config) -> None:
@@ -650,8 +661,10 @@ def describe(cfg: LlamaConfig) -> None:
     return None
 
 
-def health(counters: Dict, cfg: LlamaConfig) -> None:
-    return None
+def health(counters: Dict, cfg: LlamaConfig) -> Dict:
+    """``health_snapshot()["family"]``: the two device counters as they
+    stand (docs/OPS.md says how to read them)."""
+    return {name: int(counters.get(name, 0)) for name in PAGED_COUNTERS}
 
 
 def paged_pool_specs(pool: Dict, mesh, axis: str = "tp") -> Dict:
@@ -839,7 +852,7 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
     ids, "layers": stacked adapter pool}`` — a device operand like the
     sampling knobs, so adapter churn never retraces (``models.lora``).
     Returns (next-token logits ``[B, V]`` read at each row's
-    ``prompt_len - 1``, pool, dropped_tokens).
+    ``prompt_len - 1``, pool, counters: ``PAGED_COUNTERS``).
     """
     from ..kernels.rope import rope_cos_sin
     B, Sb = ids.shape
@@ -881,13 +894,12 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
         if ll is not None:
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         h = h + d
-        h, drops = _ffn_tail(lp, h, cfg)
-        return h, (pz, drops)
+        return _ffn_tail(lp, h, cfg)[0], pz
 
-    x, (pool, drops) = lax.scan(body, x, _lora_xs(params, pool, lora))
+    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
     idx = jnp.maximum(prompt_lens - 1, 0)[:, None, None]
     last = jnp.take_along_axis(x, idx, axis=1)          # [B, 1, E]
-    return _lm_head(params, cfg, last), pool, drops.sum()
+    return _lm_head(params, cfg, last), pool, _lane_counts(B * Sb)
 
 
 def paged_prefill_chunk(params: Dict, cfg: LlamaConfig, ids, start,
@@ -916,7 +928,7 @@ def paged_prefill_chunk(params: Dict, cfg: LlamaConfig, ids, start,
     (see ``_masked_sdpa``), so outputs are bit-identical to the dense
     cache's regardless of the gather width. Returns (next-token logits
     ``[1, V]`` read at position ``start + chunk_len - 1``, pool,
-    dropped_tokens).
+    counters).
     """
     B, Sb = ids.shape
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
@@ -963,13 +975,12 @@ def paged_prefill_chunk(params: Dict, cfg: LlamaConfig, ids, start,
         if ll is not None:
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         h = h + d
-        h, drops = _ffn_tail(lp, h, cfg)
-        return h, (pz, drops)
+        return _ffn_tail(lp, h, cfg)[0], pz
 
-    x, (pool, drops) = lax.scan(body, x, _lora_xs(params, pool, lora))
+    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
     idx = jnp.full((B, 1, 1), jnp.maximum(chunk_len - 1, 0))
     last = jnp.take_along_axis(x, idx, axis=1)           # [1, 1, E]
-    return _lm_head(params, cfg, last), pool, drops.sum()
+    return _lm_head(params, cfg, last), pool, _lane_counts(B * Sb)
 
 
 def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
@@ -996,7 +1007,7 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
       long-context bandwidth win. STATIC: bake it per compiled program
       (``ServingConfig.paged_kernel`` / ``FLAGS_serving_paged_kernel``).
 
-    Returns (logits ``[M, V]``, pool, dropped_tokens).
+    Returns (logits ``[M, V]``, pool, counters).
     """
     M = tokens.shape[0]
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
@@ -1046,11 +1057,25 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
         if ll is not None:
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         h = h + d
-        h, drops = _ffn_tail(lp, h, cfg)
-        return h, (pz, drops)
+        return _ffn_tail(lp, h, cfg)[0], pz
 
-    x, (pool, drops) = lax.scan(body, x, _lora_xs(params, pool, lora))
-    return _lm_head(params, cfg, x), pool, drops.sum()
+    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
+    return _lm_head(params, cfg, x), pool, _lane_counts(M)
+
+
+def _mm_qkv(hh, lp, dt):
+    """``wq``, ``wk``, ``wv`` as ONE matmul against their concatenation
+    (int8 weights with their scales). Not for the matmul's sake: the TPU
+    compiler wants these three weights in another layout, and under a loop
+    around the layer scan it re-lays the whole stack before the loop (805
+    MB at 7B widths, resident for the program's length); the concatenation
+    is a layer's copy in whatever layout it likes, 48 MB."""
+    names = ("wq", "wk", "wv")
+    w = {"w": jnp.concatenate([lp[n] for n in names], axis=-1)}
+    if "wq_s" in lp:
+        w["w_s"] = jnp.concatenate([lp[n + "_s"] for n in names], axis=-1)
+    nq, nk = lp["wq"].shape[-1], lp["wk"].shape[-1]
+    return jnp.split(_mm(hh, w, "w", dt), [nq, nq + nk], axis=-1)
 
 
 def _lm_head_all(params: Dict, cfg: LlamaConfig, x):
@@ -1107,11 +1132,16 @@ def paged_spec_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
     with ``draft_lens``) — block tables consumed in-kernel, each live K/V
     page copied once and scored against the slot's ``draft_lens + 1``
     real query rows. Returns
-    (logits ``[M, Q, V]``, pool, dropped_tokens)."""
-    x, pool, drops = _paged_multiquery_forward(
+    (logits ``[M, Q, V]``, pool, counters).
+
+    The forward runs over all ``M x Q`` lanes IN PLACE (``Q = k + 1``,
+    nearly every lane real): :func:`_paged_multiquery_forward` with no
+    wave size."""
+    x, pool, counts = _paged_multiquery_forward(
         params, cfg, tokens, seq_lens, draft_lens, block_tables, pool,
         active, use_kernel, lora)
-    return _lm_head_all(params, cfg, x), pool, drops
+    M, Q = tokens.shape
+    return _lm_head_all(params, cfg, x.reshape(M, Q, -1)), pool, counts
 
 
 def paged_mixed_step(params: Dict, cfg: LlamaConfig, tokens, starts,
@@ -1122,41 +1152,72 @@ def paged_mixed_step(params: Dict, cfg: LlamaConfig, tokens, starts,
     (which slots are mid-prefill vs decoding this step) never retraces.
 
     ``tokens [M, Q]`` — row ``m`` holds ``q_lens[m] <= Q`` real tokens
-    (pad lanes repeat a real token; their K/V scatter is masked to the
-    null block); ``starts [M]`` — KV entries already committed for the
-    row (``num_computed`` for a mid-prefill prompt, ``seq_len`` for a
-    decoding slot). A decode slot is the ``q_lens == 1`` degenerate case
-    — exactly :func:`paged_decode_step`'s computation; a prefill chunk is
-    a ``q_lens == n`` row writing K/V for positions ``[starts, starts +
-    n)`` with query ``q`` attending ``j <= starts + q`` — exactly
-    :func:`paged_prefill_chunk`'s causal window. Both are the
-    ``draft_lens = q_lens - 1`` specialization of the speculative-verify
-    forward (:func:`paged_spec_step`), which is what this shares, so the
-    kernel's multi-query entry and the gather oracle serve all three
-    unchanged.
+    (pad lanes repeat a real token); ``starts [M]`` — KV entries already
+    committed for the row (``num_computed`` for a mid-prefill prompt,
+    ``seq_len`` for a decoding slot). A decode slot is the ``q_lens ==
+    1`` degenerate case — exactly :func:`paged_decode_step`'s computation;
+    a prefill chunk is a ``q_lens == n`` row writing K/V for positions
+    ``[starts, starts + n)`` with query ``q`` attending ``j <= starts +
+    q`` — exactly :func:`paged_prefill_chunk`'s causal window. Both are
+    the ``draft_lens = q_lens - 1`` specialization of the
+    speculative-verify forward (:func:`paged_spec_step`), which is what
+    this shares, so the kernel's multi-query entry and the gather oracle
+    serve all three unchanged.
 
-    Returns ``(logits [M, V], pool, dropped_tokens)`` where ``logits[m]``
-    is the next-token distribution after the row's LAST real token — a
+    **The step is packed** (:func:`_paged_multiquery_forward` with a wave
+    size): of the ``M x Q`` lanes the engine hands over only the real ones
+    go through the embedding, norms, projections, RoPE, the K/V scatter,
+    ``wo``, the FFN and the LoRA deltas, in waves of ``min(M x Q,
+    _WAVE_ROWS x M)`` lanes, one wave in steady state (a step's decoding
+    slots and a chunk or two). Attention alone keeps the ``[M, Q]`` row
+    view. The counters say what ran: ``lanes_computed`` = waves x their
+    lanes, ``mixed_waves``.
+
+    Returns ``(logits [M, V], pool, counters)`` where ``logits[m]`` is
+    the next-token distribution after the row's LAST real token — a
     decode slot's next sample, or a prompt-completing chunk's FIRST
     token, sampled in the same dispatch that finished its prefill."""
-    draft_lens = jnp.maximum(q_lens - 1, 0)
-    x, pool, drops = _paged_multiquery_forward(
-        params, cfg, tokens, starts, draft_lens, block_tables, pool,
-        active, use_kernel, lora)
-    last = jnp.take_along_axis(x, draft_lens[:, None, None], axis=1)
-    return _lm_head(params, cfg, last), pool, drops
+    M, Q = tokens.shape
+    x, pool, counts = _paged_multiquery_forward(
+        params, cfg, tokens, starts, jnp.maximum(q_lens - 1, 0),
+        block_tables, pool, active, use_kernel, lora,
+        wave_lanes=min(M * Q, _WAVE_ROWS * M))
+    return _lm_head(params, cfg, x[:, None]), pool, counts
 
 
 def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
                               seq_lens, draft_lens, block_tables,
                               pool: Dict, active, use_kernel: bool,
-                              lora):
+                              lora, wave_lanes: Optional[int] = None):
     """The multi-query decode iteration both :func:`paged_spec_step` and
-    :func:`paged_mixed_step` are views of: embed ``tokens [M, Q]``, write
-    K/V for every valid query position ``seq_lens + q`` (``q <=
-    draft_lens``), attend ``j <= seq_lens + min(q, draft_lens)``, and
-    return the hidden states ``[M, Q, E]`` (plus pool and MoE drops) —
-    the callers differ only in which positions they project to logits."""
+    :func:`paged_mixed_step` are views of, as ONE forward over query
+    LANES: lane ``t`` carries token ``tokens[row[t], q[t]]`` at position
+    ``seq_lens[row[t]] + q[t]``. Everything that is per token — embedding,
+    norms, ``wq``/``wk``/``wv``, RoPE, the K/V scatter, ``wo``, the FFN,
+    LoRA deltas (per-lane adapter ids) — runs on ``[lanes, E]``; attention
+    alone sees rows: the queries are laid into ``[M, Q, H, D]`` with the
+    ``(start, draft_len)`` of the lanes each row has there, the kernel or
+    the gather path runs as for any multi-query call, and the output rows
+    are taken back to lanes. A real lane (``q <= draft_lens[row]`` of an
+    active row) writes K/V at its position and attends ``j <=`` it; any
+    other writes to the null block and is read by no one.
+
+    Two callers set the lanes:
+
+    * ``wave_lanes=None`` — all ``M x Q`` lanes IN PLACE, one pass (the
+      verify step: nearly every lane is real). The row view is a reshape.
+      Returns the hidden state of every lane, ``x [M x Q, E]``.
+    * ``wave_lanes=Tw`` — PACKED (the mixed step): the real lanes, in
+      row-major order, run in WAVES of ``Tw`` under a ``lax.while_loop``
+      of ``ceil(real / Tw)`` trips, each wave the whole stack against the
+      pool. A wave may cut a row's chunk; the row's lanes in one wave are
+      a contiguous range of its positions, a lane's earlier positions sit
+      in the same or an earlier wave, and a wave scatters all its K/V of a
+      layer before any of its lanes attends there, so every position a
+      lane attends is in the pool by then. Returns the hidden state of
+      each row's LAST real lane, ``x [M, E]``.
+
+    Returns ``(x, pool, counters)``."""
     M, Q = tokens.shape
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
     D = cfg.head_dim
@@ -1164,56 +1225,115 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
     W = block_tables.shape[1]
     C = W * bs
     dt = cfg.dtype
-    qi = jnp.arange(Q)
-    pos = seq_lens[:, None] + qi[None, :]                # [M, Q] absolute
-    cos, sin = _row_tables(cfg, pos)
-    valid_q = (qi[None, :] <= draft_lens[:, None]) & active[:, None]
-    widx = jnp.minimum(pos // bs, W - 1)
-    phys = jnp.where(valid_q,
-                     jnp.take_along_axis(block_tables, widx, axis=1), 0)
-    off = pos % bs
-    jj = jnp.arange(C)[None, None, :]
-    # query q attends j <= seq_len + min(q, draft_len): its committed KV
-    # plus the in-pass draft prefix; garbage rows cap at draft_len so no
-    # row's mask ever reaches an unwritten position
-    qcap = jnp.minimum(qi[None, :], draft_lens[:, None])  # [M, Q]
-    kv_mask = jj <= (seq_lens[:, None] + qcap)[:, :, None]  # [M, Q, C]
+    packed = wave_lanes is not None
+    Tw = wave_lanes if packed else M * Q
+    Qa = min(Q, Tw)                    # the most lanes a row has in a wave
+    n_row = jnp.where(active, jnp.clip(draft_lens + 1, 0, Q),
+                      0).astype(jnp.int32)        # real lanes a row
+    if packed:
+        ends = jnp.cumsum(n_row)
+        begins, n_real = ends - n_row, ends[-1]
+    else:
+        begins = jnp.arange(M, dtype=jnp.int32) * Q
 
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-
-    def body(h, xs):
-        lp, pz, ll = _lora_unpack(xs)
-        hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.use_fused_norm)
-        q = _mm(hh, lp, "wq", dt)
-        k = _mm(hh, lp, "wk", dt)
-        v = _mm(hh, lp, "wv", dt)
-        if ll is not None:
-            lids = lora["ids"]
-            q = q + lora_delta(hh, ll["qA"], ll["qB"], lids, dt)
-            k = k + lora_delta(hh, ll["kA"], ll["kB"], lids, dt)
-            v = v + lora_delta(hh, ll["vA"], ll["vB"], lids, dt)
-        q = q.reshape(M, Q, H, D)
-        k = k.reshape(M, Q, Hk, D)
-        v = v.reshape(M, Q, Hk, D)
-        q = _rope(q, cos, sin, False)
-        k = _rope(k, cos, sin, False)
-        pz, _, _ = _kv_store(pz, phys, off, k, v)
-        if use_kernel:
-            from ..kernels.paged_attention import paged_attention
-            o = paged_attention(q, pz["k"], pz["v"], block_tables,
-                                seq_lens, draft_lens=draft_lens,
-                                k_scale=pz.get("k_scale"),
-                                v_scale=pz.get("v_scale"))
+    def wave(w, pool):
+        """The whole stack over wave ``w``'s lanes -> ``(x [Tw, E],
+        pool)``."""
+        lo = w * Tw
+        g = lo + jnp.arange(Tw, dtype=jnp.int32)
+        if packed:
+            row = jnp.minimum(jnp.searchsorted(ends, g, side="right"),
+                              M - 1).astype(jnp.int32)
+            real = g < n_real
+            qi = jnp.where(real, g - begins[row], 0)
         else:
-            kk, vv = _kv_gather(pz, block_tables, M, C, Hk, D)
-            o = _masked_sdpa(q, kk, vv, kv_mask)
-        m = _merge_heads(o, cfg).astype(dt)
-        d = _mm(m, lp, "wo", dt)
-        if ll is not None:
-            d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
-        h = h + d
-        h, drops = _ffn_tail(lp, h, cfg)
-        return h, (pz, drops)
+            row, qi = g // Q, g % Q
+            real = qi < n_row[row]
+        pos = seq_lens[row] + qi                         # [Tw] absolute
+        cos, sin = _row_tables(cfg, pos[None])           # [1, Tw, D]
+        phys = jnp.where(
+            real, block_tables[row, jnp.minimum(pos // bs, W - 1)], 0)[None]
+        off = (pos % bs)[None]
+        # the rows' view of the wave: row m has its lanes [a, a + n) here
+        a = jnp.clip(lo - begins, 0, n_row)
+        n = jnp.clip(lo + Tw - begins, 0, n_row) - a
+        att_start = jnp.where(n > 0, seq_lens + a, 0)
+        att_dl = jnp.maximum(n - 1, 0)
+        # the gather path's mask: query i attends j <= start + min(i,
+        # draft_len), its committed KV plus the in-pass prefix; garbage
+        # rows cap at draft_len so no row's mask ever reaches an unwritten
+        # position
+        kv_mask = None if use_kernel else jnp.arange(C)[None, None, :] <= (
+            att_start[:, None] + jnp.minimum(
+                jnp.arange(Qa)[None, :], att_dl[:, None]))[:, :, None]
+        lids = lora["ids"][row] if lora is not None and packed else None
 
-    x, (pool, drops) = lax.scan(body, x, _lora_xs(params, pool, lora))
-    return x, pool, drops.sum()
+        def delta(hh, ll, name):
+            la, lb = ll[name + "A"], ll[name + "B"]
+            if packed:                 # an adapter id a lane
+                return lora_delta(hh[0][:, None], la, lb, lids,
+                                  dt)[:, 0][None]
+            return lora_delta(hh.reshape(M, Q, -1), la, lb, lora["ids"],
+                              dt).reshape(1, Tw, -1)
+
+        def body(h, xs):
+            lp, pz, ll = _lora_unpack(xs)
+            hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                           cfg.use_fused_norm)
+            q, k, v = _mm_qkv(hh, lp, dt)
+            if ll is not None:
+                q = q + delta(hh, ll, "q")
+                k = k + delta(hh, ll, "k")
+                v = v + delta(hh, ll, "v")
+            q = _rope(q.reshape(1, Tw, H, D), cos, sin, False)
+            k = _rope(k.reshape(1, Tw, Hk, D), cos, sin, False)
+            pz, _, _ = _kv_store(pz, phys, off, k, v.reshape(1, Tw, Hk, D))
+            if packed:
+                # row m's lanes are contiguous in the wave: M slices of Qa
+                # lanes (past the wave's end: zeros no one reads)
+                qp = jnp.concatenate([q[0], jnp.zeros((Qa, H, D), q.dtype)])
+                q = jax.vmap(lambda s: lax.dynamic_slice_in_dim(qp, s, Qa))(
+                    jnp.clip(begins + a - lo, 0, Tw))
+            else:
+                q = q.reshape(M, Q, H, D)
+            if use_kernel:
+                from ..kernels.paged_attention import paged_attention
+                o = paged_attention(q, pz["k"], pz["v"], block_tables,
+                                    att_start, draft_lens=att_dl,
+                                    k_scale=pz.get("k_scale"),
+                                    v_scale=pz.get("v_scale"))
+            else:
+                kk, vv = _kv_gather(pz, block_tables, M, C, Hk, D)
+                o = _masked_sdpa(q, kk, vv, kv_mask)
+            if packed:
+                o = o.reshape(M * Qa, H, D)[
+                    row * Qa + jnp.clip(qi - a[row], 0, Qa - 1)]
+                o = jnp.where(real[:, None, None], o, 0)
+            m = _merge_heads(o.reshape(1, Tw, H, D), cfg).astype(dt)
+            d = _mm(m, lp, "wo", dt)
+            if ll is not None:
+                d = d + delta(m, ll, "o")
+            return _ffn_tail(lp, h + d, cfg)[0], pz
+
+        x = jnp.take(params["embed"], tokens[row, qi], axis=0).astype(dt)
+        x, pool = lax.scan(body, x[None], _lora_xs(params, pool, lora))
+        return x[0], pool
+
+    if not packed:
+        x, pool = wave(0, pool)
+        return x, pool, _lane_counts(M * Q)
+
+    # the lane of each row's last real token, among the real lanes
+    last = jnp.maximum(ends - 1, 0)
+
+    def step(carry):
+        w, pool, x_last = carry
+        x, pool = wave(w, pool)
+        mine = (n_row > 0) & (last // Tw == w)
+        return w + 1, pool, jnp.where(mine[:, None], x[last % Tw], x_last)
+
+    waves = (n_real + Tw - 1) // Tw
+    _, pool, x_last = lax.while_loop(
+        lambda carry: carry[0] < waves, step,
+        (jnp.int32(0), pool, jnp.zeros((M, cfg.hidden_size), dt)))
+    return x_last, pool, _lane_counts(waves * Tw, waves)

@@ -193,12 +193,53 @@ def _sat_cell_cases(cases, more_chunks=(), shards=(1,)):
             for Q in ((1,) + chunks if tp == 1 else chunks[:1])]
 
 
+def _sat_cell_mixed_step_case():
+    """The PACKED ``paged_mixed_step`` (ISSUE 28) around that kernel, at
+    the serving cell's shapes: 32 slots x a 128-token chunk, the pool's
+    3072 blocks, the cell's widths, ONE layer of its 16. What the waves
+    add to the program (a loop around the layer scan, the row view's
+    slices and gathers) meets the TPU's compiler here."""
+    from paddle_tpu.models import generation as G
+    from paddle_tpu.models.llama import LlamaConfig, init_params
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mistral-7b-v0.3-d16.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    cfg = LlamaConfig(
+        **{k: c[k] for k in (
+            "vocab_size", "hidden_size", "intermediate_size",
+            "num_attention_heads", "num_key_value_heads", "rms_norm_eps",
+            "rope_theta")}, num_hidden_layers=1,
+        max_position_embeddings=eng["max_model_len"], dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    M, Q, bs = eng["max_slots"], eng["prefill_chunk"], eng["block_size"]
+
+    def build():
+        i32 = jnp.int32
+        return ({"params": init_params(cfg, jax.random.key(0, impl="rbg")),
+                 "pool": G.init_paged_pool(cfg, eng["num_blocks"], bs),
+                 "tokens": jnp.zeros((M, Q), i32),
+                 "starts": jnp.zeros((M,), i32),
+                 "q_lens": jnp.ones((M,), i32),
+                 "tables": jnp.zeros((M, eng["max_model_len"] // bs), i32),
+                 "active": jnp.ones((M,), bool)},)
+
+    def step(o):
+        return G.paged_mixed_step(
+            o["params"], cfg, o["tokens"], o["starts"], o["q_lens"],
+            o["tables"], o["pool"], o["active"], use_kernel=True)
+
+    return (f"paged_mixed_step sat cell packed {M}x{Q}, one layer", step,
+            None, build)
+
+
 def test_every_kernel_lowers_for_tpu(monkeypatch):
     """The check that found ISSUE 21's refused paged-attention BlockSpecs
     from a machine with no chip: the Pallas TPU lowering runs in
     ``jax.export`` and rejects a tile the compiler cannot take."""
     cases = _preset_cases(monkeypatch)
-    for name, fn, _ref, build in cases + _sat_cell_cases(cases):
+    for name, fn, _ref, build in (cases + _sat_cell_cases(cases) +
+                                  [_sat_cell_mixed_step_case()]):
         exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
             *jax.eval_shape(build))
         assert "tpu_custom_call" in exported.mlir_module(), name
@@ -227,7 +268,8 @@ def test_every_kernel_compiles_for_v5e_without_a_chip(monkeypatch):
     cases = _preset_cases(monkeypatch)
     with jax.default_matmul_precision("default"):
         for name, fn, _ref, build in cases + _sat_cell_cases(
-                cases, more_chunks=(256,), shards=(1, 2, 4, 8)):
+                cases, more_chunks=(256,), shards=(1, 2, 4, 8)) + [
+                    _sat_cell_mixed_step_case()]:
             args = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=where),

@@ -337,3 +337,195 @@ class TestMixedDispatchShape:
         assert a == b
         assert st["mixed_dispatches"] > 0
         assert st["mixed_traces"] == 1         # first mixed use traces it
+
+
+# ---------------------------------------------------------------------------
+# the packed step itself (ISSUE 28), against the plain form kept HERE
+# ---------------------------------------------------------------------------
+
+def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
+                      pool, active, use_kernel=False, lora=None):
+    """``paged_mixed_step`` as it stood before it was packed: every
+    matmul over all ``[M, Q]`` lanes. The oracle of the packed step."""
+    from paddle_tpu.models.llama import _masked_sdpa, _mm, _rms_norm, _rope
+    from paddle_tpu.models.lora import lora_delta
+    M, Q = tokens.shape
+    H, Hk = G._local_heads(cfg, pool)
+    D, dt = cfg.head_dim, cfg.dtype
+    bs, W = pool["k"].shape[2], block_tables.shape[1]
+    C = W * bs
+    draft_lens = jnp.maximum(q_lens - 1, 0)
+    qi = jnp.arange(Q)
+    pos = starts[:, None] + qi[None, :]
+    cos, sin = G._row_tables(cfg, pos)
+    valid_q = (qi[None, :] <= draft_lens[:, None]) & active[:, None]
+    widx = jnp.minimum(pos // bs, W - 1)
+    phys = jnp.where(valid_q,
+                     jnp.take_along_axis(block_tables, widx, axis=1), 0)
+    off = pos % bs
+    qcap = jnp.minimum(qi[None, :], draft_lens[:, None])
+    kv_mask = jnp.arange(C)[None, None, :] <= (starts[:, None] +
+                                               qcap)[:, :, None]
+    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+
+    def body(h, xs):
+        lp, pz, ll = G._lora_unpack(xs)
+        hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                       cfg.use_fused_norm)
+        q, k, v = (_mm(hh, lp, n, dt) for n in ("wq", "wk", "wv"))
+        if ll is not None:
+            lids = lora["ids"]
+            q = q + lora_delta(hh, ll["qA"], ll["qB"], lids, dt)
+            k = k + lora_delta(hh, ll["kA"], ll["kB"], lids, dt)
+            v = v + lora_delta(hh, ll["vA"], ll["vB"], lids, dt)
+        q = _rope(q.reshape(M, Q, H, D), cos, sin, False)
+        k = _rope(k.reshape(M, Q, Hk, D), cos, sin, False)
+        pz, _, _ = G._kv_store(pz, phys, off, k, v.reshape(M, Q, Hk, D))
+        if use_kernel:
+            from paddle_tpu.kernels.paged_attention import paged_attention
+            o = paged_attention(q, pz["k"], pz["v"], block_tables, starts,
+                                draft_lens=draft_lens,
+                                k_scale=pz.get("k_scale"),
+                                v_scale=pz.get("v_scale"))
+        else:
+            kk, vv = G._kv_gather(pz, block_tables, M, C, Hk, D)
+            o = _masked_sdpa(q, kk, vv, kv_mask)
+        m = G._merge_heads(o, cfg).astype(dt)
+        d = _mm(m, lp, "wo", dt)
+        if ll is not None:
+            d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
+        return G._ffn_tail(lp, h + d, cfg)[0], pz
+
+    x, pool = jax.lax.scan(body, x, G._lora_xs(params, pool, lora))
+    last = jnp.take_along_axis(x, draft_lens[:, None, None], axis=1)
+    return G._lm_head(params, cfg, last), pool
+
+
+# M = 3 slots x Q = 32: a wave is min(96, 16 x 3) = 48 lanes, two at most.
+# name -> (starts, q_lens, active, adapter ids or None, waves)
+PACKED_CASES = {
+    "all_rows_decoding": ([5, 17, 30], [1, 1, 1], [1, 1, 1], None, 1),
+    "one_chunk_beside_decoding_rows":
+        ([9, 8, 3], [1, 20, 1], [1, 1, 1], None, 1),
+    "more_real_lanes_than_one_wave":          # 49: the cut between rows
+        ([0, 4, 7], [32, 16, 1], [1, 1, 1], None, 2),
+    "a_chunk_cut_by_a_waves_edge":            # row 1: 16 lanes | 4 lanes
+        ([8, 12, 40], [32, 20, 1], [1, 1, 1], None, 2),
+    "an_inactive_row_and_full_chunks":        # row 2: 16 lanes | 16 lanes
+        ([0, 3, 16], [32, 5, 32], [1, 0, 1], None, 2),
+    "an_adapter_a_row": ([2, 11, 6], [20, 1, 32], [1, 1, 1], [1, 0, 2], 2),
+}
+_PACKED_M, _PACKED_Q, _PACKED_BS, _PACKED_W = 3, 32, 4, 18
+_PACKED_FNS = {}
+
+
+# pool kind -> (the model's dtype, the pool's quantisation, what the two
+# forms may differ by on the logits and on the pool's entries). A matmul
+# over other rows rounds its sums in another order (1e-6 in float32, a
+# last bit of bfloat16 that the layers carry on); an int8 entry next to a
+# rounding edge may then land one step away.
+PACKED_POOLS = {"f32": (jnp.float32, None, 3e-5, 3e-5),
+                "bf16": (jnp.bfloat16, None, 0.1, 0.1),
+                "int8": (jnp.float32, "int8", 5e-3, 1)}
+
+
+def _packed_fixture(pool_kind, use_kernel, with_lora):
+    """The model, a pool full of history, and both jitted forms, built
+    once a (pool kind, attention path, adapters or none)."""
+    key = (pool_kind, use_kernel, with_lora)
+    if key in _PACKED_FNS:
+        return _PACKED_FNS[key]
+    dtype, kv_quant = PACKED_POOLS[pool_kind][:2]
+    cfg = tiny_cfg(dtype=dtype)
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    M, bs, W = _PACKED_M, _PACKED_BS, _PACKED_W
+    pool = G.init_paged_pool(cfg, 1 + M * W, bs, kv_quant=kv_quant)
+    rng = np.random.default_rng(7)
+    # every block holds finite history, as after earlier dispatches
+    pool = {n: (jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+                if a.dtype == jnp.int8 else
+                jnp.asarray(rng.uniform(0.01, 0.03, a.shape), a.dtype)
+                if n.endswith("_scale") else
+                jnp.asarray(rng.standard_normal(a.shape), a.dtype))
+            for n, a in pool.items()}
+    tables = jnp.asarray(1 + np.arange(M * W).reshape(M, W), jnp.int32)
+    layers = None
+    if with_lora:
+        layers = {}
+        for n, s in lora_init_params(cfg, 4).items():
+            stack = rng.standard_normal((s.shape[0], 3) + s.shape[1:]) * 0.3
+            stack[:, 0] = 0                   # slot 0: the base adapter
+            layers[n] = jnp.asarray(stack, jnp.float32)
+
+    def run(step):
+        def fn(tokens, starts, q_lens, active, ids):
+            lora = {"ids": ids, "layers": layers} if with_lora else None
+            return step(params, cfg, tokens, starts, q_lens, tables, pool,
+                        active, use_kernel=use_kernel, lora=lora)
+        return jax.jit(fn)
+
+    _PACKED_FNS[key] = (cfg, run(_plain_mixed_step),
+                        run(G.paged_mixed_step))
+    return _PACKED_FNS[key]
+
+
+class TestPackedMixedStep:
+    @pytest.mark.parametrize("pool_kind", sorted(PACKED_POOLS))
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    @pytest.mark.parametrize("case", sorted(PACKED_CASES))
+    def test_logits_and_pool_equal_the_unpacked_forwards(
+            self, case, use_kernel, pool_kind):
+        starts, q_lens, active, ids, waves = PACKED_CASES[case]
+        cfg, plain, packed = _packed_fixture(pool_kind, use_kernel,
+                                             ids is not None)
+        logit_tol, pool_tol = PACKED_POOLS[pool_kind][2:]
+        M, Q = _PACKED_M, _PACKED_Q
+        rng = np.random.default_rng(len(case))
+        ops = (jnp.asarray(rng.integers(0, cfg.vocab_size, (M, Q)),
+                           jnp.int32),
+               jnp.asarray(starts, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+               jnp.asarray(active, bool),
+               jnp.asarray(ids if ids is not None else [0] * M, jnp.int32))
+        want, want_pool = plain(*ops)
+        got, got_pool, counts = packed(*ops)
+        rows = np.flatnonzero(active)
+        np.testing.assert_allclose(np.asarray(got)[rows],
+                                   np.asarray(want)[rows], rtol=0,
+                                   atol=logit_tol)
+        # the null block (0) takes the pad lanes' writes: any lane's
+        for n in want_pool:
+            np.testing.assert_allclose(
+                np.asarray(got_pool[n][:, 1:], np.float32),
+                np.asarray(want_pool[n][:, 1:], np.float32), rtol=1e-5,
+                atol=1e-5 if n.endswith("_scale") else pool_tol, err_msg=n)
+        Tw = min(M * Q, G._WAVE_ROWS * M)
+        assert dict(zip(G.PAGED_COUNTERS, np.asarray(counts).tolist())) == {
+            "lanes_computed": waves * Tw, "mixed_waves": waves}
+
+    def test_engine_counts_the_lanes_its_waves_ran(self):
+        """M = 2, chunks of 32: a wave is 32 lanes. Two prompts of whole
+        chunks keep every mixed step in the Q = 32 bucket; while both
+        prefill a step holds 64 real lanes, two waves. Token streams equal
+        the two-phase engine's."""
+        cfg = tiny_cfg(max_position_embeddings=160)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, 97, (n,)).astype(np.int32)
+                   for n in (96, 64, 64)]
+        outs = [5, 3, 4]
+        kw = dict(max_slots=2, max_model_len=128, prefill_chunk=32,
+                  prefix_cache=None)
+        want, _ = drain_streams(mk(params, cfg, False, **kw), prompts, outs)
+        eng = mk(params, cfg, True, **kw)
+        got, st = drain_streams(eng, prompts, outs)
+        assert got == want
+        c = st["spans"]["counters"]
+        assert c["mixed_lanes_total"] == 32 * c["mixed_waves"]
+        assert c["mixed_waves"] > st["mixed_dispatches"] > 0
+        assert 0 < c["mixed_lanes_real"] <= c["mixed_lanes_total"]
+        # every dispatch's lanes: the mixed steps' and the decode loop's
+        assert c["lanes_computed"] == (c["mixed_lanes_total"] +
+                                       2 * c["decode_iterations"])
+        assert eng.health_snapshot()["family"] == {
+            "lanes_computed": c["lanes_computed"],
+            "mixed_waves": c["mixed_waves"]}
