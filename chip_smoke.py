@@ -2,15 +2,15 @@
 
 One process, no child, no network, no CPU mode. It drives both front doors
 of the repo once, at the full width of the one model with a chip history
-(``bench._presets("tpu")``: the 738M ``llama_ratio`` preset), on seeded
-random weights:
+(:func:`preset`: the 738M ``llama_ratio`` model of ``BASELINE.md``), on
+seeded random weights:
 
 1. **kernels** — every Pallas kernel a default or flag-reachable path can
    dispatch, compiled natively and compared with its ``jax.numpy``
    reference, so a compile refusal names the kernel instead of surfacing
    minutes later inside an engine;
 2. **trainer** — ``llama.make_train_step`` through ``jit_step`` with
-   donation, the path ``bench.py`` and ``examples/train_llama.py`` use;
+   donation, the path ``examples/train_llama.py`` uses;
 3. **server** — ``ServingServer(EngineSupervisor(params, cfg,
    ServingConfig()))`` with every serving default, 16 concurrent streams;
 4. **server_int8** — a second, small engine with an int8 KV pool and int8
@@ -40,6 +40,20 @@ import importlib.metadata
 import json
 import sys
 import time
+
+
+def preset():
+    """``(cfg, batch, seq)`` of the 738M ``llama_ratio`` model: LLaMA-7B's
+    shape ratios (I/E = 2.6875) at twelve layers."""
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(
+        vocab_size=32000, hidden_size=2048, intermediate_size=5504,
+        num_hidden_layers=12, num_attention_heads=16, num_key_value_heads=16,
+        max_position_embeddings=2048, use_kernels=True, remat=True,
+        dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    return cfg, 8, 2048
+
 
 # ---------------------------------------------------------------------------
 # tolerances, stated once with their reasons
@@ -503,8 +517,8 @@ def serve(params, cfg, serving_config, requests):
     eng = sup.engine
     st = eng.stats()
     row = {k: st[k] for k in ("paged_kernel", "decode_traces", "mixed_traces",
-                              "mixed_dispatches", "chunk_prefill_traces",
-                              "prefill_traces", "prefix_hit_tokens",
+                              "mixed_dispatches", "prefill_traces",
+                              "prefix_hit_tokens",
                               "kv_quant", "tp_degree", "kv_pool_mb")}
     row.update(restarts=sup.restarts,
                blocks_in_use=eng.cache.manager.blocks_in_use,
@@ -522,7 +536,6 @@ def serve(params, cfg, serving_config, requests):
         "decode_traces<=1": row["decode_traces"] <= 1,
         "mixed_traces==executables": row["mixed_traces"] == mixed_execs >= 1,
         "mixed_dispatches>=1": row["mixed_dispatches"] >= 1,
-        "chunk_prefill_traces==0": row["chunk_prefill_traces"] == 0,
         "blocks_in_use==0": row["blocks_in_use"] == 0,
         "audit clean": row["audit_violations"] == 0,
     }
@@ -660,7 +673,6 @@ def main() -> None:
                  f"'tpu'; this script has no CPU mode")
 
     import jaxlib
-    import bench
     from paddle_tpu.inference.serving import ServingConfig
     from paddle_tpu.jit import enable_compile_cache
     cache_dir = enable_compile_cache()
@@ -669,7 +681,7 @@ def main() -> None:
          libtpu=importlib.metadata.version("libtpu"),
          compile_cache_dir=cache_dir)
 
-    cfg, batch, seq = bench._presets("tpu")          # the 738M llama_ratio
+    cfg, batch, seq = preset()
     sc = ServingConfig()                             # every serving default
     sizes = dict(lengths=(32, 96, 300, 700), prefix=64, new_tokens=(16, 48))
 

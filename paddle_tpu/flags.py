@@ -161,8 +161,7 @@ define_flag("FLAGS_emergency_ckpt_deadline_s", 10.0,
 define_flag("FLAGS_health_sentinel", False,
             "Default for TrainStep/Model.prepare's sentinel knob: fuse the "
             "on-device NaN/Inf/loss-spike detector into the train step and "
-            "skip bad updates (jnp.where-gated; overhead tracked by bench "
-            "--health as health_sentinel_overhead_pct).", bool)
+            "skip bad updates (jnp.where-gated).", bool)
 define_flag("FLAGS_health_spike_factor", 0.0,
             "Loss-spike threshold: a step is bad when loss > factor * |EMA| "
             "(after FLAGS_health_spike_warmup good steps). 0 disables the "
@@ -222,8 +221,8 @@ define_flag("FLAGS_serving_queue_depth", 128,
             int)
 define_flag("FLAGS_serving_decode_chunk", 8,
             "Cap on decode iterations per device dispatch when a live "
-            "request can retire EARLY (EOS enabled), a prompt is "
-            "mid-chunked-prefill, or the caller streams token events. "
+            "request can retire EARLY (EOS enabled) or the caller "
+            "streams token events. "
             "Otherwise dispatches are schedule-sized: run to the next "
             "budget retirement (queue waiting) or drain the tail in one "
             "dispatch (queue empty) — the bound is a device scalar, so "
@@ -238,22 +237,11 @@ define_flag("FLAGS_serving_prefix_cache", True,
             "None/False) disables per engine.", bool)
 define_flag("FLAGS_serving_prefill_chunk", 256,
             "Chunked prefill: prompts longer than this prefill in chunks "
-            "of this many tokens interleaved with decode dispatches, so a "
-            "long admission no longer freezes in-flight streams. 0 "
+            "of this many tokens, each riding the decode dispatch as extra "
+            "query rows of ONE mixed step, so a long admission never "
+            "freezes in-flight streams. 0 "
             "disables (whole prompt in one dispatch); ServingConfig("
             "prefill_chunk=None) disables per engine.", int)
-define_flag("FLAGS_serving_mixed_batch", True,
-            "Stall-free mixed batching (ServingConfig.mixed_batch): "
-            "mid-flight prefill chunks ride the decode dispatch as "
-            "extra query rows of ONE mixed multi-query step — per-row "
-            "start/q_len are device operands, so role churn never "
-            "retraces — instead of each prompt running its own B=1 "
-            "chunk dispatch before a separate (decode_chunk-clamped) "
-            "decode dispatch. Decode rows advance every step a prompt "
-            "prefills, and the chunk that completes a prompt samples "
-            "its first token in the same dispatch. Token streams are "
-            "bit-identical either way; False restores the two-phase "
-            "path (the parity oracle).", bool)
 define_flag("FLAGS_serving_preempt", True,
             "On-demand KV paging: a sequence holds only the blocks it has "
             "filled, and when the pool runs dry the newest-admitted "

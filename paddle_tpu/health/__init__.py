@@ -19,7 +19,7 @@ frozen rank hangs the job until a human notices. Three layers:
 
 Surfaces: ``jit.train_step.TrainStep(sentinel=...)`` /
 ``Model.prepare(sentinel=...)``, the ``callbacks.AnomalyMonitor`` hapi
-callback, ``FLAGS_health_*`` flags, ``bench.py --health``, and the
+callback, ``FLAGS_health_*`` flags, and the
 ``nan_payload`` / ``bad_sample`` / ``dead_worker`` chaos injectors.
 """
 

@@ -19,9 +19,9 @@ replicas sharing one set of params and one compiled
 prefix/tenant affinity, cross-replica failover (bit-exact resume from
 delivered tokens), per-replica :class:`CircuitBreaker`\\ s, hedged
 retries, autoscale actuation and rolling restarts (docs/OPS.md "Serving
-fleet"). Benchmarked by ``bench.py --serve`` against the static-batch
-``generate()`` baseline and driven through hostile-traffic faults by
-``testing.chaos``'s serving injectors. The fleet-scale proof layer
+fleet"). Measured on the chip by ``benchmark/run.py``'s serving cells and
+driven through hostile-traffic faults by ``testing.chaos``'s serving
+injectors. The fleet-scale proof layer
 (ISSUE 13) sits across all of it: :class:`InvariantAuditor` — one
 registry of named invariants (``AUDIT_CHECKS``) replacing the asserts
 scattered through the test suite, surfaced in production via

@@ -14,8 +14,8 @@ Design (docs/SERVING.md):
   returns exactly when the first live slot exhausts its budget, so
   retirement/admission happen with zero idle iterations; with the queue
   empty one dispatch drains the whole tail. ``decode_chunk`` caps the
-  bound only when a live slot can retire EARLY (EOS enabled), a prompt is
-  mid-chunked-prefill, or the caller streams (token granularity).
+  bound only when a live slot can retire EARLY (EOS enabled) or the
+  caller streams (token granularity).
 * **On-demand paged KV + preemption.** A sequence holds only the blocks
   covering KV it has actually written: admission allocates the prompt's
   blocks (prefix-cache hits are MAPPED, not recomputed), decode extends
@@ -31,13 +31,14 @@ Design (docs/SERVING.md):
   few-shot prefix map the cached blocks and prefill only their suffix.
   Refcount-0 blocks stay cached on an LRU list until allocation pressure
   evicts them. ``prefix_cache=False`` disables.
-* **Chunked prefill.** Prompts longer than ``prefill_chunk`` prefill in
-  fixed-size chunks (``models.generation.paged_prefill_chunk`` — offset
-  and length are device scalars) interleaved with decode dispatches, so a
-  long admission no longer freezes in-flight streams. Short cold prompts
-  still take the BATCHED bucketed prefill: one dispatch per power-of-2
-  length bucket with the batch dim padded to the power-of-2 bucket of the
-  admission-wave size.
+* **Chunked prefill in the mixed step.** Prompts longer than
+  ``prefill_chunk``, prefix-cache hits and readmissions prefill in
+  fixed-size chunks that ride the decode dispatch as ``q_len > 1`` rows
+  of ONE mixed step (the family's ``paged_mixed_step`` — per-row offset
+  and length are device operands), so a long admission never freezes
+  in-flight streams. Short cold prompts take the BATCHED bucketed
+  prefill: one dispatch per power-of-2 length bucket with the batch dim
+  padded to the power-of-2 bucket of the admission-wave size.
 * **Overload-safe lifecycle + policy scheduling.** Every request ends in
   exactly one terminal state (``finished`` / ``cancelled`` /
   ``timed_out`` / ``shed``): ``cancel(rid)`` and per-request
@@ -294,7 +295,6 @@ class EnginePrograms:
     it: decode_traces must not grow across a restart)."""
 
     prefill: Any
-    chunk: Any
     decode: Any
     spec: Any           # speculative verify (multi-query decode) program
     sample: Any         # first-token sampler (prefill-logits -> token)
@@ -309,11 +309,7 @@ class EnginePrograms:
     #                     None when no embed model is attached
     mixed: Any = None   # mixed prefill+decode step (ISSUE 20): per-row
     #                     start/q_len device operands, so one executable
-    #                     per Q bucket serves every role mix. Built with
-    #                     the others regardless of ServingConfig.
-    #                     mixed_batch (the flag gates DISPATCH, not
-    #                     shapes), so engines on either side of the flag
-    #                     share one program set
+    #                     per Q bucket serves every role mix
 
 
 @dataclasses.dataclass
@@ -361,14 +357,6 @@ class ServingConfig:
     prefix_cache: Any = _UNSET       # bool; None/False = off
     prefill_chunk: Any = _UNSET      # tokens/chunk; None/0 = whole prompt
     preempt: Any = _UNSET            # bool; None/False = legacy reservation
-    mixed_batch: Any = _UNSET        # bool (ISSUE 20): mid-flight prefill
-    #                                  chunks ride the decode dispatch as
-    #                                  extra query rows of ONE mixed step;
-    #                                  None/False = the two-phase path
-    #                                  (chunk dispatches before a clamped
-    #                                  decode dispatch — the parity
-    #                                  oracle); unset ->
-    #                                  FLAGS_serving_mixed_batch
     # speculative decoding (ISSUE 11)
     spec_decode: Any = _UNSET        # draft tokens per verify dispatch
     #                                  (n-gram prompt lookup); None/0 =
@@ -439,10 +427,6 @@ class ServingConfig:
             self.preempt = bool(flag("FLAGS_serving_preempt"))
         else:
             self.preempt = bool(self.preempt)
-        if self.mixed_batch == _UNSET:
-            self.mixed_batch = bool(flag("FLAGS_serving_mixed_batch"))
-        else:
-            self.mixed_batch = bool(self.mixed_batch)
         if self.prefill_chunk == _UNSET:
             self.prefill_chunk = int(flag("FLAGS_serving_prefill_chunk"))
         self.prefill_chunk = (int(self.prefill_chunk)
@@ -531,7 +515,6 @@ class ServingEngine:
         self._family = paged_family(model_config)
         self._family.validate_serving(model_config, self.config)
         self._counter_names = tuple(self._family.PAGED_COUNTERS)
-        self._uncounted: List[Any] = []   # counters of dispatches in flight
         # the served model's widths and counts under its family's names
         # (what turns the family's counters into bytes and shares); None
         # for a family that describes nothing
@@ -652,15 +635,15 @@ class ServingEngine:
             # one place across rebuilds, proving recovery never retraces
             self._stats = programs.stats
             self._prefill_buckets = programs.prefill_buckets
-            self._jprefill, self._jchunk, self._jdecode = (
-                programs.prefill, programs.chunk, programs.decode)
+            self._jprefill, self._jdecode = (programs.prefill,
+                                             programs.decode)
             self._jspec, self._jsample = programs.spec, programs.sample
             self._jembed = programs.embed
             self._jmixed = programs.mixed
             self.programs = programs
         else:
             self._stats = {"decode_traces": 0, "prefill_traces": 0,
-                           "chunk_prefill_traces": 0, "chunks": 0,
+                           "chunks": 0,
                            "steps": 0, "spec_traces": 0,
                            "sample_traces": 0, "spec_steps": 0,
                            "embed_traces": 0, "embeds": 0,
@@ -668,13 +651,13 @@ class ServingEngine:
                            "decode_dispatches": 0, "mixed_dispatches": 0,
                            "spec_dispatches": 0}
             self._prefill_buckets = set()
-            (self._jprefill, self._jchunk, self._jdecode, self._jspec,
-             self._jsample, self._jmixed) = self._build(jax)
+            (self._jprefill, self._jdecode, self._jspec, self._jsample,
+             self._jmixed) = self._build(jax)
             self._jembed = (self._build_embed(jax)
                             if self._embed_params is not None else None)
             self.programs = EnginePrograms(
-                self._jprefill, self._jchunk, self._jdecode, self._jspec,
-                self._jsample, self._stats, self._prefill_buckets, key,
+                self._jprefill, self._jdecode, self._jspec, self._jsample,
+                self._stats, self._prefill_buckets, key,
                 embed=self._jembed, mixed=self._jmixed)
         # per-dispatch wall-time observability (ISSUE 20): bounded recent
         # windows per dispatch KIND, feeding the p50/p99 rows stats() and
@@ -725,13 +708,6 @@ class ServingEngine:
             return F.paged_prefill(params, cfg, ids, prompt_lens,
                                    block_tables, pool, active, lora=lora,
                                    use_kernel=use_kernel)
-
-        def chunk_fn(params, ids, start, chunk_len, block_tables, pool,
-                     lora):
-            stats["chunk_prefill_traces"] += 1     # trace-time only
-            return F.paged_prefill_chunk(params, cfg, ids, start, chunk_len,
-                                         block_tables, pool, lora=lora,
-                                         use_kernel=use_kernel)
 
         def _next_tokens(logits, keys, sample_idx, temp, topk, topp):
             """One compiled sampling step over per-slot DEVICE operands:
@@ -859,7 +835,6 @@ class ServingEngine:
             # TP the shard_map arity) is exactly the LoRA-less engine's
             import functools
             prefill_fn = functools.partial(prefill_fn, lora=None)
-            chunk_fn = functools.partial(chunk_fn, lora=None)
             decode_fn = functools.partial(decode_fn, lora=None)
             spec_fn = functools.partial(spec_fn, lora=None)
             mixed_fn = functools.partial(mixed_fn, lora=None)
@@ -896,9 +871,6 @@ class ServingEngine:
             prefill_fn = shard_map(prefill_fn, mesh=self._mesh,
                                    in_specs=(ps, R, R, R, zs, R) + ls,
                                    out_specs=(R, zs, R), check_vma=False)
-            chunk_fn = shard_map(chunk_fn, mesh=self._mesh,
-                                 in_specs=(ps, R, R, R, R, zs) + ls,
-                                 out_specs=(R, zs, R), check_vma=False)
             decode_fn = shard_map(decode_fn, mesh=self._mesh,
                                   in_specs=(ps, zs) + (R,) * 12 + ls,
                                   out_specs=(zs, R, R, R, R, R) + cs,
@@ -916,12 +888,11 @@ class ServingEngine:
                            donate_argnums=donated if donate else ())
 
         jpre = jit("paged_prefill", prefill_fn, 4)
-        jchk = jit("paged_chunk", chunk_fn, 5)
         jdec = jit("paged_decode", decode_fn, 1)
         jspec = jit("paged_spec", spec_fn, 1)
         jmix = jit("paged_mixed", mixed_fn, 1)
         jsamp = jit("sample_tokens", sample_fn)
-        return jpre, jchk, jdec, jspec, jsamp, jmix
+        return jpre, jdec, jspec, jsamp, jmix
 
     def _build_embed(self, jax):
         """The prefill-only embeddings program (ISSUE 19): one jitted
@@ -963,34 +934,26 @@ class ServingEngine:
         """Count + time ONE device dispatch by kind (ISSUE 20), from the
         ``serve:dispatch`` span's start to the ``serve:fetch`` span's
         end (the same two ``perf_counter`` stamps, no second pair). Every
-        dispatch — batched prefill, prefill chunk, embed encode, decode
-        loop, mixed step, spec verify — lands here, so ``chunks`` is the
-        true all-kinds dispatch total (it previously only counted
-        decode/verify dispatches: a prefill-only step reported zero
-        dispatch work), the per-kind ``*_dispatches`` counters split it,
+        dispatch — batched prefill, embed encode, decode loop, mixed
+        step, spec verify — lands here, so ``chunks`` is the all-kinds
+        dispatch total, the per-kind ``*_dispatches`` counters split it,
         and the wall time feeds the bounded window behind the p50/p99
         dispatch-latency rows in stats()/health_snapshot()."""
         self._stats["chunks"] += 1
         self._stats[kind + "_dispatches"] += 1
         self._dispatch_ms[kind].append((t1 - t0) * 1e3)
 
-    def _count_dispatch(self, aux=None, fetch: bool = True) -> Dict[str, int]:
+    def _count_dispatch(self, aux=None) -> Dict[str, int]:
         """Add one dispatch's device counters (the small array a counting
         family's program returns, fetched here inside the ``serve:fetch``
         the dispatch makes anyway) to the span aggregator under the
-        family's names, and return them. ``fetch=False`` is for a dispatch
-        that is left in flight: its counters wait for the next fetch. A
-        family that counts nothing passes nothing."""
+        family's names, and return them. A family that counts nothing
+        passes nothing."""
         if aux is None or not self._counter_names:
             return {}
-        if not fetch:
-            self._uncounted.append(aux)
-            return {}
         counts = dict(zip(self._counter_names, np.asarray(aux).tolist()))
-        late = [np.asarray(a).tolist() for a in self._uncounted]
-        self._uncounted.clear()
-        for name, *ns in zip(self._counter_names, counts.values(), *late):
-            self.spans.count(name, sum(ns))
+        for name, n in counts.items():
+            self.spans.count(name, n)
         return counts
 
     def _dispatch_latency(self) -> Dict[str, Dict[str, float]]:
@@ -1488,7 +1451,7 @@ class ServingEngine:
             entries = int(kv["entries"])
             if kv["prefilling"]:
                 # resume the chunked prefill exactly at its chunk offset:
-                # _advance_prefills picks the slot up next step
+                # the next step's mixed dispatch picks the slot up
                 req.prefill_ids = req.build_prefill_ids()
                 req.num_computed = entries
             else:
@@ -1782,7 +1745,7 @@ class ServingEngine:
         dispatch per power-of-2 length bucket, batch dim padded to the
         wave-size bucket); prefix-cache hits (prefill starts at an
         offset), long prompts (chunked), and readmissions (recompute) go
-        through the offset chunk path / the mixed step, one row each.
+        through the mixed step, one row each.
         A request's FIRST admission lands its queue wait (enqueue ->
         admit, the wait in the server's command queue included) in the
         ``queue_wait_s`` histogram."""
@@ -1890,55 +1853,6 @@ class ServingEngine:
                 self._stats["embeds"] += 1
                 self._sched.finish(req)
 
-    def _advance_prefills(self, emitted: Dict[int, List[int]]) -> None:
-        """One prefill chunk per mid-prefill slot (offset path, B=1):
-        long admissions make progress WITHOUT freezing the decode slots —
-        the decode dispatch between chunks is what kills head-of-line
-        pressure. Completing requests emit their first token (fresh) or
-        resume from their kept tokens (post-preemption recompute)."""
-        import jax.numpy as jnp
-        chunk = self.config.prefill_chunk
-        for req in [r for r in self._sched.live if r.prefilling]:
-            with self._span("serve:operands", "chunk"):
-                total = len(req.prefill_ids)
-                n = total - req.num_computed
-                if chunk is not None:
-                    n = min(n, chunk)
-                Sb = self._bucket(n)
-                ids = np.zeros((1, Sb), np.int32)
-                ids[0, :n] = req.prefill_ids[req.num_computed:
-                                             req.num_computed + n]
-                ops = (jnp.asarray(ids),
-                       jnp.asarray(req.num_computed, jnp.int32),
-                       jnp.asarray(n, jnp.int32),
-                       jnp.asarray(self.cache.tables[req.slot][None]))
-                lora = self._lora_operand([req.adapter_slot])
-            # only the chunk that completes a FRESH prompt has a token
-            # to fetch; the others leave their dispatch in flight
-            last = req.num_computed + n >= total and not req.tokens
-            with _watchdog.section("serving.prefill"):
-                with self._span("serve:dispatch", "chunk") as d:
-                    logits, self.cache.pool, aux = self._jchunk(
-                        self._params, *ops, self.cache.pool, *lora)
-                with self._span("serve:fetch", "chunk") as f:
-                    tok0 = (int(self._first_tokens(logits, [req], 1)[0])
-                            if last else None)
-                    self._count_dispatch(aux, fetch=last)
-            with self._span("serve:commit", "chunk"):
-                self._record_dispatch("prefill", d.t0, f.t1)
-                self.spans.count("prefill_tokens", n)
-                req.num_computed += n
-                req.reg_state = self.cache.register_prefix(
-                    req.prefill_ids, req.blocks, req.num_computed,
-                    req.reg_state, tenant=req.tenant,
-                    namespace=req.adapter_id)
-                if req.prefilling:
-                    continue                      # more chunks to go
-                if req.tokens:                    # readmission: resume
-                    self._start_decode(req)
-                else:
-                    self._emit_first(req, tok0, time.time(), emitted)
-
     def _first_tokens(self, logits, group, Bb: int) -> np.ndarray:
         """Sample each admitted request's FIRST token (sample index 0)
         from its prefill logits. All-greedy waves take the literal host
@@ -1966,24 +1880,19 @@ class ServingEngine:
     # ---- decode dispatch sizing -------------------------------------------
 
     def _limit(self, decoding, max_iters: Optional[int]) -> int:
-        """Iterations for the next decode dispatch. Queue waiting or a
-        prompt mid-chunked-prefill: run to the FIRST budget retirement
-        (admit with zero idle iterations) and cap at ``decode_chunk`` so
-        prefill chunks interleave. Queue empty: drain the whole tail in
-        one dispatch (the in-graph alive-mask exit handles rows finishing
-        early). ``decode_chunk`` also caps when a live row can retire
-        EARLIER than its budget (EOS enabled) so admission latency stays
-        bounded, or when the caller asked for streaming granularity via
-        ``max_iters``."""
+        """Iterations for the next decode dispatch (no row is mid-prefill
+        here: a step with one dispatches the mixed step instead). Queue
+        waiting: run to the FIRST budget retirement (admit with zero idle
+        iterations). Queue empty: drain the whole tail in one dispatch
+        (the in-graph alive-mask exit handles rows finishing early).
+        ``decode_chunk`` caps when a live row can retire EARLIER than its
+        budget (EOS enabled) so admission latency stays bounded;
+        ``max_iters`` when the caller asked for streaming granularity."""
         sl = [int(self._steps_left[r.slot]) for r in decoding]
-        prefilling = any(r.prefilling for r in self._sched.live)
-        waiting = bool(self._sched.queue) or prefilling
-        n = min(sl) if waiting else max(sl)
-        if prefilling or (max_iters is None and
-                          any(r.eos_token_id is not None
-                              for r in decoding)):
-            max_iters = min(max_iters or self.config.decode_chunk,
-                            self.config.decode_chunk)
+        n = min(sl) if self._sched.queue else max(sl)
+        if max_iters is None and any(r.eos_token_id is not None
+                                     for r in decoding):
+            max_iters = self.config.decode_chunk
         if max_iters is not None:
             n = min(n, int(max_iters))
         return max(1, min(n, self._out_width))
@@ -2256,10 +2165,7 @@ class ServingEngine:
         bucket, so role churn never retraces. A chunk that COMPLETES its
         prompt samples the first token in this same dispatch (TTFT no
         longer waits for the next step's decode); incomplete chunks and
-        readmission recomputes discard their sampled lane. Block
-        planning, preemption, prefix-cache registration, LoRA operands
-        and journal cursors are exactly the two-phase path's — token
-        streams are bit-identical either way."""
+        readmission recomputes discard their sampled lane."""
         import jax.numpy as jnp
 
         from ...models.generation import seed_key
@@ -2345,8 +2251,7 @@ class ServingEngine:
                              int((qlens[active] == 1).sum()))
             self.spans.count("attn_rows", int(active.sum()))
             now = time.time()
-            # prefill rows first (the two-phase path's bookkeeping order:
-            # _advance_prefills before the decode dispatch's commits)
+            # prefill rows first, then the decode rows' commits
             for req, n in plan:
                 m = req.slot
                 req.num_computed += n
@@ -2468,12 +2373,10 @@ class ServingEngine:
                 if decoding and any(drafts.values()):
                     return "spec", (decoding, drafts)
             decoding = self._sched.decoding
-        if self.config.mixed_batch and \
-                any(r.prefilling for r in self._sched.live):
+        if any(r.prefilling for r in self._sched.live):
             # mixed batching (ISSUE 20): every mid-prefill slot's chunk
             # rides the decode dispatch as a q_len > 1 row of ONE mixed
-            # step — no per-prompt B=1 chunk dispatches, no decode_chunk
-            # clamp, and decoding slots advance in the SAME step a new
+            # step, and decoding slots advance in the SAME step a new
             # prompt prefills. Precedence: a step with spec drafts
             # dispatched verify above and never reaches here. Block
             # planning is the decode planner's (_ensure_blocks for the
@@ -2503,9 +2406,11 @@ class ServingEngine:
 
     def step(self, max_iters: Optional[int] = None) -> Dict[int, List[int]]:
         """One scheduler iteration: expire deadlines -> retire -> admit
-        (+ prefill) -> advance chunked prefills -> extend/preempt for
-        blocks -> one decode dispatch of up to ``_limit()`` iterations
-        (``max_iters`` caps it). Returns ``{rid: [tokens emitted]}``.
+        (+ batched prefill) -> extend/preempt for blocks -> ONE decode-side
+        dispatch: a spec verify, a mixed step carrying every mid-prefill
+        slot's next chunk beside the decoding rows, or the decode loop of
+        up to ``_limit()`` iterations (``max_iters`` caps it). Returns
+        ``{rid: [tokens emitted]}``.
         Each step stamps the global :mod:`~paddle_tpu.health.watchdog`
         (progress tick + ``serving.step``/``serving.prefill``/
         ``serving.decode`` section markers), so a frozen dispatch is
@@ -2536,13 +2441,6 @@ class ServingEngine:
             self._embed_dispatch(Sb, group)
         for Sb, group in waves:
             self._prefill_dispatch(Sb, group, emitted)
-        if not self.config.mixed_batch:
-            # two-phase path (the parity oracle): one B=1 chunk dispatch
-            # per mid-prefill slot BEFORE the decode dispatch, which
-            # _limit then clamps at decode_chunk while any prompt is
-            # mid-prefill. In mixed mode the chunks ride the mixed
-            # dispatch below instead, so the clamp never engages.
-            self._advance_prefills(emitted)
         with self._span("serve:plan"):
             kind, args = self._plan_dispatch(max_iters)
         if kind == "spec":

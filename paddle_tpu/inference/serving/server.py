@@ -569,8 +569,7 @@ class ServingServer:
 def serve_requests(server: ServingServer, prompts,
                    **kwargs) -> Dict[str, Any]:
     """Synchronous convenience: serve a batch of prompts through the
-    in-process transport on a private event loop — the 'mini trace
-    through the server' entry the bench front-line row uses. Returns
+    in-process transport on a private event loop. Returns
     ``{"outputs": [token lists in submission order], "elapsed_s": serve
     wall time (drain excluded), "drain_report": close()'s report}``."""
 

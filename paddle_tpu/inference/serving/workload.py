@@ -109,7 +109,7 @@ class WorkloadSpec:
     # old seeds). >0 extends that fraction of prompts with fresh tokens
     # up to ~long_prompt_len — prompts that must CHUNK through
     # prefill_chunk-sized pieces, the mid-flight-prefill pressure mixed
-    # batching (FLAGS_serving_mixed_batch) absorbs into the decode
+    # batching absorbs into the decode
     # dispatch. Extension is appended at the prompt END so family
     # prefixes (and router affinity keys) stay intact.
     long_prompt_frac: float = 0.0     # requests stretched to ~long len

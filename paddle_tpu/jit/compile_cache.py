@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, the ``examples/``) calls :func:`enable_compile_cache` once,
+Every entry point that compiles for the chip (``chip_smoke.py``, the
+``examples/``) calls :func:`enable_compile_cache` once,
 before its first compile. The directory is decided OUTSIDE the program
 whenever the caller cares: where ``JAX_COMPILATION_CACHE_DIR`` is set, jax
 itself reads it into ``jax_compilation_cache_dir`` and nothing here sets

@@ -63,8 +63,7 @@ def jit_step(fn: Callable, donate_argnums: Sequence[int] = (),
       resume, so it orders a trace's steps and is not the trainer's step
       number.
 
-    Used by bench.py's llama/tuned/checkpoint sections; the raw jitted
-    callable is available as ``wrapped._jitted``.
+    The raw jitted callable is available as ``wrapped._jitted``.
     """
     donate = tuple(donate_argnums) if donation_supported() else ()
     jfn = jax.jit(fn, donate_argnums=donate,
@@ -212,7 +211,7 @@ def make_train_step(model, optimizer, loss_fn: Callable,
             loss = step(x, y)
 
     See :class:`TrainStep` for the amp/scaler/donate knobs. hapi's
-    ``Model.prepare(..., jit=True)`` and bench.py's resnet/detect sections
-    ride this path; ``Optimizer.fuse`` is the optimizer-side spelling.
+    ``Model.prepare(..., jit=True)`` rides this path; ``Optimizer.fuse`` is
+    the optimizer-side spelling.
     """
     return TrainStep(model, optimizer, loss_fn, **kwargs)

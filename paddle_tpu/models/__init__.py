@@ -16,7 +16,7 @@ from .llama import (LlamaConfig, LlamaForCausalLM, init_params, forward,
 
 def paged_family(model_config):
     """The module that holds the paged serving entry points of
-    ``model_config``'s family: ``paged_prefill``, ``paged_prefill_chunk``,
+    ``model_config``'s family: ``paged_prefill``,
     ``paged_decode_step``, ``paged_mixed_step`` (and ``paged_spec_step``
     where it has a verify step), ``init_paged_pool``,
     ``paged_pool_block_bytes``, and beside them ``PAGED_COUNTERS`` (names

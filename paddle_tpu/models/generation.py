@@ -62,7 +62,7 @@ from .lora import lora_delta
 __all__ = ["GenerationConfig", "init_cache", "prefill", "decode_step",
            "make_generate_fn", "generate", "DecodeSession",
            "init_paged_pool", "paged_pool_block_bytes", "paged_pool_specs",
-           "paged_prefill", "paged_prefill_chunk", "paged_decode_step",
+           "paged_prefill", "paged_decode_step",
            "paged_spec_step", "paged_mixed_step", "sample_tokens",
            "seed_key",
            "validate_sampling", "validate_tp",
@@ -474,8 +474,7 @@ def generate(params: Dict, ids, cfg: LlamaConfig, *, max_new_tokens: int,
     whose ``ServingConfig.prefix_cache`` / ``prefill_chunk`` / ``preempt``
     knobs add paged on-demand KV, automatic prefix caching, and chunked
     prefill while staying bit-identical to this path under greedy
-    decoding — this function doubles as that parity oracle in the tests
-    and ``bench --serve``.
+    decoding — this function doubles as that parity oracle in the tests.
 
     Sampling randomness resolves through ``seed`` (default: the
     ``GenerationConfig.seed`` default, 0 — the previously-hardcoded
@@ -739,12 +738,10 @@ def paged_pool_block_bytes(cfg: LlamaConfig, block_size: int, dtype=None,
                            kv_quant=None, tp: int = 1) -> int:
     """Bytes ONE physical block costs across all layers (K + V + scales) —
     the capacity-planning arithmetic behind sizing ``num_blocks`` to a
-    byte budget (``bench --serve``'s int8-vs-fp and TP capacity rows
-    divide a fixed budget by this per layout). ``tp > 1`` returns the
+    byte budget. ``tp > 1`` returns the
     PER-DEVICE cost of the block under a tensor-parallel pool: each
     device holds ``Hk/tp`` heads of every block, so a fixed per-device
-    byte budget backs ``tp`` times the blocks — the per-chip capacity
-    multiplier the TP bench row measures."""
+    byte budget backs ``tp`` times the blocks."""
     import numpy as _np
     validate_tp(cfg, tp)
     L, bs = cfg.num_hidden_layers, int(block_size)
@@ -902,87 +899,6 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
     return _lm_head(params, cfg, last), pool, _lane_counts(B * Sb)
 
 
-def paged_prefill_chunk(params: Dict, cfg: LlamaConfig, ids, start,
-                        chunk_len, block_tables, pool: Dict, lora=None,
-                        use_kernel: bool = False):
-    """Prefill-from-offset: one sequence's token chunk against the pool
-    (``use_kernel`` is the family interface's: the chunk attends over a
-    dense gather of its table here, so it is unused).
-
-    The entry point behind CHUNKED PREFILL and PREFIX-CACHE HITS
-    (``inference.serving``): compute KV for positions ``[start, start +
-    chunk_len)`` of a single sequence whose earlier positions are already
-    in the pool — written by previous chunks, or mapped from the prefix
-    cache (the cache-hit block remap is pure host bookkeeping; this kernel
-    just attends through the block table it is handed).
-
-    ``ids [1, Sb]`` right-padded chunk tokens (``Sb`` the power-of-2
-    bucket); ``start``/``chunk_len`` DEVICE scalars — chunk position and
-    real length never retrace; ``block_tables [1, W]`` must cover ``start
-    + chunk_len`` KV entries. Queries RoPE at their absolute positions,
-    scatter their K/V into the pool, then attend the GATHERED pool
-    (``pool[block_tables]``) under the causal mask ``j <= start + i`` —
-    exactly the decode step's gather generalized to ``Sb`` queries, so
-    cached-prefix KV and freshly-scattered in-chunk KV are read through
-    one path. Masked lanes sit at -1e30 -> exact 0.0 in the fp32 softmax
-    (see ``_masked_sdpa``), so outputs are bit-identical to the dense
-    cache's regardless of the gather width. Returns (next-token logits
-    ``[1, V]`` read at position ``start + chunk_len - 1``, pool,
-    counters).
-    """
-    B, Sb = ids.shape
-    H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
-    D = cfg.head_dim
-    bs = pool["k"].shape[2]
-    W = block_tables.shape[1]
-    C = W * bs
-    dt = cfg.dtype
-    j = jnp.arange(Sb)
-    pos = start + j[None, :]                             # [1, Sb] absolute
-    cos, sin = _row_tables(cfg, pos)
-    valid = j[None, :] < chunk_len                       # [1, Sb]
-    phys = jnp.where(valid,
-                     block_tables[:, jnp.minimum(pos[0] // bs, W - 1)], 0)
-    off = pos % bs
-    jg = jnp.arange(C)[None, None, :]                    # key positions
-    # every position <= the query's is written (previous chunks + cache
-    # hits + this chunk's causal prefix); later/pad lanes are masked
-    kv_mask = jg <= pos[:, :, None]                      # [1, Sb, C]
-
-    x = jnp.take(params["embed"], ids, axis=0).astype(dt)
-
-    def body(h, xs):
-        lp, pz, ll = _lora_unpack(xs)
-        hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.use_fused_norm)
-        q = _mm(hh, lp, "wq", dt)
-        k = _mm(hh, lp, "wk", dt)
-        v = _mm(hh, lp, "wv", dt)
-        if ll is not None:
-            lids = lora["ids"]
-            q = q + lora_delta(hh, ll["qA"], ll["qB"], lids, dt)
-            k = k + lora_delta(hh, ll["kA"], ll["kB"], lids, dt)
-            v = v + lora_delta(hh, ll["vA"], ll["vB"], lids, dt)
-        q = q.reshape(B, Sb, H, D)
-        k = k.reshape(B, Sb, Hk, D)
-        v = v.reshape(B, Sb, Hk, D)
-        q = _rope(q, cos, sin, False)
-        k = _rope(k, cos, sin, False)
-        pz, _, _ = _kv_store(pz, phys, off, k, v)
-        kk, vv = _kv_gather(pz, block_tables, B, C, Hk, D)
-        o = _masked_sdpa(q, kk, vv, kv_mask)
-        m = _merge_heads(o, cfg).astype(dt)
-        d = _mm(m, lp, "wo", dt)
-        if ll is not None:
-            d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
-        h = h + d
-        return _ffn_tail(lp, h, cfg)[0], pz
-
-    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
-    idx = jnp.full((B, 1, 1), jnp.maximum(chunk_len - 1, 0))
-    last = jnp.take_along_axis(x, idx, axis=1)           # [1, 1, E]
-    return _lm_head(params, cfg, last), pool, _lane_counts(B * Sb)
-
-
 def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
                       block_tables, pool: Dict, active,
                       use_kernel: bool = False, lora=None):
@@ -1110,8 +1026,7 @@ def paged_spec_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
     exactly (query ``q`` attends ``j <= seq_lens[m] + q``: committed KV
     plus the in-pass draft prefix, the same set the sequential step at
     that position would see; on int8 pools the attention reads the
-    QUANTIZED round-trip of the in-pass writes, exactly like
-    :func:`paged_prefill_chunk`).
+    QUANTIZED round-trip of the in-pass writes).
 
     The engine rolls back on rejection HOST-SIDE: positions past the
     accepted prefix hold stale draft KV that the next dispatch's write at
@@ -1158,7 +1073,7 @@ def paged_mixed_step(params: Dict, cfg: LlamaConfig, tokens, starts,
     1`` degenerate case — exactly :func:`paged_decode_step`'s computation;
     a prefill chunk is a ``q_lens == n`` row writing K/V for positions
     ``[starts, starts + n)`` with query ``q`` attending ``j <= starts +
-    q`` — exactly :func:`paged_prefill_chunk`'s causal window. Both are
+    q`` — the causal window of a prefill from an offset. Both are
     the ``draft_lens = q_lens - 1`` specialization of the
     speculative-verify forward (:func:`paged_spec_step`), which is what
     this shares, so the kernel's multi-query entry and the gather oracle

@@ -65,7 +65,7 @@ from .llama import _rms_norm
 
 __all__ = ["PanguUltraMoEConfig", "init_params", "forward", "num_params",
            "init_paged_pool", "paged_pool_block_bytes", "paged_prefill",
-           "paged_prefill_chunk", "paged_decode_step", "paged_mixed_step",
+           "paged_decode_step", "paged_mixed_step",
            "PAGED_COUNTERS", "validate_serving", "describe", "health"]
 
 # what one dispatch counts on the device, in this order (int32, summed over
@@ -550,22 +550,6 @@ def paged_prefill(params: Dict, cfg: PanguUltraMoEConfig, ids, prompt_lens,
     last = jnp.take_along_axis(
         x.reshape(B, Sb, -1), jnp.maximum(prompt_lens - 1, 0)[:, None, None],
         axis=1)[:, 0]
-    return _head(params, last, cfg), pool, counts
-
-
-def paged_prefill_chunk(params: Dict, cfg: PanguUltraMoEConfig, ids, start,
-                        chunk_len, block_tables, pool: Dict, lora=None,
-                        use_kernel: bool = False):
-    """``generation.paged_prefill_chunk``'s contract: positions ``[start,
-    start + chunk_len)`` of ONE sequence (``ids [1, Sb]``) whose earlier
-    positions are in the pool."""
-    _no_lora(lora)
-    Sb = ids.shape[1]
-    j = jnp.arange(Sb, dtype=jnp.int32)
-    x, pool, counts = _paged_lanes(
-        params, cfg, ids[0], start + j, jnp.zeros((Sb,), jnp.int32),
-        j < chunk_len, block_tables, pool, use_kernel)
-    last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0), 0)
     return _head(params, last, cfg), pool, counts
 
 

@@ -4,15 +4,13 @@ Capability target: the reference ecosystem's SDXL UNet (ppdiffusers
 ``models/unet_2d_condition.py``: timestep-embedded ResBlocks,
 cross-attention transformer blocks at the lower resolutions, down/up paths
 with skip connections; BASELINE.json configs[4] names "SDXL UNet (Pallas
-attention)"). This is the architecture at configurable width/depth — the
-bench row drives the heavy attention shapes through the Pallas flash
-kernel; tests train a tiny instance end to end on the epsilon-prediction
+attention)"). This is the architecture at configurable width/depth;
+tests train a tiny instance end to end on the epsilon-prediction
 objective.
 
 TPU notes: NCHW throughout (the repo's conv convention); attention flattens
-spatial to sequence and runs scaled-dot-product attention — the self-attn
-at 64x64 latents (S=4096) is exactly the `bench.py --sdxl` kernel shape;
-GroupNorm/SiLU ride XLA fusion.
+spatial to sequence and runs scaled-dot-product attention (the self-attn
+at 64x64 latents is S=4096); GroupNorm/SiLU ride XLA fusion.
 """
 
 from __future__ import annotations

@@ -159,8 +159,8 @@ def generate(out_path: str = "docs/OPS.md") -> str:
         "delivered-so-far` under its original journal id — zero lost "
         "requests, zero re-delivered tokens, greedy and seeded streams "
         "bit-identical (the `durable_exactly_once` auditor check and "
-        "`bench --serve`'s `serving_recovery_ms` row hold the line; "
-        "journal overhead is asserted < 5% there). A graceful SIGTERM "
+        "`tests/test_journal.py`'s kill-point fuzz hold the line). A "
+        "graceful SIGTERM "
         "drain writes a final snapshot, so the next cold start replays "
         "nothing. Watch: `torn_tail_bytes` > 0 (the crash cut a "
         "write), `snapshot_fallbacks` climbing (snapshot corruption — "
@@ -371,7 +371,7 @@ def generate(out_path: str = "docs/OPS.md") -> str:
         "`leaked_blocks == 0` on every replica at quiesce, autoscale "
         "`spawns`/`drains` >= 1 each with the measured arrival-TTFT "
         "p99 effect vs the fixed-fleet counterfactual "
-        "(`bench --serve`'s replay row asserts all of it).",
+        "(`tests/test_replay.py` asserts it on a small trace).",
         "",
         "### Capacity report",
         "",
@@ -380,8 +380,7 @@ def generate(out_path: str = "docs/OPS.md") -> str:
         "per-chip block cost and concurrent sequences across fp/int8 x "
         "TP degree at an HBM budget — with the replay's measured "
         "curves: req/s, TTFT/TPOT p50/p99, `goodput_tok_s_per_chip` "
-        "(SLO-met tokens per second per chip — the "
-        "`serving_replay_goodput` bench metric), and the sizing line "
+        "(SLO-met tokens per second per chip), and the sizing line "
         "(\"X replicas of config Y serve Z req/s within SLO\") plus "
         "`replicas_for_<N>_req_s` projections.",
     ]

@@ -1,7 +1,8 @@
-"""Regenerate every narrated number from its artifact (r4 VERDICT weak #2 /
-next #9: the builder's README/BASELINE counts drifted from the registry and
-the driver's bench output — so the counts are now GENERATED, and
-tests/test_docs_fresh.py fails CI-style when they drift).
+"""Regenerate every narrated count from the live registry (r4 VERDICT weak
+#2 / next #9: the builder's README counts drifted from the registry — so
+the counts are now GENERATED, and tests/test_docs_fresh.py fails CI-style
+when they drift). Speeds are not narrated here: they are in PERF.md and
+PERF_LEDGER.jsonl, measured by benchmark/run.py.
 
     python -m paddle_tpu.tools.refresh_docs          # rewrite docs
     python -m paddle_tpu.tools.refresh_docs --check  # exit 1 on drift
@@ -9,8 +10,6 @@ tests/test_docs_fresh.py fails CI-style when they drift).
 
 from __future__ import annotations
 
-import glob
-import json
 import os
 import re
 import sys
@@ -82,60 +81,12 @@ def measured_counts() -> dict:
     }
 
 
-def latest_bench() -> dict:
-    """Newest BENCH_r*.json -> {metric: value}."""
-    def round_no(path):
-        m = re.search(r"BENCH_r(\d+)\.json$", path)
-        return int(m.group(1)) if m else -1
-
-    files = sorted(glob.glob(os.path.join(ROOT, "BENCH_r*.json")),
-                   key=round_no)
-    if not files:
-        return {}
-    rows = {}
-    raw = open(files[-1]).read()
-    for line in raw.splitlines():
-        line = line.strip()
-        if not line:
-            continue
-        try:
-            d = json.loads(line)
-        except json.JSONDecodeError:
-            continue
-        if isinstance(d, dict) and "metric" in d:
-            rows[d["metric"]] = d
-    if not rows:   # maybe one JSON array/object
-        try:
-            data = json.loads(raw)
-            if isinstance(data, list):
-                for d in data:
-                    if isinstance(d, dict) and "metric" in d:
-                        rows[d["metric"]] = d
-            elif isinstance(data, dict) and isinstance(data.get("tail"), str):
-                # driver format: one object whose "tail" holds the bench
-                # stdout (JSON lines) — parse the embedded metric lines
-                for line in data["tail"].splitlines():
-                    line = line.strip()
-                    if not line.startswith("{"):
-                        continue
-                    try:
-                        d = json.loads(line)
-                    except json.JSONDecodeError:
-                        continue
-                    if isinstance(d, dict) and "metric" in d:
-                        rows[d["metric"]] = d
-        except json.JSONDecodeError:
-            pass
-    return rows
-
-
 # every generated span sits between these markers in the docs
-# (digits allowed: bench metric keys like resnet50_throughput / h2d carry them)
 _GEN = re.compile(r"<!--gen:(?P<key>[a-z0-9_]+)-->(?P<body>.*?)"
                   r"<!--/gen-->", re.S)
 
 
-def render(key: str, counts: dict, bench: dict) -> str:
+def render(key: str, counts: dict) -> str:
     if key in ("health_flags_table", "serving_flags_table"):
         # generated flags table straight from the live registry (ONE
         # shared renderer with ops/gen_docs.py) so the docs cannot drift
@@ -149,15 +100,11 @@ def render(key: str, counts: dict, bench: dict) -> str:
     if key == "sweep_line":
         return (f"{counts['swept']}/{counts['ops']} ops "
                 f"({counts['swept_pct']}%) oracle-swept")
-    if key.startswith("bench_"):
-        m = bench.get(key[len("bench_"):])
-        return "unmeasured" if m is None else f"{m['value']} {m['unit']}"
     raise KeyError(key)
 
 
 def refresh(check: bool = False) -> int:
     counts = measured_counts()
-    bench = latest_bench()
     drift = []
     for rel in ("README.md", "docs/FAULT_TOLERANCE.md",
                 "docs/PERFORMANCE.md", "docs/SERVING.md"):
@@ -165,7 +112,7 @@ def refresh(check: bool = False) -> int:
         src = open(path).read()
 
         def sub(m):
-            want = render(m.group("key"), counts, bench)
+            want = render(m.group("key"), counts)
             have = m.group("body")
             if have != want:
                 drift.append(f"{rel}: {m.group('key')}: "

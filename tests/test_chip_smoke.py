@@ -21,7 +21,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke as cs  # noqa: E402
 from paddle_tpu.inference.serving import ServingConfig  # noqa: E402
 from paddle_tpu.models.llama import LlamaConfig  # noqa: E402
@@ -161,7 +160,7 @@ def _preset_cases(monkeypatch):
     from paddle_tpu.kernels import dispatch
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
     assert dispatch.interpret() is False
-    cfg, batch, seq = bench._presets("tpu")
+    cfg, batch, seq = cs.preset()
     return cs.kernel_cases(cfg, batch, seq, ServingConfig())
 
 
@@ -373,12 +372,13 @@ def test_latent_and_grouped_kernels_compile_for_v5e_without_a_chip(
             jax.jit(fn).lower(*args).compile()
 
 
-def test_preset_is_spelled_once():
-    """chip_smoke.py takes the 738M preset from bench.py, not a copy."""
-    src = open(os.path.join(REPO, "chip_smoke.py")).read()
-    assert 'bench._presets("tpu")' in src
-    assert "intermediate_size" not in src and "5504" not in src
-    cfg, batch, seq = bench._presets("tpu")
+def test_preset_is_the_738m_model():
+    """The smoke's model is the one with a chip history: 738M parameters
+    at LLaMA-7B's shape ratios, kernels and remat on."""
+    from paddle_tpu.models.llama import num_params
+    cfg, batch, seq = cs.preset()
     assert (cfg.hidden_size, cfg.num_hidden_layers, batch, seq) == \
         (2048, 12, 8, 2048)
+    assert cfg.intermediate_size / cfg.hidden_size == 2.6875
+    assert round(num_params(cfg) / 1e6) == 738
     assert cfg.use_kernels and cfg.remat

@@ -65,7 +65,7 @@ def test_a_program_forward_is_the_references_on_logits():
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
-def test_b_paged_prefill_chunks_and_decode_are_the_references_full_forward(
+def test_b_paged_prefill_mixed_chunks_and_decode_are_the_references_forward(
         kernel):
     """A short prompt through the batched prefill, a long one in chunks
     through the mixed step beside a decoding slot, then decode: the logits
@@ -378,6 +378,48 @@ def test_the_engine_finds_a_family_through_the_config_object():
         with pytest.raises(ValueError, match="pangu_ultra_moe"):
             ServingEngine(params, cfg, ServingConfig(
                 block_size=4, max_slots=2, max_model_len=16, **knob))
+
+
+def _dense_toy():
+    from paddle_tpu.models.llama import init_params
+    cfg = LlamaConfig(vocab_size=96, hidden_size=64, intermediate_size=96,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=64)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("family", ["dense", "pangu_ultra_moe"])
+def test_a_family_is_its_documented_entry_points_and_serves_in_chunks(
+        family):
+    """A family's contract is ``paged_prefill``, ``paged_decode_step``,
+    ``paged_mixed_step`` (and ``paged_spec_step`` where it has a verify
+    step) beside the pool's functions and the four names the engine
+    reads: exactly these, and an engine built on them serves a prompt
+    longer than ``prefill_chunk``, every chunk through the mixed step."""
+    cfg, params = _dense_toy() if family == "dense" else toy()[1:]
+    F = paged_family(cfg)
+    steps = {"paged_prefill", "paged_decode_step", "paged_mixed_step"}
+    if family == "dense":
+        steps.add("paged_spec_step")
+    assert {n for n in F.__all__ if n.startswith("paged_")
+            and not n.startswith("paged_pool")} == steps
+    assert {n for n in dir(F) if n.startswith("paged_")
+            and callable(getattr(F, n))} - steps <= {
+                "paged_pool_block_bytes", "paged_pool_specs"}
+    for name in ("init_paged_pool", "paged_pool_block_bytes",
+                 "PAGED_COUNTERS", "validate_serving", "describe", "health"):
+        assert name in F.__all__, name
+    eng = ServingEngine(params, cfg, ServingConfig(
+        block_size=4, max_slots=2, max_model_len=48, prefill_chunk=8,
+        decode_chunk=2))
+    prompt = ids_of(27, 3)
+    (out,) = eng.run([prompt], max_new_tokens=4, eos_token_id=None)
+    assert len(out) == 4
+    st = eng.stats()
+    assert st["mixed_dispatches"] == 4          # ceil(27 / 8) chunks
+    assert st["prefill_dispatches"] == 0 and st["decode_dispatches"] >= 1
+    assert st["spans"]["counters"]["prefill_tokens"] == 27
+    assert eng.cache.manager.blocks_in_use == 0
 
 
 def test_counts_of_the_published_configuration_by_hand():

@@ -659,19 +659,15 @@ class TestMixedBatchReplay:
 
     def test_mixed_fleet_replay_clean_under_chaos(self, setup):
         """The chaos timeline over a MIXED fleet: chunked long prompts
-        riding the decode dispatch (prefill_chunk=4, mixed_batch on),
-        every chaos kind armed, full audit — zero violations, zero
-        leaks, failed == 0. The two-phase path's invariants hold
-        verbatim because block planning / preemption / registration /
-        journal cursors are shared between the paths."""
+        riding the decode dispatch (prefill_chunk=4), every chaos kind
+        armed, full audit — zero violations, zero leaks, failed == 0."""
         from paddle_tpu.inference.serving import run_replay
         cfg, params, programs = setup
         spec = small_spec(requests=40, horizon_steps=30,
                           long_prompt_frac=0.4, long_prompt_len=24,
                           output_lens=(3, 4, 6))
         rep = run_replay(params, cfg, spec=spec,
-                         serving_config=serving_config(prefill_chunk=4,
-                                                       mixed_batch=True),
+                         serving_config=serving_config(prefill_chunk=4),
                          replicas=2, chaos_events=6, programs=programs)
         assert rep["violations"] == []
         assert rep["failed"] == 0 and rep["router_failed"] == 0

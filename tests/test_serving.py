@@ -307,23 +307,22 @@ class TestRecompileBounds:
         assert st["prefill_buckets"] == 2
         assert st["prefill_traces"] == 3
         # the whole first trace was COLD: no hits, so no offset prefills
-        assert st["chunk_prefill_traces"] == 0
+        assert st["mixed_traces"] == 0
         assert st["prefix_hit_tokens"] == 0
-        # a second identical trace hits the prefix cache: suffixes run the
-        # offset chunk path (suffix <= 8 -> ONE more executable at the
-        # (1, 8) bucket), cache-cold rows reuse the existing fast-path
+        # a second identical trace hits the prefix cache: suffixes ride
+        # the mixed step (suffix <= 8 -> ONE executable, the Q = 8
+        # bucket), cache-cold rows reuse the existing fast-path
         # executables, and the decode program STILL never retraces
         eng.run(prompts, max_new_tokens=outs, eos_token_id=None)
         st2 = eng.stats()
         assert st2["decode_traces"] == 1
         assert st2["prefill_traces"] == 3
-        assert st2["chunk_prefill_traces"] <= 1
+        assert st2["mixed_traces"] == 1
         assert st2["prefix_hit_tokens"] > 0
         # by the third run every shape has been seen: ZERO new traces
         eng.run(prompts, max_new_tokens=outs, eos_token_id=None)
         st3 = eng.stats()
-        for key in ("decode_traces", "prefill_traces",
-                    "chunk_prefill_traces"):
+        for key in ("decode_traces", "prefill_traces", "mixed_traces"):
             assert st3[key] == st2[key], key
 
     def test_exact_schedule_dispatch_counts(self, setup):
@@ -741,9 +740,7 @@ class TestChunkedPrefill:
         """Long prompts prefilled in fixed-size chunks: greedy outputs are
         bit-identical to the dense path, and the decode executable still
         compiles exactly once. With mixed batching (the default) the
-        chunks ride the fused mixed dispatch instead of the dedicated
-        chunk program — that two-phase program's own parity is pinned by
-        the mixed_batch=False oracles in test_serving_mixed.py."""
+        chunks ride the fused mixed dispatch."""
         cfg, params, prompts, outs = setup
         eng = make_engine(params, cfg, prefill_chunk=4)
         got = eng.run(prompts, max_new_tokens=outs, eos_token_id=None)
@@ -1999,18 +1996,18 @@ class TestOnDeviceSampling:
         # prefix_cache off: reruns replay the identical admission path,
         # so every trace counter must freeze after the first pass (with
         # the cache on, a rerun's first prefix HIT legitimately traces
-        # the chunk program once — that is the hit path's executable,
+        # the mixed program once — that is the hit path's executable,
         # not a sampling recompile)
         eng = make_engine(params, cfg, prefix_cache=None)
         trace(eng)
         st = eng.stats()
         assert st["decode_traces"] == 1
         t0 = (st["decode_traces"], st["prefill_traces"],
-              st["chunk_prefill_traces"], st["sample_traces"])
+              st["mixed_traces"], st["sample_traces"])
         trace(eng)
         st = eng.stats()
         assert (st["decode_traces"], st["prefill_traces"],
-                st["chunk_prefill_traces"], st["sample_traces"]) == t0
+                st["mixed_traces"], st["sample_traces"]) == t0
 
     def test_lifecycle_fuzz_with_sampling_rows(self, setup):
         """The ISSUE 6 randomized cancel/timeout fuzz extended with
@@ -2336,17 +2333,17 @@ class TestSpeculativeDecoding:
         eng.run(prompts, max_new_tokens=10, eos_token_id=None)
         st = eng.stats()
         assert st["spec_traces"] == 1
-        # second run prefix-HITS, which may trace the chunk program once
+        # second run prefix-HITS, which may trace the mixed program once
         # (the hit path's executable); from then on every counter freezes
         eng.run(prompts, max_new_tokens=10, eos_token_id=None)
         st = eng.stats()
         assert st["spec_traces"] == 1
         t0 = (st["spec_traces"], st["decode_traces"], st["prefill_traces"],
-              st["chunk_prefill_traces"])
+              st["mixed_traces"])
         eng.run(prompts, max_new_tokens=10, eos_token_id=None)
         st = eng.stats()
         assert (st["spec_traces"], st["decode_traces"],
-                st["prefill_traces"], st["chunk_prefill_traces"]) == t0
+                st["prefill_traces"], st["mixed_traces"]) == t0
 
     def test_per_request_spec_counters(self, setup):
         """Request records carry spec_drafted/spec_accepted; stream()

@@ -1,18 +1,26 @@
-"""Stall-free mixed batching (ISSUE 20): chunked prefill fused into the
-decode dispatch as extra query rows of ONE mixed multi-query step.
+"""The mixed step (ISSUE 20): chunked prefill rides the decode dispatch
+as extra query rows of ONE multi-query step. It is the only way the
+engine steps, so its oracles are outside the engine:
 
-Oracle discipline: the two-phase engine (``mixed_batch=False`` — byte-
-for-byte the pre-ISSUE-20 path) is the bit-parity reference. The mixed
-engine must reproduce its token streams EXACTLY across
-{fp32, int8 KV} x {kernel, gather} x {greedy, seeded} (TP2 rides
-test_serving_tp's mesh via the tp-marked class here), including prefix
-hits, preemption recompute, crash resubmit/recovery, and adapters —
-with ``recomputed_tokens`` / leak counters unchanged. On top of parity:
-spec-decode precedence (a step with drafts dispatches verify, never
-mixed), compile-once across admission churn (``decode_traces`` /
-``mixed_traces`` flat), and the stall removal itself (decoding slots
-advance in the SAME step a new prompt prefills).
+1. dense ``generate()`` (``dense_rows``) for greedy float32 streams, on
+   the gather path and the kernel: code that shares no paged function;
+2. **the request served alone** (``served_alone``): the same
+   ``ServingConfig``, the request submitted by itself to a fresh engine
+   (cold cache, no neighbour, never preempted). A reply does not depend
+   on who shares the step, so its stream in the wave is its stream
+   alone, bit for bit: int8 KV, seeded sampling, adapters, a second wave
+   that prefix-hits, a preempted and recomputed request, one resubmitted
+   after a crash, TP2;
+3. counters against what the scenario itself implies.
+
+On top of those: spec-decode precedence (a step with drafts dispatches
+verify, never mixed), compile-once across admission churn
+(``decode_traces`` / ``mixed_traces`` flat), decoding slots advancing in
+the SAME step a new prompt prefills, and the decode loop never sized
+with a row mid-prefill.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -21,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models import generation as G
-from paddle_tpu.models.llama import LlamaConfig, init_params
+from paddle_tpu.models.llama import LlamaConfig, init_params, quantize_params
 from paddle_tpu.models.lora import lora_init_params
 from paddle_tpu.inference.serving import (EngineSupervisor, ServingConfig,
                                           ServingEngine)
@@ -40,6 +48,7 @@ def tiny_cfg(**kw):
 # boundaries for the mixed path to carry mid-flight prefill rows
 BASE = dict(block_size=4, max_slots=3, max_model_len=64, decode_chunk=2,
             queue_depth=16, prefill_chunk=4)
+PREFIX = 8          # tokens every prompt of the trace shares: two blocks
 
 
 @pytest.fixture(scope="module")
@@ -47,9 +56,9 @@ def setup():
     cfg = tiny_cfg()
     params = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(11)
-    prefix = rng.integers(0, 97, (8,)).astype(np.int32)
-    # mixed lengths with several prompts long enough to chunk (> 4),
-    # sharing a block-aligned family prefix so prefix hits engage
+    prefix = rng.integers(0, 97, (PREFIX,)).astype(np.int32)
+    # mixed lengths, every prompt long enough to chunk (> 4), sharing a
+    # block-aligned family prefix so prefix hits engage
     prompts = [np.concatenate([prefix,
                                rng.integers(0, 97, (s,)).astype(np.int32)])
                for s in [2, 13, 5, 21, 9, 3]]
@@ -57,31 +66,47 @@ def setup():
     return cfg, params, prompts, outs
 
 
+def dense_rows(params, cfg, prompts, outs):
+    return [np.asarray(G.generate(params, jnp.asarray(p[None]), cfg,
+                                  max_new_tokens=int(n)))[0].tolist()
+            for p, n in zip(prompts, outs)]
+
+
+@pytest.fixture(scope="module")
+def dense(setup):
+    """The trace's greedy float32 streams by ``generate()`` on a dense
+    cache, one request a call."""
+    cfg, params, prompts, outs = setup
+    return dense_rows(params, cfg, prompts, outs)
+
+
 # donor-programs cache: engines with an identical shape surface share
-# one compiled EnginePrograms (the supervisor/fleet sharing path — and
-# mixed_batch is deliberately NOT in the program key, so both sides of
-# a parity pair share too). Cuts the module's compile bill to one per
-# distinct shape key; per-engine parity counters (preemptions, prefix
-# hits, ...) live on the scheduler, not the shared stats, so parity
-# comparisons are unaffected.
+# one compiled EnginePrograms (the supervisor/fleet sharing path). Cuts
+# the module's compile bill to one per distinct shape key; a wave's
+# engine and its requests' served-alone engines share too. Per-engine
+# counters (preemptions, prefix hits, ...) live on the scheduler, not
+# the shared stats.
 _DONORS = {}
 
 
-def mk(params, cfg, mixed, **kw):
+def mk(params, cfg, **kw):
     sc = dict(BASE)
     sc.update(kw)
     key = tuple(sorted(sc.items()))
-    eng = ServingEngine(params, cfg, ServingConfig(mixed_batch=mixed, **sc),
+    eng = ServingEngine(params, cfg, ServingConfig(**sc),
                         programs=_DONORS.get(key))
     _DONORS.setdefault(key, eng.programs)
     return eng
 
 
-def drain_streams(eng, prompts, outs, max_iters=None, **submit_kw):
+def drain_streams(eng, prompts, outs, max_iters=None, adapters=None,
+                  **submit_kw):
     """Submit a wave and drain step-by-step, returning per-rid streams
-    plus the stats record (the parity payload)."""
+    plus the stats record."""
+    adapters = adapters or [None] * len(prompts)
     rids = [eng.submit(p, max_new_tokens=int(n), eos_token_id=None,
-                       **submit_kw) for p, n in zip(prompts, outs)]
+                       adapter_id=a, **submit_kw)
+            for p, n, a in zip(prompts, outs, adapters)]
     acc = {r: [] for r in rids}
     while eng.pending:
         for rid, toks in eng.step(max_iters).items():
@@ -89,135 +114,208 @@ def drain_streams(eng, prompts, outs, max_iters=None, **submit_kw):
     return [sum(acc[r], []) for r in rids], eng.stats()
 
 
-PARITY_COUNTERS = ("preemptions", "recomputed_tokens", "prefix_hit_tokens",
-                   "oom_truncated", "retired")
+def served_alone(make, prompts, outs, adapters=None, **submit_kw):
+    """Each request's stream when it is the only one its engine ever
+    sees: ``make()`` builds a fresh engine of the wave's own
+    ``ServingConfig`` (cold cache, no neighbour; one request never
+    preempts itself)."""
+    adapters = adapters or [None] * len(prompts)
+    streams = []
+    for p, n, a in zip(prompts, outs, adapters):
+        (got,), st = drain_streams(make(), [p], [n], adapters=[a],
+                                   **submit_kw)
+        assert (st["preemptions"], st["prefix_hit_tokens"]) == (0, 0)
+        streams.append(got)
+    return streams
+
+
+def later_admissions_hits(prompts, slots):
+    """Prefix-hit tokens of ONE cold wave on ``slots`` slots: the first
+    ``slots`` requests admit together against an empty cache; each later
+    one admits into a slot a finished request freed, so the shared prefix
+    is registered by then and it hits all of it."""
+    return PREFIX * (len(prompts) - slots)
 
 
 class TestMixedParityMatrix:
-    """Token streams bit-identical to the two-phase oracle, counters
-    unchanged, across the quant x attention-path x sampling matrix."""
+    """Token streams of a wave on the mixed step, across the quant x
+    attention-path x sampling matrix and the paging scenarios, against
+    dense ``generate()`` and the request served alone; counters against
+    what the scenario implies."""
 
     @pytest.mark.parametrize("quantize", [None, "int8"])
     @pytest.mark.parametrize("paged_kernel", [False, True])
-    def test_greedy_parity(self, setup, quantize, paged_kernel):
+    def test_greedy_parity(self, setup, dense, quantize, paged_kernel):
         cfg, params, prompts, outs = setup
         kw = dict(quantize=quantize, paged_kernel=paged_kernel)
-        a, sa = drain_streams(mk(params, cfg, False, **kw), prompts, outs)
-        b, sb = drain_streams(mk(params, cfg, True, **kw), prompts, outs)
-        assert a == b
-        assert sb["mixed_dispatches"] > 0      # the path actually ran
-        for k in PARITY_COUNTERS:
-            assert sa[k] == sb[k], k
+        got, st = drain_streams(mk(params, cfg, **kw), prompts, outs)
+        assert got == (dense_rows(quantize_params(params), cfg, prompts, outs)
+                       if quantize else dense)
+        assert st["mixed_dispatches"] > 0      # the path actually ran
+        assert st["retired"] == len(prompts)
+        assert st["prefix_hit_tokens"] == later_admissions_hits(prompts, 3)
+        assert (st["preemptions"], st["recomputed_tokens"],
+                st["oom_truncated"]) == (0, 0, 0)
 
     @pytest.mark.parametrize("paged_kernel", [False, True])
     def test_seeded_parity(self, setup, paged_kernel):
         cfg, params, prompts, outs = setup
         kw = dict(temperature=0.8, top_k=25, top_p=0.9, seed=123)
-        a, sa = drain_streams(mk(params, cfg, False,
-                                 paged_kernel=paged_kernel),
-                              prompts, outs, **kw)
-        b, sb = drain_streams(mk(params, cfg, True,
-                                 paged_kernel=paged_kernel),
-                              prompts, outs, **kw)
-        assert a == b
-        assert sb["mixed_dispatches"] > 0
-        for k in PARITY_COUNTERS:
-            assert sa[k] == sb[k], k
 
-    def test_prefix_hit_parity(self, setup):
+        def make():
+            return mk(params, cfg, paged_kernel=paged_kernel)
+        got, st = drain_streams(make(), prompts, outs, **kw)
+        assert got == served_alone(make, prompts, outs, **kw)
+        assert st["mixed_dispatches"] > 0
+        assert st["retired"] == len(prompts)
+        assert st["prefix_hit_tokens"] == later_admissions_hits(prompts, 3)
+        assert (st["preemptions"], st["recomputed_tokens"],
+                st["oom_truncated"]) == (0, 0, 0)
+
+    def test_prefix_hit_parity(self, setup, dense):
         """A second identical wave prefix-hits: suffixes enter mid-offset
         chunked prefill — exactly the rows the mixed dispatch carries —
-        and streams still match the oracle's second wave."""
+        and both waves' streams are the dense ones and the requests'
+        own, served alone on a cold cache."""
         cfg, params, prompts, outs = setup
-        ea, eb = mk(params, cfg, False), mk(params, cfg, True)
-        a1, _ = drain_streams(ea, prompts, outs)
-        a2, sa = drain_streams(ea, prompts, outs)
-        b1, _ = drain_streams(eb, prompts, outs)
-        b2, sb = drain_streams(eb, prompts, outs)
-        assert (a1, a2) == (b1, b2)
-        assert sa["prefix_hit_tokens"] == sb["prefix_hit_tokens"] > 0
+        eng = mk(params, cfg)
+        first, s1 = drain_streams(eng, prompts, outs)
+        second, s2 = drain_streams(eng, prompts, outs)
+        assert first == second == dense
+        assert second == served_alone(lambda: mk(params, cfg), prompts,
+                                      outs)
+        # the second wave finds every prompt of the first in the cache
+        # (the pool holds them all): each request hits its own prompt's
+        # whole blocks, short of the last token, which it must compute
+        bs = BASE["block_size"]
+        assert s1["prefix_hit_tokens"] == later_admissions_hits(prompts, 3)
+        assert s2["prefix_hit_tokens"] - s1["prefix_hit_tokens"] == sum(
+            (len(p) - 1) // bs * bs for p in prompts)
+        assert s2["retired"] == 2 * len(prompts)
 
-    def test_preemption_recompute_parity(self, setup):
-        """An undersized pool forces preempt-and-recompute in BOTH modes:
-        streams stay bit-identical and the recompute counters match
-        exactly. Driven at step(1) so both modes advance decode one
-        iteration per step — the per-step KV state evolves identically,
-        so the planner/preemption ladder (shared code) fires at the SAME
-        instants with the SAME victims."""
+    def test_preemption_recompute_parity(self, setup, dense):
+        """An undersized pool forces preempt-and-recompute: every stream,
+        the victims' too, is the dense one and the one served alone, and
+        ``recomputed_tokens`` is the KV the victims held when they were
+        preempted (no prefix cache here, so a readmission recomputes all
+        of it)."""
         cfg, params, prompts, outs = setup
         kw = dict(num_blocks=14, prefix_cache=None)
-        a, sa = drain_streams(mk(params, cfg, False, **kw), prompts, outs,
-                              max_iters=1)
-        b, sb = drain_streams(mk(params, cfg, True, **kw), prompts, outs,
-                              max_iters=1)
-        assert a == b
-        assert sa["preemptions"] == sb["preemptions"] >= 1
-        assert sa["recomputed_tokens"] == sb["recomputed_tokens"] > 0
-        for eng_mode, st in (("unmixed", sa), ("mixed", sb)):
-            assert st["free_blocks"] == 13, eng_mode   # zero leaked
+        eng = mk(params, cfg, **kw)
+        held = []                      # KV entries of each victim
+        preempt = eng._preempt
 
-    def test_adapter_parity(self, setup):
+        def spy(req):
+            held.append(req.num_computed if req.prefilling
+                        else int(eng._seq_lens[req.slot]))
+            preempt(req)
+        eng._preempt = spy
+        got, st = drain_streams(eng, prompts, outs, max_iters=1)
+        assert got == dense
+        assert got == served_alone(lambda: mk(params, cfg, **kw), prompts,
+                                   outs)
+        assert st["preemptions"] == len(held) >= 1
+        assert st["recomputed_tokens"] == sum(held) > 0
+        assert st["retired"] == len(prompts)
+        assert (st["prefix_hit_tokens"], st["oom_truncated"]) == (0, 0)
+        assert st["free_blocks"] == 13             # zero leaked
+
+    def test_adapter_parity(self, setup, dense):
+        """A wave of mixed adapters: each reply is the one its request
+        gets alone with its adapter (the merged-weights dense oracle of
+        the adapters themselves is tests/test_lora.py's)."""
         cfg, params, prompts, outs = setup
         adapters = {f"a{i}": lora_init_params(cfg, 4, seed=i, scale=0.5)
                     for i in range(2)}
         ids = ["a0", None, "a1", "a0", None, "a1"]
-        streams = {}
-        for mixed in (False, True):
-            eng = mk(params, cfg, mixed, lora_rank=4, lora_slots=2,
-                     lora_pool=8)
+
+        def make():
+            eng = mk(params, cfg, lora_rank=4, lora_slots=2, lora_pool=8)
             for name, ap in adapters.items():
                 eng.register_adapter(name, ap)
-            rids = [eng.submit(p, max_new_tokens=int(n),
-                               eos_token_id=None, adapter_id=a)
-                    for p, n, a in zip(prompts, outs, ids)]
-            while eng.pending:
-                eng.step()
-            streams[mixed] = [list(eng.request(r).output()) for r in rids]
-            if mixed:
-                assert eng.stats()["mixed_dispatches"] > 0
-        assert streams[False] == streams[True]
+            return eng
+        got, st = drain_streams(make(), prompts, outs, adapters=ids)
+        assert st["mixed_dispatches"] > 0
+        assert got == served_alone(make, prompts, outs, adapters=ids)
+        for g, w, a in zip(got, dense, ids):
+            assert (g == w) == (a is None)         # adapters did bite
 
-    def test_crash_resubmit_recovery_parity(self, setup):
-        """Crash mid-trace under a supervisor in BOTH modes: the rebuilt
-        engine's resubmit/recompute path must land every stream on the
-        same tokens (and mixed-mode recovery re-chunks mid-prefill
-        prompts through the mixed dispatch)."""
+    def test_crash_resubmit_recovery_parity(self, setup, dense):
+        """Crash mid-trace under a supervisor: the rebuilt engine's
+        resubmit/recompute path (which re-chunks mid-prefill prompts
+        through the mixed dispatch) lands every stream on the dense
+        tokens, which are the requests' own served alone."""
         cfg, params, prompts, outs = setup
-        streams = {}
-        for mixed in (False, True):
-            sup = EngineSupervisor(params, cfg,
-                                   ServingConfig(mixed_batch=mixed,
-                                                 **BASE))
-            srids = [sup.submit(p, max_new_tokens=int(n),
-                                eos_token_id=None)
-                     for p, n in zip(prompts, outs)]
-            assert sup.step(2) is not None and sup.pending
-            chaos.engine_crash(sup, at_step=1)
-            assert sup.step(2) == {}        # the crashed iteration
-            assert sup.restarts == 1
-            while sup.pending:
-                sup.step(2)
-            streams[mixed] = [list(sup.result(s)) for s in srids]
-            if mixed:
-                assert sup.engine.stats()["mixed_dispatches"] > 0
-        assert streams[False] == streams[True]
+        sup = EngineSupervisor(params, cfg, ServingConfig(**BASE))
+        srids = [sup.submit(p, max_new_tokens=int(n), eos_token_id=None)
+                 for p, n in zip(prompts, outs)]
+        assert sup.step(2) is not None and sup.pending
+        chaos.engine_crash(sup, at_step=1)
+        assert sup.step(2) == {}        # the crashed iteration
+        assert sup.restarts == 1
+        while sup.pending:
+            sup.step(2)
+        got = [list(sup.result(s)) for s in srids]
+        assert sup.engine.stats()["mixed_dispatches"] > 0
+        assert got == dense
+        assert got == served_alone(lambda: mk(params, cfg), prompts, outs)
+
+
+class TestServedAlone:
+    @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+    @pytest.mark.parametrize("paged_kernel", [False, True],
+                             ids=["gather", "kernel"])
+    @pytest.mark.parametrize("kv_quant", [None, "int8"],
+                             ids=["float32", "int8kv"])
+    def test_a_reply_does_not_depend_on_who_shares_the_step(
+            self, setup, dense, kv_quant, paged_kernel, sampling):
+        """Each request of a wave (three slots, six requests, chunks
+        beside decoding rows, later admissions on a warm prefix) streams
+        what it streams alone on a cold engine: a neighbour's rows, keys
+        or cache leaking into a reply changes it. Seeds differ a request,
+        so a key drawn from the wrong row shows. The greedy float32 cases
+        are held to dense ``generate()`` as well."""
+        cfg, params, prompts, outs = setup
+
+        def make():
+            return mk(params, cfg, kv_quant=kv_quant,
+                      paged_kernel=paged_kernel)
+        eng = make()
+        rids, want = [], []
+        for i, (p, n) in enumerate(zip(prompts, outs)):
+            kw = (dict(temperature=0.9, top_k=30, top_p=0.95, seed=1000 + i)
+                  if sampling == "seeded" else {})
+            rids.append(eng.submit(p, max_new_tokens=int(n),
+                                   eos_token_id=None, **kw))
+            want += served_alone(make, [p], [n], **kw)
+        while eng.pending:
+            eng.step()
+        got = [list(eng.request(r).output()) for r in rids]
+        assert got == want
+        st = eng.stats()
+        assert st["mixed_dispatches"] > 0 and st["decode_dispatches"] > 0
+        assert st["prefix_hit_tokens"] == later_admissions_hits(prompts, 3)
+        if sampling == "greedy" and kv_quant is None:
+            assert got == dense
+        elif sampling == "seeded":
+            assert got != dense
 
 
 @pytest.mark.tp
 class TestMixedParityTP:
     def test_tp2_parity(self, setup, tp_platform):
+        """The wave at tp=1 and on the two-way mesh: dense ``generate()``
+        streams both, and at tp=2 each request's own served alone."""
         cfg = tiny_cfg(num_attention_heads=4, num_key_value_heads=2)
         params = init_params(cfg, jax.random.PRNGKey(3))
         _, _, prompts, outs = setup
-        streams = {}
-        for mixed in (False, True):
-            for tp in (1, 2):
-                eng = mk(params, cfg, mixed, tp=tp)
-                got, st = drain_streams(eng, prompts, outs)
-                streams[(mixed, tp)] = got
-                if mixed:
-                    assert st["mixed_dispatches"] > 0
-        assert len({tuple(map(tuple, v)) for v in streams.values()}) == 1
+        want = dense_rows(params, cfg, prompts, outs)
+        for tp in (1, 2):
+            got, st = drain_streams(mk(params, cfg, tp=tp), prompts, outs)
+            assert st["mixed_dispatches"] > 0
+            assert got == want, tp
+        assert got == served_alone(lambda: mk(params, cfg, tp=2), prompts,
+                                   outs)
 
 
 class TestMixedDispatchShape:
@@ -227,7 +325,7 @@ class TestMixedDispatchShape:
         draft-less steps dispatch mixed. The two counters never move
         together within one step."""
         cfg, params, prompts, outs = setup
-        eng = mk(params, cfg, True, spec_decode=3, spec_ngram=2,
+        eng = mk(params, cfg, spec_decode=3, spec_ngram=2,
                  max_model_len=256, prefill_chunk=16)
         # a prompt in which prompt lookup MUST find a draft, whatever the
         # model emits: "c 0 c 1 c 2 ... c V-1 c" holds the bigram (c, x)
@@ -271,7 +369,7 @@ class TestMixedDispatchShape:
         sizes here stay inside ONE bucket (prefill_chunk=4 -> Q=8), so
         both trace counters go exactly flat after the first wave."""
         cfg, params, prompts, outs = setup
-        eng = mk(params, cfg, True)
+        eng = mk(params, cfg)
         drain_streams(eng, prompts, outs)
         st = eng.stats()
         assert st["mixed_traces"] == 1
@@ -289,13 +387,11 @@ class TestMixedDispatchShape:
         assert st["mixed_traces"] == m0 == 1
 
     def test_decode_advances_while_prompt_prefills(self, setup):
-        """The stall this PR removes, pinned directly: in the SAME
-        engine step that a newly admitted long prompt advances its
-        prefill chunk, an already-decoding slot emits its next token
-        (two-phase mode stalls the decoder behind the chunk dispatches
-        and the decode_chunk clamp instead)."""
+        """In the SAME engine step that a newly admitted long prompt
+        advances its prefill chunk, an already-decoding slot emits its
+        next token: a long admission stalls nobody."""
         cfg, params, prompts, outs = setup
-        eng = mk(params, cfg, True)
+        eng = mk(params, cfg)
         r0 = eng.submit(prompts[0], max_new_tokens=12, eos_token_id=None)
         eng.step()                             # r0 admits
         req0 = next(r for r in eng._sched.live if r.rid == r0)
@@ -318,25 +414,46 @@ class TestMixedDispatchShape:
         st = eng.stats()
         assert st["mixed_dispatches"] >= saw_same_step
 
-    def test_flag_default_and_override(self):
-        assert ServingConfig(**BASE).mixed_batch is True
-        assert ServingConfig(mixed_batch=False, **BASE).mixed_batch \
-            is False
-
-    def test_programs_shared_across_flag_values(self, setup):
-        """EnginePrograms carry jmixed keyed like the others: a two-phase
-        engine's programs rebuild a mixed engine (and vice versa) with
-        zero new traces — the supervisor/router shared-program contract."""
+    @pytest.mark.parametrize("kw, max_iters", [
+        (dict(), None), (dict(), 1),
+        (dict(num_blocks=14, prefix_cache=None), 1),
+        (dict(spec_decode=3, spec_ngram=2), None),
+        (dict(preempt=False), None)],
+        ids=["drain", "stream", "preempting", "spec", "reserved"])
+    def test_decode_loop_is_sized_with_no_row_prefilling(self, setup, kw,
+                                                         max_iters):
+        """``_limit`` sizes the decode loop from the decoding rows and the
+        queue alone: a step with a row mid-prefill dispatches the mixed
+        step (or a verify) and never asks it. Held on a drained wave and
+        on a staggered one with EOS on, whose admissions land while
+        others decode."""
         cfg, params, prompts, outs = setup
-        donor = mk(params, cfg, False)
-        a, _ = drain_streams(donor, prompts, outs)
-        eng = ServingEngine(params, cfg,
-                            ServingConfig(mixed_batch=True, **BASE),
-                            programs=donor.programs)
-        b, st = drain_streams(eng, prompts, outs)
-        assert a == b
-        assert st["mixed_dispatches"] > 0
-        assert st["mixed_traces"] == 1         # first mixed use traces it
+        eng = mk(params, cfg, **kw)
+        seen = []
+        limit = eng._limit
+
+        def spy(decoding, mi):
+            seen.append(any(r.prefilling for r in eng._sched.live))
+            return limit(decoding, mi)
+        eng._limit = spy
+        drain_streams(eng, prompts, outs, max_iters=max_iters)
+        for p, n in zip(prompts, outs):
+            eng.submit(p, max_new_tokens=int(n), eos_token_id=5)
+            eng.step(max_iters)
+        while eng.pending:
+            eng.step(max_iters)
+        assert seen and not any(seen)
+
+    def test_there_is_one_way_to_step(self):
+        """No field of ``ServingConfig`` and no flag selects a scheduler:
+        23 fields, none of them about mixing, and the name the switch
+        had is an unknown argument, like any other."""
+        from paddle_tpu.flags import get_flags
+        names = {f.name for f in dataclasses.fields(ServingConfig)}
+        assert len(names) == 23
+        assert not [n for n in [*names, *get_flags()] if "mixed" in n]
+        with pytest.raises(TypeError):
+            ServingConfig(**{"mixed" + "_batch": True}, **BASE)
 
 
 # ---------------------------------------------------------------------------
@@ -505,8 +622,8 @@ class TestPackedMixedStep:
     def test_engine_counts_the_lanes_its_waves_ran(self):
         """M = 2, chunks of 32: a wave is 32 lanes. Two prompts of whole
         chunks keep every mixed step in the Q = 32 bucket; while both
-        prefill a step holds 64 real lanes, two waves. Token streams equal
-        the two-phase engine's."""
+        prefill a step holds 64 real lanes, two waves. Token streams are
+        dense ``generate()``'s."""
         cfg = tiny_cfg(max_position_embeddings=160)
         params = init_params(cfg, jax.random.PRNGKey(0))
         rng = np.random.default_rng(3)
@@ -515,10 +632,9 @@ class TestPackedMixedStep:
         outs = [5, 3, 4]
         kw = dict(max_slots=2, max_model_len=128, prefill_chunk=32,
                   prefix_cache=None)
-        want, _ = drain_streams(mk(params, cfg, False, **kw), prompts, outs)
-        eng = mk(params, cfg, True, **kw)
+        eng = mk(params, cfg, **kw)
         got, st = drain_streams(eng, prompts, outs)
-        assert got == want
+        assert got == dense_rows(params, cfg, prompts, outs)
         c = st["spans"]["counters"]
         assert c["mixed_lanes_total"] == 32 * c["mixed_waves"]
         assert c["mixed_waves"] > st["mixed_dispatches"] > 0

@@ -422,13 +422,7 @@ def test_paged_programs_and_kernels_carry_names_in_the_tpu_lowering(
                temperature=0.8, top_k=8, seed=1)
     while eng.pending:
         eng.step()
-    two_phase = ServingEngine(params, cfg,
-                              dataclasses.replace(sc, mixed_batch=False),
-                              programs=eng.programs)
-    recorded(two_phase, "_jchunk")
-    two_phase.run([rep[:20]], max_new_tokens=2, eos_token_id=None)
     want = {"_jprefill": ("jit_paged_prefill", []),
-            "_jchunk": ("jit_paged_chunk", []),
             "_jdecode": ("jit_paged_decode", ["paged_attention_q1"]),
             "_jmixed": ("jit_paged_mixed", ["paged_attention_mq"]),
             "_jspec": ("jit_paged_spec", ["paged_attention_mq"]),

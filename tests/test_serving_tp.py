@@ -98,8 +98,8 @@ class TestTPBitParity:
         st = eng2.stats()
         assert st["decode_traces"] == 1
         assert st["tp_degree"] == 2
-        # second run warms the prefix-HIT path (the offset chunk program
-        # first traces here, exactly as at TP=1); the third run must then
+        # second run warms the prefix-HIT path (the mixed program first
+        # traces here, exactly as at TP=1); the third run must then
         # add zero executables anywhere
         outs2 = eng2.run(prompts, max_new_tokens=10, eos_token_id=None)
         assert _parity(outs2, oracle)
@@ -107,8 +107,8 @@ class TestTPBitParity:
         outs3 = eng2.run(prompts, max_new_tokens=10, eos_token_id=None)
         assert _parity(outs3, oracle)
         after = eng2.stats()
-        for k in ("decode_traces", "prefill_traces",
-                  "chunk_prefill_traces", "sample_traces", "spec_traces"):
+        for k in ("decode_traces", "prefill_traces", "mixed_traces",
+                  "sample_traces", "spec_traces"):
             assert after[k] == before[k], k
 
     def test_greedy_kernel(self, tp_platform, params, prompts):
