@@ -295,16 +295,29 @@ def kernel_cases(cfg, batch, seq, serving_config):
         return paged_attention(
             o["q"], o["k"], o["v"], o["tbl"], o["sl"],
             draft_lens=o["dl"] if o["q"].ndim == 4 else None,
-            k_scale=o.get("ks"), v_scale=o.get("vs"))
+            k_scale=o.get("ks"), v_scale=o.get("vs"), layer=o.get("layer"))
+
+    def whole(o):
+        """The operands with their pool as layer 1 of a pool of two (what
+        a paged program's layer scan carries); layer 0 is all NaN (an int8
+        pool's scales are), which no output may show."""
+        under = lambda x: jnp.stack([
+            jnp.zeros_like(x) if x.dtype == jnp.int8
+            else jnp.full_like(x, jnp.nan), x])
+        return {**o, **{n: under(o[n]) for n in ("k", "v", "ks", "vs")
+                        if n in o}, "layer": jnp.int32(1)}
 
     def paged_ref(o):
         multi = o["q"].ndim == 4
         q = o["q"] if multi else o["q"][:, None]
-        Q, Hk = q.shape[1], o["k"].shape[2]
+        Q, Hk = q.shape[1], o["k"].shape[-2]
         pz = {"k": o["k"], "v": o["v"]}
         if "ks" in o:
             pz.update(k_scale=o["ks"], v_scale=o["vs"])
-        kk, vv = _kv_gather(pz, o["tbl"], M, W * bs, Hk, D)
+        if "layer" not in o:                     # a pool of one layer
+            pz = {n: a[None] for n, a in pz.items()}
+        kk, vv = _kv_gather(pz, o.get("layer", 0), o["tbl"], M, W * bs, Hk,
+                            D)
         cap = jnp.minimum(jnp.arange(Q)[None, :], o["dl"][:, None]) \
             if multi else jnp.zeros((M, 1), jnp.int32)
         mask = jnp.arange(W * bs)[None, None, :] <= \
@@ -326,6 +339,14 @@ def kernel_cases(cfg, batch, seq, serving_config):
                     f"{Hq}/{Hkv} heads Q={Q}", paged, paged_ref,
                     lambda Q=Q, Hq=Hq, Hkv=Hkv, quant=quant: (_paged_operands(
                         key, M, Q, Hq, Hkv, D, bs, W, quant, dt),)))
+    # the form the paged programs call since ISSUE 30: every layer's pool
+    # and the layer's index, the kernel's copies indexing the layer
+    for quant, Q in ((False, sc.prefill_chunk or 8), (True, 1)):
+        cases.append((
+            f"paged_attention whole {'int8' if quant else 'fp'} pool layer "
+            f"1 of 2, {gqa[0]}/{gqa[1]} heads Q={Q}", paged, paged_ref,
+            lambda Q=Q, quant=quant: (whole(_paged_operands(
+                key, M, Q, *gqa, D, bs, W, quant, dt)),)))
 
     # --- weight-only int8 matmul at a decode step's M
     cases.append((

@@ -27,6 +27,11 @@ the real tokens** — pages a slot holds, query rows a slot carries.
   ``(bs, Hk, D)`` block, one copy serving every kv head — into a two-slot
   VMEM buffer, starting cell ``c + 1``'s copies before it computes cell
   ``c``. The ``[slots, W*bs, ...]`` gather is never materialized in HBM.
+  The pools may be EVERY layer's, ``[L, N, bs, Hk, D]``, with the layer a
+  further scalar-prefetch operand (``pool.at[layer, blk]`` is the copy's
+  source): a caller whose layer scan carries the whole pool hands it over
+  as it is, and no layer's slab is ever sliced out (by the pool's rank
+  alone: one kernel, one more index).
 * **One matmul pair a kv head a cell.** The kv heads are a static loop
   inside the cell; a head stacks its ``P * bs`` rows out of the resident
   pages and does ONE score matmul, ONE value matmul and ONE online-softmax
@@ -177,7 +182,8 @@ def _weighted_values(p, v):
     return out
 
 
-def _kernel(*refs, bs, W, P, scale, quant, Hk, G, Q, R0, TQ, kv_dtype):
+def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
+            kv_dtype):
     """One grid step = one slot ``m``; inside it a loop over the slot's
     LIVE cells of ``P`` KV pages. The pools stay in HBM: the kernel copies
     a cell's live pages into a two-slot VMEM buffer itself, the next
@@ -188,6 +194,10 @@ def _kernel(*refs, bs, W, P, scale, quant, Hk, G, Q, R0, TQ, kv_dtype):
     bs]``, positions along lanes) and scale the scores' and the weights'
     COLUMNS: ``(q · kᵀ) * ks`` and ``(p * vs) · v`` are the dequantized
     sums with the int8 values, exact in either float type, as operands.
+
+    ``layered``: the pools are every layer's, ``[L, N, bs, Hk, D]``, and
+    a last scalar-prefetch operand names the layer whose pages are copied
+    (``pool.at[layer, blk]``); nothing else differs.
 
     ``Q = 1`` is the single-token decode step. ``Q > 1`` is the
     multi-query entry point: a third scalar-prefetch operand carries each
@@ -200,6 +210,8 @@ def _kernel(*refs, bs, W, P, scale, quant, Hk, G, Q, R0, TQ, kv_dtype):
     tbl_ref, sl_ref = refs[:2]
     dl_ref = refs[2] if multi else None
     refs = refs[3 if multi else 2:]
+    if layered:
+        layer, refs = refs[0][0], refs[1:]
     q_ref, hbm = refs[0], refs[1:3]      # the K and V pools, in HBM
     ks_ref, vs_ref = refs[3:5] if quant else (None, None)
     o_ref, acc_ref, m_ref, l_ref, kbuf, vbuf, ksem, vsem = refs[-8:]
@@ -229,9 +241,10 @@ def _kernel(*refs, bs, W, P, scale, quant, Hk, G, Q, R0, TQ, kv_dtype):
             @pl.when(c * P + i < pages)
             def _live():
                 blk = tbl_ref[m, c * P + i]
+                at = (layer, blk) if layered else (blk,)
                 for pool, buf, sem in zip(hbm, (kbuf, vbuf), (ksem, vsem)):
                     go(pltpu.make_async_copy(
-                        pool.at[blk], buf.at[slot, i], sem.at[slot]))
+                        pool.at[at], buf.at[slot, i], sem.at[slot]))
         unrolled(P, page)
 
     def head_rows(buf, slot, h):
@@ -383,7 +396,8 @@ def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
                     draft_lens=None, k_scale=None, v_scale=None,
-                    scale: Optional[float] = None, out_dtype=None):
+                    scale: Optional[float] = None, out_dtype=None,
+                    layer=None):
     """Decode attention for ``M`` serving slots straight off the block pool.
 
     ``q [M, H, D]`` — one query token per slot (the decode entry point) —
@@ -395,9 +409,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     ``i > draft_lens[m]`` are not computed and come back as ZEROS: no
     caller reads them. ``k_pool``/``v_pool``
     ``[N, bs, Hk, D]`` — ONE layer's physical block pool (fp, or int8 with
-    ``k_scale``/``v_scale [N, bs, Hk]`` fp32 per-token-per-head scales);
-    ``block_tables [M, W]`` int32 — slot ``m``'s KV position ``j`` lives in
-    physical block ``block_tables[m, j // bs]`` at offset ``j % bs``;
+    ``k_scale``/``v_scale [N, bs, Hk]`` fp32 per-token-per-head scales) —
+    or, told apart by rank, EVERY layer's, ``[L, N, bs, Hk, D]`` (scales
+    ``[L, N, bs, Hk]``) with ``layer``, an int32 scalar that may be
+    traced: the kernel's page copies index the layer, so a caller that
+    holds the whole pool (a layer scan's carry) never slices a layer out
+    of it. ``block_tables [M, W]`` int32 — slot ``m``'s KV position ``j``
+    lives in physical block ``block_tables[m, j // bs]`` at offset ``j %
+    bs``;
     ``seq_lens [M]`` int32 — slot ``m`` attends positions ``j <=
     seq_lens[m]`` (its new token's KV was just scattered at ``seq_lens[m]``).
     Table entries past a slot's window are never read. Returns
@@ -418,7 +437,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
             raise ValueError("paged_attention: draft_lens given with a "
                              "single-token q [M, H, D]; the verify entry "
                              "point takes q [M, Q, H, D]")
-    N, bs, Hk, _ = k_pool.shape
+    layered = k_pool.ndim == 5
+    if layered != (layer is not None):
+        raise ValueError(
+            "paged_attention: a pool of every layer [L, N, bs, Hk, D] is "
+            "read at `layer`, one layer's [N, bs, Hk, D] without it; got "
+            f"a rank-{k_pool.ndim} pool and layer={layer!r}")
+    bs, Hk = k_pool.shape[-3:-1]
     W = block_tables.shape[1]
     if H % Hk:
         raise ValueError(f"paged_attention: {H} query heads not divisible "
@@ -455,6 +480,22 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     # — every index map takes them positionally after the grid indices
     scalars = (tbl, sl, jnp.asarray(draft_lens, jnp.int32)) if multi \
         else (tbl, sl)
+    # a kernel's own copy slices HBM in whole 32-bit rows of the (Hk, D)
+    # plane. A pool with fewer kv heads than one such row packs (bf16
+    # under 2, int8 under 4: a TP shard of a GQA model) is padded up to it
+    # here — a copy of the shard's one layer a call, the price of that
+    # shape (of a whole pool the layer is sliced out first, so the price
+    # stays a layer's)
+    narrow = -Hk % max(1, 4 // k_pool.dtype.itemsize)
+    if narrow:
+        if layered:
+            k_pool, v_pool = (jax.lax.dynamic_index_in_dim(
+                x, layer, 0, keepdims=False) for x in (k_pool, v_pool))
+        k_pool, v_pool = (jnp.pad(x, ((0, 0), (0, 0), (0, narrow), (0, 0)))
+                          for x in (k_pool, v_pool))
+    in_kernel = layered and not narrow     # the kernel indexes the layer
+    if in_kernel:
+        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
 
     def qmap(m, *_):
         return (m, 0, 0, 0)
@@ -466,14 +507,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     in_specs = [pl.BlockSpec((1, Hk, QG, D), qmap),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY)]
-    # a kernel's own copy slices HBM in whole 32-bit rows of the (Hk, D)
-    # plane. A pool with fewer kv heads than one such row packs (bf16
-    # under 2, int8 under 4: a TP shard of a GQA model) is padded up to it
-    # here — a copy of the shard's pool a call, the price of that shape
-    narrow = -Hk % max(1, 4 // k_pool.dtype.itemsize)
-    if narrow:
-        k_pool, v_pool = (jnp.pad(x, ((0, 0), (0, 0), (0, narrow), (0, 0)))
-                          for x in (k_pool, v_pool))
     ops = [qg, k_pool, v_pool]
     if quant:
         # a page's scale tile is (bs, Hk) fp32, narrower than anything a
@@ -483,8 +516,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
         whole_cells = jnp.pad(tbl, ((0, 0), (0, cells * P - W)))
 
         def by_cell(plane):
-            return plane[whole_cells].reshape(M, cells, P * bs, Hk) \
-                             .transpose(0, 1, 3, 2)
+            pages = plane[layer, whole_cells] if layered \
+                else plane[whole_cells]
+            return pages.reshape(M, cells, P * bs, Hk).transpose(0, 1, 3, 2)
         in_specs += [pl.BlockSpec((1, cells, Hk, P * bs), qmap)] * 2
         ops += [by_cell(k_scale), by_cell(v_scale)]
 
@@ -497,16 +531,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
             pltpu.VMEM((Hk, QG, D), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
-            pltpu.VMEM((2, P) + k_pool.shape[1:], k_pool.dtype),
-            pltpu.VMEM((2, P) + v_pool.shape[1:], v_pool.dtype),
+            pltpu.VMEM((2, P) + k_pool.shape[-3:], k_pool.dtype),
+            pltpu.VMEM((2, P) + v_pool.shape[-3:], v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, W=W, P=P, scale=scale,
-                          quant=quant, Hk=Hk, G=G, Q=Q, R0=R0, TQ=TQ,
-                          kv_dtype=kv_dtype),
+                          quant=quant, layered=in_kernel, Hk=Hk, G=G, Q=Q,
+                          R0=R0, TQ=TQ, kv_dtype=kv_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, Hk, QG, D), out_dtype),
         compiler_params=pltpu.CompilerParams(

@@ -35,6 +35,10 @@ TPU redesign, not a translation:
   docs/SERVING.md): one physical block pool shared by every slot,
   gather-based attention over each sequence's own blocks, token-level
   bit-parity with the dense cache path pinned by tests/test_serving.py.
+  The pool is loop STATE of every paged program, carried whole through
+  the layer scan and written and read at the layer's index
+  (:func:`_layer_xs`): never a scanned operand, which would be a second
+  buffer.
 
 MoE caveat: GShard routing capacity is evaluated per forward call, so a
 decode step routes B tokens in isolation while a full no-cache forward
@@ -766,62 +770,60 @@ def _kv_quantize(x):
     return q.astype(jnp.int8), scale
 
 
-def _kv_store(p: Dict, phys, off, k, v):
-    """Scatter freshly computed ``k``/``v [..., Hk, D]`` into one layer's
-    pool slice at ``(phys, off)`` (quantizing when the pool is int8).
-    Returns ``(new_pool_layer, k_attend, v_attend)`` — the attend pair is
+def _kv_store(p: Dict, layer, phys, off, k, v):
+    """Scatter freshly computed ``k``/``v [..., Hk, D]`` into the WHOLE
+    pool at ``(layer, phys, off)`` (quantizing when the pool is int8): an
+    in-place write of the new entries into leaves ``[L, N, bs, Hk, D]``
+    that a layer scan carries, never a layer's slab sliced out and put
+    back. Returns ``(new_pool, k_attend, v_attend)`` — the attend pair is
     what LATER READS of these entries will observe (identity for fp pools,
     the int8 round-trip for quantized ones), so the batched prefill can
     attend exactly the values decode will gather back and every engine
     path sees ONE consistent view of a KV entry."""
-    out = dict(p)
+    at = (layer, phys, off)
     if "k_scale" in p:
         qk, sk = _kv_quantize(k)
         qv, sv = _kv_quantize(v)
-        out["k"] = p["k"].at[phys, off].set(qk)
-        out["v"] = p["v"].at[phys, off].set(qv)
-        out["k_scale"] = p["k_scale"].at[phys, off].set(sk)
-        out["v_scale"] = p["v_scale"].at[phys, off].set(sv)
+        out = {"k": p["k"].at[at].set(qk), "v": p["v"].at[at].set(qv),
+               "k_scale": p["k_scale"].at[at].set(sk),
+               "v_scale": p["v_scale"].at[at].set(sv)}
         return out, qk.astype(jnp.float32) * sk[..., None], \
             qv.astype(jnp.float32) * sv[..., None]
-    out["k"] = p["k"].at[phys, off].set(k.astype(p["k"].dtype))
-    out["v"] = p["v"].at[phys, off].set(v.astype(p["v"].dtype))
-    return out, k, v
+    return {"k": p["k"].at[at].set(k.astype(p["k"].dtype)),
+            "v": p["v"].at[at].set(v.astype(p["v"].dtype))}, k, v
 
 
-def _kv_gather(p: Dict, block_tables, B: int, C: int, Hk: int, D: int):
-    """Gather one layer's pool through the block tables into logical order
-    ``[B, C, Hk, D]``, dequantizing int8 pools after the gather — the XLA
-    FALLBACK path (``_masked_sdpa`` consumes the result). The Pallas
-    kernel (``kernels.paged_attention``) never materializes this."""
-    kk = p["k"][block_tables].reshape(B, C, Hk, D)
-    vv = p["v"][block_tables].reshape(B, C, Hk, D)
+def _kv_gather(p: Dict, layer, block_tables, B: int, C: int, Hk: int,
+               D: int):
+    """Gather layer ``layer`` of the pool through the block tables into
+    logical order ``[B, C, Hk, D]``, dequantizing int8 pools after the
+    gather — the XLA FALLBACK path (``_masked_sdpa`` consumes the result).
+    The Pallas kernel (``kernels.paged_attention``) never materializes
+    this."""
+    kk = p["k"][layer, block_tables].reshape(B, C, Hk, D)
+    vv = p["v"][layer, block_tables].reshape(B, C, Hk, D)
     if "k_scale" in p:
-        ks = p["k_scale"][block_tables].reshape(B, C, Hk)
-        vs = p["v_scale"][block_tables].reshape(B, C, Hk)
+        ks = p["k_scale"][layer, block_tables].reshape(B, C, Hk)
+        vs = p["v_scale"][layer, block_tables].reshape(B, C, Hk)
         kk = kk.astype(jnp.float32) * ks[..., None]
         vv = vv.astype(jnp.float32) * vs[..., None]
     return kk, vv
 
 
-def _lora_xs(params: Dict, pool: Dict, lora: Optional[Dict]):
-    """Scan xs for one paged forward pass: the stacked layer weights and
-    the pool, plus — when multi-adapter LoRA serving is on — the stacked
-    adapter-pool leaves (``lora["layers"]``, sliced per layer alongside
-    the weights; see ``models.lora``). ``lora`` is ``None`` on LoRA-less
-    builds, which keeps the traced computation BYTE-IDENTICAL to the
-    pre-LoRA engine — the zero-cost-for-base-traffic contract."""
-    if lora is None:
-        return (params["layers"], pool)
-    return (params["layers"], pool, lora["layers"])
-
-
-def _lora_unpack(xs):
-    """(layer params, pool layer, adapter layer or None) from scan xs."""
-    if len(xs) == 2:
-        lp, pz = xs
-        return lp, pz, None
-    return xs
+def _layer_xs(params: Dict, lora: Optional[Dict]):
+    """Scan xs for one paged forward pass: the stacked layer weights, each
+    layer's index, and — when multi-adapter LoRA serving is on — the
+    stacked adapter-pool leaves (``lora["layers"]``, sliced per layer
+    alongside the weights; see ``models.lora``), else ``None``: no leaf,
+    which keeps the traced computation that of the pre-LoRA engine — the
+    zero-cost-for-base-traffic contract. The POOL is not among them: a
+    scan's ``xs`` and ``ys`` are other buffers than its argument (a
+    layer's slab sliced out and written back, the stack copied whole), so
+    the pool rides in the scan's carry and is written and read at the
+    layer's index."""
+    L = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
+    return (params["layers"], jnp.arange(L, dtype=jnp.int32),
+            None if lora is None else lora["layers"])
 
 
 def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
@@ -868,8 +870,9 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
 
     x = jnp.take(params["embed"], ids, axis=0).astype(dt)
 
-    def body(h, xs):
-        lp, pz, ll = _lora_unpack(xs)
+    def body(carry, xs):
+        h, pool = carry
+        lp, layer, ll = xs
         hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.use_fused_norm)
         q = _mm(hh, lp, "wq", dt)
         k = _mm(hh, lp, "wk", dt)
@@ -884,16 +887,16 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
         v = v.reshape(B, Sb, Hk, D)
         q = _rope(q, cos, sin, False)
         k = _rope(k, cos, sin, False)
-        pz, ka, va = _kv_store(pz, phys, off, k, v)
+        pool, ka, va = _kv_store(pool, layer, phys, off, k, v)
         o = _masked_sdpa(q, ka, va, kv_mask)
         m = _merge_heads(o, cfg).astype(dt)
         d = _mm(m, lp, "wo", dt)
         if ll is not None:
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         h = h + d
-        return _ffn_tail(lp, h, cfg)[0], pz
+        return (_ffn_tail(lp, h, cfg)[0], pool), None
 
-    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
+    (x, pool), _ = lax.scan(body, (x, pool), _layer_xs(params, lora))
     idx = jnp.maximum(prompt_lens - 1, 0)[:, None, None]
     last = jnp.take_along_axis(x, idx, axis=1)          # [B, 1, E]
     return _lm_head(params, cfg, last), pool, _lane_counts(B * Sb)
@@ -943,8 +946,9 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
 
     x = jnp.take(params["embed"], tokens[:, None], axis=0).astype(dt)
 
-    def body(h, xs):
-        lp, pz, ll = _lora_unpack(xs)
+    def body(carry, xs):
+        h, pool = carry
+        lp, layer, ll = xs
         hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.use_fused_norm)
         q = _mm(hh, lp, "wq", dt)
         k = _mm(hh, lp, "wk", dt)
@@ -959,23 +963,24 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
         v = v.reshape(M, 1, Hk, D)
         q = _rope(q, cos, sin, False)
         k = _rope(k, cos, sin, False)
-        pz, _, _ = _kv_store(pz, phys, off, k[:, 0], v[:, 0])
+        pool, _, _ = _kv_store(pool, layer, phys, off, k[:, 0], v[:, 0])
         if use_kernel:
             from ..kernels.paged_attention import paged_attention
-            o = paged_attention(q[:, 0], pz["k"], pz["v"], block_tables,
-                                seq_lens, k_scale=pz.get("k_scale"),
-                                v_scale=pz.get("v_scale"))[:, None]
+            o = paged_attention(q[:, 0], pool["k"], pool["v"], block_tables,
+                                seq_lens, k_scale=pool.get("k_scale"),
+                                v_scale=pool.get("v_scale"),
+                                layer=layer)[:, None]
         else:
-            kk, vv = _kv_gather(pz, block_tables, M, C, Hk, D)
+            kk, vv = _kv_gather(pool, layer, block_tables, M, C, Hk, D)
             o = _masked_sdpa(q, kk, vv, kv_mask)
         m = _merge_heads(o, cfg).astype(dt)
         d = _mm(m, lp, "wo", dt)
         if ll is not None:
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         h = h + d
-        return _ffn_tail(lp, h, cfg)[0], pz
+        return (_ffn_tail(lp, h, cfg)[0], pool), None
 
-    x, pool = lax.scan(body, x, _lora_xs(params, pool, lora))
+    (x, pool), _ = lax.scan(body, (x, pool), _layer_xs(params, lora))
     return _lm_head(params, cfg, x), pool, _lane_counts(M)
 
 
@@ -1191,8 +1196,9 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
             return lora_delta(hh.reshape(M, Q, -1), la, lb, lora["ids"],
                               dt).reshape(1, Tw, -1)
 
-        def body(h, xs):
-            lp, pz, ll = _lora_unpack(xs)
+        def body(carry, xs):
+            h, pool = carry
+            lp, layer, ll = xs
             hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
                            cfg.use_fused_norm)
             q, k, v = _mm_qkv(hh, lp, dt)
@@ -1202,7 +1208,8 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
                 v = v + delta(hh, ll, "v")
             q = _rope(q.reshape(1, Tw, H, D), cos, sin, False)
             k = _rope(k.reshape(1, Tw, Hk, D), cos, sin, False)
-            pz, _, _ = _kv_store(pz, phys, off, k, v.reshape(1, Tw, Hk, D))
+            pool, _, _ = _kv_store(pool, layer, phys, off, k,
+                                   v.reshape(1, Tw, Hk, D))
             if packed:
                 # row m's lanes are contiguous in the wave: M slices of Qa
                 # lanes (past the wave's end: zeros no one reads)
@@ -1213,12 +1220,13 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
                 q = q.reshape(M, Q, H, D)
             if use_kernel:
                 from ..kernels.paged_attention import paged_attention
-                o = paged_attention(q, pz["k"], pz["v"], block_tables,
+                o = paged_attention(q, pool["k"], pool["v"], block_tables,
                                     att_start, draft_lens=att_dl,
-                                    k_scale=pz.get("k_scale"),
-                                    v_scale=pz.get("v_scale"))
+                                    k_scale=pool.get("k_scale"),
+                                    v_scale=pool.get("v_scale"),
+                                    layer=layer)
             else:
-                kk, vv = _kv_gather(pz, block_tables, M, C, Hk, D)
+                kk, vv = _kv_gather(pool, layer, block_tables, M, C, Hk, D)
                 o = _masked_sdpa(q, kk, vv, kv_mask)
             if packed:
                 o = o.reshape(M * Qa, H, D)[
@@ -1228,10 +1236,11 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
             d = _mm(m, lp, "wo", dt)
             if ll is not None:
                 d = d + delta(m, ll, "o")
-            return _ffn_tail(lp, h + d, cfg)[0], pz
+            return (_ffn_tail(lp, h + d, cfg)[0], pool), None
 
         x = jnp.take(params["embed"], tokens[row, qi], axis=0).astype(dt)
-        x, pool = lax.scan(body, x[None], _lora_xs(params, pool, lora))
+        (x, pool), _ = lax.scan(body, (x[None], pool),
+                                _layer_xs(params, lora))
         return x[0], pool
 
     if not packed:

@@ -11,6 +11,7 @@ paddle_tpu``; unset, the one resolver answers ``<checkout>/.jax_cache``).
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -55,14 +56,15 @@ class TestStagesAtToySize:
     RUN = ("flash_attention varlen fwd+bwd",
            "paged_attention int8 pool 8/1 heads Q=8",
            "paged_attention fp pool 4/4 heads Q=1",
+           "paged_attention whole fp pool layer 1 of 2, 8/1 heads Q=8",
            "quant_matmul M=8", "rms_norm fwd+bwd", "apply_rope")
 
     def test_kernel_rollcall(self, monkeypatch):
         sc = ServingConfig(**TOY_SC)
         cases = cs.kernel_cases(TOY, 2, 16, sc)
         names = [c[0] for c in cases]
-        assert len(set(names)) == 14 and set(self.RUN) <= set(names)
-        for family, n in (("flash_attention", 3), ("paged_attention", 8),
+        assert len(set(names)) == 16 and set(self.RUN) <= set(names)
+        for family, n in (("flash_attention", 3), ("paged_attention", 10),
                           ("quant_matmul", 1), ("rms_norm", 1),
                           ("apply_rope", 1)):
             assert sum(k.startswith(family) for k in names) == n
@@ -192,12 +194,12 @@ def _sat_cell_cases(cases, more_chunks=(), shards=(1,)):
             for Q in ((1,) + chunks if tp == 1 else chunks[:1])]
 
 
-def _sat_cell_mixed_step_case():
+def _sat_cell_mixed_step_case(layers=1):
     """The PACKED ``paged_mixed_step`` (ISSUE 28) around that kernel, at
     the serving cell's shapes: 32 slots x a 128-token chunk, the pool's
-    3072 blocks, the cell's widths, ONE layer of its 16. What the waves
-    add to the program (a loop around the layer scan, the row view's
-    slices and gathers) meets the TPU's compiler here."""
+    3072 blocks, the cell's widths, ``layers`` of its 16 layers. What the
+    waves add to the program (a loop around the layer scan, the row
+    view's slices and gathers) meets the TPU's compiler here."""
     from paddle_tpu.models import generation as G
     from paddle_tpu.models.llama import LlamaConfig, init_params
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -208,7 +210,7 @@ def _sat_cell_mixed_step_case():
         **{k: c[k] for k in (
             "vocab_size", "hidden_size", "intermediate_size",
             "num_attention_heads", "num_key_value_heads", "rms_norm_eps",
-            "rope_theta")}, num_hidden_layers=1,
+            "rope_theta")}, num_hidden_layers=layers,
         max_position_embeddings=eng["max_model_len"], dtype=jnp.bfloat16,
         param_dtype=jnp.bfloat16)
     M, Q, bs = eng["max_slots"], eng["prefill_chunk"], eng["block_size"]
@@ -228,8 +230,8 @@ def _sat_cell_mixed_step_case():
             o["params"], cfg, o["tokens"], o["starts"], o["q_lens"],
             o["tables"], o["pool"], o["active"], use_kernel=True)
 
-    return (f"paged_mixed_step sat cell packed {M}x{Q}, one layer", step,
-            None, build)
+    return (f"paged_mixed_step sat cell packed {M}x{Q}, {layers} layer(s)",
+            step, None, build)
 
 
 def test_every_kernel_lowers_for_tpu(monkeypatch):
@@ -274,6 +276,24 @@ def test_every_kernel_compiles_for_v5e_without_a_chip(monkeypatch):
                                                sharding=where),
                 jax.eval_shape(build))
             jax.jit(fn).lower(*args).compile()
+        # ISSUE 30: the pool is loop state of ONE buffer. With the pool
+        # donated, as the engine donates it, the packed step over two
+        # layers holds no copy of the pool and never materialises a
+        # layer's slab (until then: two ``copy`` of the whole pool a wave,
+        # a ``dynamic-slice`` and a write-back of 100 MB a layer)
+        _, step, _, build = _sat_cell_mixed_step_case(layers=2)
+        (o,) = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            jax.eval_shape(build))
+        pool = o.pop("pool")
+        text = jax.jit(lambda pool, o: step({**o, "pool": pool}),
+                       donate_argnums=0).lower(pool, o).compile().as_text()
+    whole = "bf16[%s]" % ",".join(map(str, pool["k"].shape))
+    slab = "bf16[%s]" % ",".join(map(str, pool["k"].shape[1:]))
+    assert f" = {whole}{{" in text               # the pool is in the text
+    ops = re.findall(r" = (bf16\[[\d,]+\])\{[^}]*\} ([\w\-]+)\(", text)
+    assert (whole, "copy") not in ops
+    assert not [op for shape, op in ops if shape == slab]
 
 
 def _moe_cell_cases():

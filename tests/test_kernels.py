@@ -304,7 +304,8 @@ class TestPagedAttention:
         M = q.shape[0]
         N, bs, Hk, D = pool["k"].shape
         C = tbl.shape[1] * bs
-        kk, vv = _kv_gather(pool, tbl, M, C, Hk, D)
+        kk, vv = _kv_gather({n: a[None] for n, a in pool.items()}, 0, tbl,
+                            M, C, Hk, D)     # a pool of one layer
         mask = (jnp.arange(C)[None, :] <= sl[:, None])[:, None, :]
         return _masked_sdpa(q[:, None], kk, vv, mask)[:, 0]
 
@@ -410,7 +411,8 @@ class TestPagedAttention:
         M, Q = q.shape[:2]
         N, bs, Hk, D = pool["k"].shape
         C = tbl.shape[1] * bs
-        kk, vv = _kv_gather(pool, tbl, M, C, Hk, D)
+        kk, vv = _kv_gather({n: a[None] for n, a in pool.items()}, 0, tbl,
+                            M, C, Hk, D)     # a pool of one layer
         qi = jnp.arange(Q)
         hi = sl[:, None] + jnp.minimum(qi[None, :], dl[:, None])  # [M, Q]
         mask = jnp.arange(C)[None, None, :] <= hi[:, :, None]
@@ -558,6 +560,65 @@ class TestPagedAttention:
             np.testing.assert_allclose(
                 np.asarray(out, np.float32)[short, 0],
                 np.asarray(single, np.float32)[short], **tol)
+
+    @pytest.mark.parametrize("pool_kind", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("Hk", [1, 4])
+    @pytest.mark.parametrize("Q", [1, 8])
+    def test_whole_pool_reads_its_layer(self, Q, Hk, pool_kind):
+        """ISSUE 30: handed EVERY layer's pool ``[L, N, bs, Hk, D]`` and
+        ``layer`` (traced, as under a layer scan) the kernel returns, for
+        each layer, what the one-layer call on ``pool[layer]`` returns,
+        bit for bit: decode and multi-query form, fp32, bf16 and int8
+        with its scale planes ``[L, N, bs, Hk]``. ``Hk`` 1 is the
+        ``narrow`` shape under bf16 and int8 (fewer kv heads than a
+        32-bit row packs: the layer is sliced out and padded, not the
+        whole pool)."""
+        from paddle_tpu.kernels.paged_attention import paged_attention
+        L, M, G, D, bs, W = 3, 3, 2, 8, 4, 3
+        N = M * W + 2
+        dt = jnp.bfloat16 if pool_kind == "bf16" else jnp.float32
+        rng = np.random.default_rng(30)
+        q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((L, N, bs, Hk, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((L, N, bs, Hk, D)), dt)
+        tbl = jnp.asarray(rng.permutation(np.arange(1, M * W + 1))
+                          .reshape(M, W), jnp.int32)
+        sl = jnp.asarray([0, bs, W * bs - Q], jnp.int32)
+        kw = {"draft_lens": jnp.asarray([0, Q - 1, Q // 2], jnp.int32)} \
+            if Q > 1 else {}
+        qq = q if Q > 1 else q[:, 0]
+        pool = {"k": kf, "v": vf}
+        if pool_kind == "int8":
+            (kq, ks), (vq, vs) = self._quantize(kf), self._quantize(vf)
+            pool = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+
+        def call(p, **layer):
+            return paged_attention(qq, p["k"], p["v"], tbl, sl,
+                                   k_scale=p.get("k_scale"),
+                                   v_scale=p.get("v_scale"), **kw, **layer)
+
+        whole = jax.jit(lambda layer: call(pool, layer=layer))
+        one = jax.jit(lambda layer: call({n: a[layer]
+                                          for n, a in pool.items()}))
+        outs = [np.asarray(whole(jnp.int32(i)), np.float32)
+                for i in range(L)]
+        for i, out in enumerate(outs):
+            np.testing.assert_array_equal(
+                out, np.asarray(one(jnp.int32(i)), np.float32))
+        assert not np.array_equal(outs[0], outs[1])   # the layers differ
+
+    def test_pool_rank_and_layer_go_together(self):
+        """A pool of every layer without ``layer``, or one layer's with
+        it, raises: read silently, either would attend another layer's
+        (or another block's) entries."""
+        from paddle_tpu.kernels.paged_attention import paged_attention
+        q = jnp.zeros((1, 2, 8), jnp.float32)
+        k = jnp.zeros((2, 3, 4, 1, 8), jnp.float32)
+        tbl, sl = jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32)
+        with pytest.raises(ValueError, match="layer"):
+            paged_attention(q, k, k, tbl, sl)
+        with pytest.raises(ValueError, match="layer"):
+            paged_attention(q, k[0], k[0], tbl, sl, layer=0)
 
     def test_multiquery_requires_draft_lens(self):
         """Both halves of the entry-point contract: rank-4 q needs

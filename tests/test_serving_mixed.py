@@ -485,8 +485,21 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
                                                qcap)[:, :, None]
     x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
 
+    def store(p, k, v):
+        """One layer's slab ``[N, bs, Hk, D]`` with the new entries in."""
+        out = dict(p)
+        if "k_scale" in p:
+            (k, sk), (v, sv) = G._kv_quantize(k), G._kv_quantize(v)
+            out["k_scale"] = p["k_scale"].at[phys, off].set(sk)
+            out["v_scale"] = p["v_scale"].at[phys, off].set(sv)
+        out["k"] = p["k"].at[phys, off].set(k.astype(p["k"].dtype))
+        out["v"] = p["v"].at[phys, off].set(v.astype(p["v"].dtype))
+        return out
+
     def body(h, xs):
-        lp, pz, ll = G._lora_unpack(xs)
+        # the pool a layer through the scan's xs and ys, as every paged
+        # program of this family carried it until ISSUE 30
+        lp, pz, ll = xs
         hh = _rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
                        cfg.use_fused_norm)
         q, k, v = (_mm(hh, lp, n, dt) for n in ("wq", "wk", "wv"))
@@ -497,7 +510,7 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
             v = v + lora_delta(hh, ll["vA"], ll["vB"], lids, dt)
         q = _rope(q.reshape(M, Q, H, D), cos, sin, False)
         k = _rope(k.reshape(M, Q, Hk, D), cos, sin, False)
-        pz, _, _ = G._kv_store(pz, phys, off, k, v.reshape(M, Q, Hk, D))
+        pz = store(pz, k, v.reshape(M, Q, Hk, D))
         if use_kernel:
             from paddle_tpu.kernels.paged_attention import paged_attention
             o = paged_attention(q, pz["k"], pz["v"], block_tables, starts,
@@ -505,7 +518,8 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
                                 k_scale=pz.get("k_scale"),
                                 v_scale=pz.get("v_scale"))
         else:
-            kk, vv = G._kv_gather(pz, block_tables, M, C, Hk, D)
+            kk, vv = G._kv_gather({n: a[None] for n, a in pz.items()}, 0,
+                                  block_tables, M, C, Hk, D)
             o = _masked_sdpa(q, kk, vv, kv_mask)
         m = G._merge_heads(o, cfg).astype(dt)
         d = _mm(m, lp, "wo", dt)
@@ -513,7 +527,8 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
             d = d + lora_delta(m, ll["oA"], ll["oB"], lora["ids"], dt)
         return G._ffn_tail(lp, h + d, cfg)[0], pz
 
-    x, pool = jax.lax.scan(body, x, G._lora_xs(params, pool, lora))
+    x, pool = jax.lax.scan(body, x, (
+        params["layers"], pool, None if lora is None else lora["layers"]))
     last = jnp.take_along_axis(x, draft_lens[:, None, None], axis=1)
     return G._lm_head(params, cfg, last), pool
 
@@ -645,3 +660,72 @@ class TestPackedMixedStep:
         assert eng.health_snapshot()["family"] == {
             "lanes_computed": c["lanes_computed"],
             "mixed_waves": c["mixed_waves"]}
+
+
+# ---------------------------------------------------------------------------
+# the pool is loop state of ONE buffer, never a scanned operand (ISSUE 30)
+# ---------------------------------------------------------------------------
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, those inside loops, branches
+    and calls too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def _pool_program(name, pool_kind, use_kernel):
+    """(a paged program of the family as ``fn(pool)``, the pool), toy size."""
+    cfg = tiny_cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    M, Q, bs, W = 3, 8, 4, 6
+    pool = G.init_paged_pool(cfg, 1 + M * W, bs, kv_quant=pool_kind)
+    tables = jnp.asarray(1 + np.arange(M * W).reshape(M, W), jnp.int32)
+    tokens = jnp.ones((M, Q), jnp.int32)
+    lens = jnp.asarray([3, 1, 5], jnp.int32)
+    active = jnp.ones((M,), bool)
+    programs = {
+        "paged_prefill": lambda pool: G.paged_prefill(
+            params, cfg, tokens, lens, tables, pool, active,
+            use_kernel=use_kernel),
+        "paged_decode_step": lambda pool: G.paged_decode_step(
+            params, cfg, tokens[:, 0], lens, tables, pool, active,
+            use_kernel=use_kernel),
+        "paged_mixed_step": lambda pool: G.paged_mixed_step(
+            params, cfg, tokens, lens, lens, tables, pool, active,
+            use_kernel=use_kernel),
+        "paged_spec_step": lambda pool: G.paged_spec_step(
+            params, cfg, tokens, lens, lens, tables, pool, active,
+            use_kernel=use_kernel)}
+    return programs[name], pool
+
+
+class TestPoolStaysOutOfTheLayerScan:
+    @pytest.mark.parametrize("pool_kind", [None, "int8"])
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    @pytest.mark.parametrize("name", ["paged_prefill", "paged_decode_step",
+                                      "paged_mixed_step", "paged_spec_step"])
+    def test_the_pool_is_carried_whole(self, name, use_kernel, pool_kind):
+        """In the program's jaxpr no scan has an ``xs`` or a ``ys`` of a
+        pool leaf's shape (a scanned operand is another
+        buffer than the argument: a layer's slab sliced out and written
+        back, the stack copied whole once a loop trip; ``PERF.md`` §6, PR
+        30), and every leaf of the pool is among the layer scan's
+        carries."""
+        fn, pool = _pool_program(name, pool_kind, use_kernel)
+        leaves = sorted({a.shape for a in pool.values()})
+        shape = lambda v: tuple(v.aval.shape)
+        holders = []
+        for eqn in _scans(jax.make_jaxpr(fn)(pool).jaxpr):
+            p = eqn.params                # the kernel's loops are scans too
+            n_in, n_out = p["num_consts"] + p["num_carry"], p["num_carry"]
+            scanned = [shape(v) for v in (*eqn.invars[n_in:],
+                                          *eqn.outvars[n_out:])]
+            assert not [s for s in scanned if s in leaves], scanned
+            carries = [shape(v) for v in eqn.outvars[:n_out]]
+            if all(s in carries for s in leaves):
+                holders.append((p["length"], (p["length"],) in scanned))
+        # the layer scan, and no other: the stack's length, a layer's index
+        assert holders == [(tiny_cfg().num_hidden_layers, True)]
