@@ -63,10 +63,18 @@ there. What it brings:
     ``LAYER``, ``MOVES``, ``UNIT`` and ``read(run)``; a reader that finds
     nothing to read returns ``None``.
 ``BENCHMARK.json``
-    the ``configs`` and ``workloads`` entries, a ``per_layer`` entry for
-    each new reader, and the new cell's name appended to the
-    ``workloads`` list of every end-to-end and per-layer metric it
-    reports.
+    the ``configs`` and ``workloads`` entries; the new cell's name
+    APPENDED to the ``workloads`` list of every end-to-end metric it
+    reports and of every reader that reads in it (a reader's list names
+    the cells it finds something to read in, not the one it was written
+    for); and a ``per_layer`` entry for each new reader, APPENDED at the
+    end of ``per_layer``, after whatever is last then. Append, never
+    insert: an entry that is there keeps its index, because the driver
+    reads an entry put in the middle as a change to the one whose place
+    it takes. That is all the tests hold of the order
+    (``tests/benchmark/test_benchmark_contract.py`` does to a copy of the
+    file what such a PR does): none pins the end of ``per_layer`` or a
+    reader's ``workloads`` to one cell.
 
 ``tests/benchmark/rehearsal/`` is the proof on the CPU: its ``toy.train``
 cell runs a model this directory has no file for, through

@@ -140,94 +140,232 @@ def _line(text):
     return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
+# ---------------------------------------------------------------------------
+# what a ``BENCHMARK.json`` of the repo's kind has to be. Each takes the
+# parsed file, so the file itself and a dictionary a test made of it go
+# through the same code (``test_the_next_configuration_*`` below)
+# ---------------------------------------------------------------------------
+
+def check_top_level_keys(bench):
+    assert sorted(bench) == sorted([
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"])
+    assert 1 <= bench["run_seconds"] <= 51
+    assert isinstance(bench["run_seconds"], int)
+    assert len(json.dumps(bench)) < 64 * 1024
+    assert all(_line(w) for w in bench["command"])
+    assert all(PATH.match(p) and not p.startswith("/") and ".." not in p
+               for p in bench["paths"])
+
+
+def check_names_and_units(bench):
+    names = []
+    for group, keys in (
+            ("configs", {"name", "source", "file", "reduced", "why"}),
+            ("workloads", {"name", "config", "traffic", "chips", "why"})):
+        for e in bench[group]:
+            assert set(e) == keys, e
+            assert NAME.match(e["name"]), e["name"]
+            assert _line(e["why"]), e["why"]
+        names.append([e["name"] for e in bench[group]])
+    for c in bench["configs"]:
+        assert _line(c["source"]) and c["source"].startswith("https://")
+        assert all(NAME.match(k) for k in c["reduced"])
+        assert len(c["reduced"]) <= 16
+    for w in bench["workloads"]:
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4)
+    metric_names = []
+    for group, keys in (
+            ("end_to_end", {"name", "unit", "better", "bound", "source"}),
+            ("per_layer", {"name", "unit", "better", "source", "layer",
+                           "moves"})):
+        for m in bench[group]:
+            assert set(m) - {"workloads"} == keys, m
+            assert NAME.match(m["name"]), m["name"]
+            assert UNIT.match(m["unit"]), m["unit"]
+            assert m["better"] in ("lower", "higher")
+            metric_names.append(m["name"])
+    names.append(metric_names)
+    for group in names:
+        assert len(group) == len(set(group)), group
+
+
+def check_sources_bounds_and_moves(bench):
+    cells = [w["name"] for w in bench["workloads"]]
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    for m in e2e.values():
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+        assert set(m.get("workloads", cells)) <= set(cells)
+
+    def reported(metric, cell):
+        return cell in metric.get("workloads", cells)
+
+    for m in bench["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+        assert _line(m["layer"])
+        assert m["moves"] in e2e and m["moves"] != "setup_s"
+        for cell in m.get("workloads", cells):
+            assert cell in cells
+            assert reported(e2e[m["moves"]], cell), (m["name"], cell)
+    for cell in cells:
+        assert any(reported(m, cell) for m in e2e.values()
+                   if m["name"] != "setup_s"), cell
+        assert any(reported(m, cell) for m in bench["per_layer"]), cell
+
+
+def check_cells_and_metrics_are_files(bench, roots):
+    """``roots``: where files are looked for, as ``run.py`` looks: a tree
+    of the caller's own first, then the repo's ``benchmark/``."""
+    def found(kind, name):
+        hits = [p for p in (os.path.join(r, kind, name) for r in roots)
+                if os.path.exists(p)]
+        assert hits, f"no {kind}/{name} under {roots}"
+        return hits[0]
+
+    used = set()
+    for w in bench["workloads"]:
+        mix = traffic.load(w["traffic"], roots)
+        found("runners", mix["kind"] + ".py")
+        used.add(w["config"])
+    assert used == {c["name"] for c in bench["configs"]}
+    assert len({c["file"] for c in bench["configs"]}) == len(
+        bench["configs"])
+    for m in bench["per_layer"]:
+        src = open(found("layer_metrics", m["name"] + ".py")).read()
+        assert f'LAYER = "{m["layer"]}"' in src, m["name"]
+        assert f'MOVES = "{m["moves"]}"' in src, m["name"]
+        assert f'UNIT = "{m["unit"]}"' in src, m["name"]
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(bench["workloads"]) // 4)
+
+
+# ``per_layer`` as PR 32 left it. The one rule about order: APPEND, never
+# insert. An entry that is there keeps its index (the driver reads an
+# entry put in the middle as a change to the one whose place it takes and
+# refuses the PR); what a later PR appends after these is free, and no
+# test knows which entries are last. Only a ``benchmark`` PR that retires
+# a metric edits this list.
+PER_LAYER_AT_PR32 = [
+    "mixed_wall_p50_ms.sat", "decode_wall_p50_ms.sat", "live_slots_mean.sat",
+    "mixed_dispatches_per_req.sat", "step_ms.train", "mfu_pct.train",
+    "peak_hbm_gb.train", "collective_exposed_pct.train4",
+    "device_idle_pct.sat", "device_idle_pct.train", "compiles_in_window.sat",
+    "compiles_in_window.train", "frontline_host_ms.sat", "step_host_ms.sat",
+    "decode_iter_wall_ms.sat", "mixed_real_lane_pct.sat",
+    "paged_attn_device_pct.sat", "moe_ffn_device_pct.sat",
+    "moe_grouped_roofline_pct.sat", "latent_attn_roofline_pct.sat",
+    "moe_load_max_over_mean.sat", "mfu_pct.sat"]
+
+
+def check_what_exists_keeps_its_place(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[:len(PER_LAYER_AT_PR32)] == PER_LAYER_AT_PR32
+
+
+def check_structure(bench, roots):
+    """Every structural assertion ``tests/benchmark/*.py`` make of the
+    repo's ``BENCHMARK.json``."""
+    import test_benchmark_moe as moe        # beside this file, as pytest
+    import test_benchmark_spans as spans    # imports them
+    check_top_level_keys(bench)
+    check_names_and_units(bench)
+    check_sources_bounds_and_moves(bench)
+    check_cells_and_metrics_are_files(bench, roots)
+    check_what_exists_keeps_its_place(bench)
+    spans.check_the_span_readers_entries(bench)
+    moe.check_which_readers_list_the_cell(bench)
+
+
 class TestBenchmarkJson:
     def test_top_level_keys_are_exactly_the_contracts(self, bench):
-        assert sorted(bench) == sorted([
-            "command", "paths", "run_seconds", "configs", "workloads",
-            "end_to_end", "per_layer"])
-        assert 1 <= bench["run_seconds"] <= 51
-        assert isinstance(bench["run_seconds"], int)
-        assert len(json.dumps(bench)) < 64 * 1024
-        assert all(_line(w) for w in bench["command"])
-        assert all(PATH.match(p) and not p.startswith("/") and ".." not in p
-                   for p in bench["paths"])
+        check_top_level_keys(bench)
 
     def test_names_and_units_are_in_the_drivers_alphabet(self, bench):
-        names = []
-        for group, keys in (
-                ("configs", {"name", "source", "file", "reduced", "why"}),
-                ("workloads", {"name", "config", "traffic", "chips", "why"})):
-            for e in bench[group]:
-                assert set(e) == keys, e
-                assert NAME.match(e["name"]), e["name"]
-                assert _line(e["why"]), e["why"]
-            names.append([e["name"] for e in bench[group]])
-        for c in bench["configs"]:
-            assert _line(c["source"]) and c["source"].startswith("https://")
-            assert all(NAME.match(k) for k in c["reduced"])
-            assert len(c["reduced"]) <= 16
-        for w in bench["workloads"]:
-            assert NAME.match(w["config"]) and NAME.match(w["traffic"])
-            assert w["chips"] in (1, 4)
-        metric_names = []
-        for group, keys in (
-                ("end_to_end", {"name", "unit", "better", "bound", "source"}),
-                ("per_layer", {"name", "unit", "better", "source", "layer",
-                               "moves"})):
-            for m in bench[group]:
-                assert set(m) - {"workloads"} == keys, m
-                assert NAME.match(m["name"]), m["name"]
-                assert UNIT.match(m["unit"]), m["unit"]
-                assert m["better"] in ("lower", "higher")
-                metric_names.append(m["name"])
-        names.append(metric_names)
-        for group in names:
-            assert len(group) == len(set(group)), group
+        check_names_and_units(bench)
 
     def test_sources_bounds_and_moves(self, bench):
-        cells = [w["name"] for w in bench["workloads"]]
-        e2e = {m["name"]: m for m in bench["end_to_end"]}
-        assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
-        for m in e2e.values():
-            assert m["source"] in ("host_clock", "device_trace")
-            assert 0.01 <= m["bound"] <= 0.1
-            assert set(m.get("workloads", cells)) <= set(cells)
-
-        def reported(metric, cell):
-            return cell in metric.get("workloads", cells)
-
-        for m in bench["per_layer"]:
-            assert m["source"] in ("device_trace", "program_span",
-                                   "program_counter", "host_clock")
-            assert _line(m["layer"])
-            assert m["moves"] in e2e and m["moves"] != "setup_s"
-            for cell in m.get("workloads", cells):
-                assert cell in cells
-                assert reported(e2e[m["moves"]], cell), (m["name"], cell)
-        for cell in cells:
-            assert any(reported(m, cell) for m in e2e.values()
-                       if m["name"] != "setup_s"), cell
-            assert any(reported(m, cell) for m in bench["per_layer"]), cell
+        check_sources_bounds_and_moves(bench)
 
     def test_cells_and_metrics_are_files_found_by_name(self, bench):
-        root = os.path.join(REPO, bench["paths"][0])
-        used = set()
-        for w in bench["workloads"]:
-            mix = traffic.load(w["traffic"])
-            assert os.path.exists(os.path.join(root, "runners",
-                                               mix["kind"] + ".py"))
-            used.add(w["config"])
-        assert used == {c["name"] for c in bench["configs"]}
-        assert len({c["file"] for c in bench["configs"]}) == len(
-            bench["configs"])
-        for m in bench["per_layer"]:
-            path = os.path.join(root, "layer_metrics", m["name"] + ".py")
-            src = open(path).read()
-            assert f'LAYER = "{m["layer"]}"' in src, m["name"]
-            assert f'MOVES = "{m["moves"]}"' in src, m["name"]
-            assert f'UNIT = "{m["unit"]}"' in src, m["name"]
-        four = [w for w in bench["workloads"] if w["chips"] == 4]
-        assert len(four) <= max(1, len(bench["workloads"]) // 4)
+        check_cells_and_metrics_are_files(
+            bench, [os.path.join(REPO, bench["paths"][0])])
+
+    def test_what_exists_keeps_its_place(self, bench):
+        check_what_exists_keeps_its_place(bench)
+
+
+# ---------------------------------------------------------------------------
+# the next ``model_config`` PR, done to a copy of the repo's file in memory
+# ---------------------------------------------------------------------------
+
+FAMILYS_OWN = ("moe_", "latent_")      # readers of one family's kernels
+NEW_ENTRY = {"name": "window_attn_roofline_pct.sat", "unit": "%",
+             "better": "higher", "source": "device_trace",
+             "layer": "kernels", "moves": "out_tokens_per_s",
+             "workloads": ["next-serve-sat"]}
+
+
+@pytest.fixture
+def next_configuration(bench, tmp_path):
+    """What that PR does to ``BENCHMARK.json``: a configuration, a cell on
+    it, the cell's name appended to ``out_tokens_per_s`` and to every
+    ``.sat`` reader that is not one family's own; and, in a tree of its
+    own (nothing is written into the repo's), the file of ONE new reader,
+    whose entry the test puts where it wants it."""
+    bench = json.loads(json.dumps(bench))
+    cell = NEW_ENTRY["workloads"][0]
+    bench["configs"].append({
+        "name": "next-model-d4", "source": "https://huggingface.co/org/next",
+        "file": "benchmark/configs/next-model-d4.json",
+        "reduced": ["num_hidden_layers"],
+        "why": "window and full layers mixed, 256 routed experts"})
+    bench["workloads"].append({
+        "name": cell, "config": "next-model-d4", "traffic": "reason-sat",
+        "chips": 1, "why": "closed loop; the window-bounded paged kernel"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] == "out_tokens_per_s" or (
+                m["name"].endswith(".sat")
+                and not m["name"].startswith(FAMILYS_OWN)):
+            m["workloads"].append(cell)
+    readers = tmp_path / "layer_metrics"
+    readers.mkdir()
+    (readers / (NEW_ENTRY["name"] + ".py")).write_text(
+        'LAYER = "kernels"\nMOVES = "out_tokens_per_s"\nUNIT = "%"\n\n\n'
+        'def read(run):\n    return None\n')
+    return bench, [str(tmp_path), os.path.join(REPO, "benchmark")]
+
+
+def test_the_next_configuration_is_appended_and_every_check_passes(
+        next_configuration):
+    bench, roots = next_configuration
+    bench["per_layer"].append(dict(NEW_ENTRY))
+    check_structure(bench, roots)
+    # the cell reads what the families share and, last, its own
+    listed = [m["name"] for m in bench["per_layer"]
+              if "next-serve-sat" in m["workloads"]]
+    assert {"step_host_ms.sat", "paged_attn_device_pct.sat",
+            "mfu_pct.sat"} < set(listed)
+    assert listed[-1] == NEW_ENTRY["name"]
+    assert not [n for n in listed if n.startswith(FAMILYS_OWN)]
+
+
+@pytest.mark.parametrize("at", [0, 4, 12, 17, len(PER_LAYER_AT_PR32) - 1])
+def test_an_entry_put_in_the_middle_is_told_apart(next_configuration, at):
+    """The same entry, inserted: everything else still holds (so nothing
+    but its place is wrong), and the order's check says so. "Append, never
+    insert" is what the tests hold of the order, and all they hold."""
+    bench, roots = next_configuration
+    bench["per_layer"].insert(at, dict(NEW_ENTRY))
+    check_top_level_keys(bench)
+    check_names_and_units(bench)
+    check_sources_bounds_and_moves(bench)
+    check_cells_and_metrics_are_files(bench, roots)
+    with pytest.raises(AssertionError):
+        check_what_exists_keeps_its_place(bench)
 
 
 class TestConfigurations:

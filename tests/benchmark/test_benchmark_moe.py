@@ -101,44 +101,42 @@ def test_a_picked_local_expert_left_out_is_not_correct(capsys, cache_dir,
     assert faults == {"name": "health_faults", "value": 0.0, "limit": 0.0}
 
 
-# readers whose entries ``test_benchmark_spans.py`` (a file the benchmark
-# already has) pins to the one cell they had at PR 24, and to the TAIL of
-# ``per_layer``. A program PR may only append to that list, so until a
-# benchmark PR frees the tail the real cell cannot be appended to these
-# five lists, and the family's five readers have NO entry in the real
-# ``BENCHMARK.json``: an entry before the tail reads as a change to the
-# entry whose place it takes, and one after it fails that test. Their
-# files and this tree's entries are what that PR appends (PERF.md, Open
-# questions)
-PINNED = ["frontline_host_ms.sat", "step_host_ms.sat",
-          "decode_iter_wall_ms.sat", "mixed_real_lane_pct.sat",
-          "paged_attn_device_pct.sat"]
+# Since PR 32 the real cell reports every reader this tree's toy cell
+# does: the nine it shares with the dense family (``APPENDED``: the cell's
+# name appended to their ``workloads``) and the family's own five (``NEW``:
+# entries appended at the end of ``per_layer``). No test pins the end of
+# ``per_layer`` or a reader's ``workloads`` to one cell: the next
+# configuration appends its cell's name to the lists of the readers that
+# read in it and its own readers' entries after whatever is last then
+# (``benchmark/__init__.py``; ``test_benchmark_contract.py`` rehearses it).
+
+def _listed(bench, cell):
+    return [m for m in bench["per_layer"] if cell in m.get("workloads", ())]
 
 
-def test_which_readers_list_the_cell():
-    def listed(tree, cell):
-        with open(os.path.join(tree, "BENCHMARK.json")) as f:
-            bench = json.load(f)
-        return [m for m in bench["per_layer"]
-                if cell in m.get("workloads", ())], bench
-
-    ours, _ = listed(REHEARSAL, "tiny-moe.sat")
+def check_which_readers_list_the_cell(bench):
+    """``bench``: the repo's ``BENCHMARK.json``, or a dictionary a test
+    made of it."""
+    with open(os.path.join(REHEARSAL, "BENCHMARK.json")) as f:
+        ours = _listed(json.load(f), "tiny-moe.sat")
     assert {m["name"] for m in ours} == set(NEW + APPENDED)
-    real, bench = listed(REPO, "pangu718b-serve-reason-sat")
-    assert {m["name"] for m in real} == set(APPENDED) - set(PINNED)
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-5:] == PINNED and not set(NEW) & set(names)
+    real = _listed(bench, "pangu718b-serve-reason-sat")
+    assert {m["name"] for m in real} == set(NEW + APPENDED)
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for m in ours:      # the rehearsal's entries are the real ones' twins
-        if m["name"] in by_name:
-            assert {k: m[k] for k in m if k != "workloads"} == {
-                k: by_name[m["name"]][k] for k in m if k != "workloads"}
+        assert {k: m[k] for k in m if k != "workloads"} == {
+            k: by_name[m["name"]][k] for k in m if k != "workloads"}
         path = os.path.join(REPO, "benchmark", "layer_metrics",
                             m["name"] + ".py")
         src = open(path).read()
         assert f'LAYER = "{m["layer"]}"' in src
         assert f'MOVES = "{m["moves"]}"' in src
         assert f'UNIT = "{m["unit"]}"' in src
+
+
+def test_which_readers_list_the_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        check_which_readers_list_the_cell(json.load(f))
 
 
 def test_the_toys_cut_keeps_the_guides_floors():
