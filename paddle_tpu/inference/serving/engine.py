@@ -515,6 +515,11 @@ class ServingEngine:
         self._family = paged_family(model_config)
         self._family.validate_serving(model_config, self.config)
         self._counter_names = tuple(self._family.PAGED_COUNTERS)
+        # the query lanes a mixed step is kept to, where the family names
+        # a number (the lanes of one wave of its packed step)
+        budget = getattr(self._family, "mixed_lane_budget", None)
+        self._lane_budget = None if budget is None else \
+            budget(model_config, self.config.max_slots)
         # the served model's widths and counts under its family's names
         # (what turns the family's counters into bytes and shares); None
         # for a family that describes nothing
@@ -942,6 +947,15 @@ class ServingEngine:
         self._stats["chunks"] += 1
         self._stats[kind + "_dispatches"] += 1
         self._dispatch_ms[kind].append((t1 - t0) * 1e3)
+        # what the pool holds, once a dispatch (over the dispatches of a
+        # window: the mean share of the pool in use), and of it what the
+        # bounded groups of a grouped cache hold
+        self.spans.count("kv_blocks_in_use_sum",
+                         self.cache.manager.blocks_in_use)
+        if not self.cache.uniform:
+            self.spans.count("kv_window_blocks_in_use_sum", sum(
+                self.cache.window_blocks(len(r.blocks))
+                for r in self._sched.live if r.blocks))
 
     def _count_dispatch(self, aux=None) -> Dict[str, int]:
         """Add one dispatch's device counters (the small array a counting
@@ -1350,8 +1364,8 @@ class ServingEngine:
                 "adapter_id": req.adapter_id,
                 "kv": None,
             }
-            if req.slot is None or not req.blocks:
-                return payload
+            if req.slot is None or not req.blocks or not self.cache.uniform:
+                return payload       # grouped cache: recompute on arrival
             if req.prefilling:
                 entries = int(req.num_computed)
             else:
@@ -1907,7 +1921,7 @@ class ServingEngine:
         still can't get a block the pool is truly exhausted relative to
         its budget: it is retired early with ``oom_truncated`` set rather
         than hung."""
-        bf = self.cache.manager.blocks_for
+        bf = self.cache.blocks_for
 
         while True:
             decoding = self._sched.decoding
@@ -2034,7 +2048,7 @@ class ServingEngine:
         preemption; the preempt/truncate ladder is the shared
         :meth:`_relieve_pressure`. Returns the decoding set (possibly
         shrunk by preemption; empty = nothing to do)."""
-        bf = self.cache.manager.blocks_for
+        bf = self.cache.blocks_for
 
         while True:
             decoding = self._sched.decoding
@@ -2077,7 +2091,7 @@ class ServingEngine:
         entries INSIDE the kept tail block are overwritten by the next
         dispatch's write at ``seq_len`` or hidden by the ``j <= seq_len``
         mask."""
-        keep = self.cache.manager.blocks_for(int(self._seq_lens[req.slot]))
+        keep = self.cache.blocks_for(int(self._seq_lens[req.slot]))
         tail = req.blocks[keep:]
         if not tail:
             return
@@ -2385,6 +2399,10 @@ class ServingEngine:
             kd = self._ensure_blocks(1) if decoding else 0
             prefills = [r for r in self._sched.live if r.prefilling]
             if prefills:
+                if self._lane_budget is not None:
+                    prefills = self._within_lane_budget(
+                        prefills,
+                        len(self._sched.decoding) if kd >= 1 else 0)
                 return "mixed", (prefills, kd >= 1)
             decoding = self._sched.decoding
         k = 0
@@ -2401,6 +2419,27 @@ class ServingEngine:
         if decoding and k >= 1:
             return "decode", (decoding, k)
         return None, ()
+
+    def _within_lane_budget(self, prefills: List[Request],
+                            decode_rows: int) -> List[Request]:
+        """The prompts whose next chunk rides this mixed step where the
+        family keeps a step to a number of query lanes: oldest request
+        first (a slot's index says nothing of its age, and a closed loop
+        refills the low slots for ever), as many as fit beside the decode
+        rows, and at least one. The others keep their slot and their
+        blocks and wait a step."""
+        chunk = self.config.prefill_chunk
+        left = self._lane_budget - decode_rows
+        kept: List[Request] = []
+        for req in sorted(prefills, key=lambda r: r.rid):
+            n = len(req.prefill_ids) - req.num_computed
+            if chunk is not None:
+                n = min(n, chunk)
+            if kept and n > left:
+                break
+            kept.append(req)
+            left -= n
+        return kept
 
     # ---- the scheduler iteration ------------------------------------------
 

@@ -315,6 +315,21 @@ class PagedKVCache:
     with every decode dispatch (W = ceil(max_model_len / block_size));
     unassigned entries point at the null block 0 and are masked by the
     sequence-length mask on device.
+
+    **Cache groups.** A family whose layers do not all see the same
+    positions declares its groups (``paged_cache_groups(cfg, block_size)``
+    of its module, one entry a group: ``None`` for a group that holds
+    every position, the entries of its RING for a group bounded by a
+    window). Each group has its own stretch of a table row, side by side
+    (``W`` is their sum), and every group draws from the ONE
+    :class:`BlockManager`: a sequence of ``p`` pages holds ``p`` blocks in
+    an unbounded group and ``min(p, ring)`` in a bounded one, whose page
+    ``p`` lives at entry ``p % ring`` (so its blocks are reused in place
+    and it never grows past its ring). :meth:`blocks_for` is what a
+    sequence costs over all its groups. A request's ``blocks`` stay one
+    flat list, in the order they were taken: page by page, and within a
+    page group by group. A family that declares nothing has one unbounded
+    group, and every number here is what it was.
     """
 
     def __init__(self, model_config, max_slots: int, max_model_len: int,
@@ -324,7 +339,6 @@ class PagedKVCache:
                  mesh=None, offload: bool = False,
                  offload_blocks: int = 0):
         from ...models import paged_family
-        init_paged_pool = paged_family(model_config).init_paged_pool
         self.block_size = int(block_size)
         self.max_model_len = int(max_model_len)
         self.prefix_cache = bool(prefix_cache)
@@ -336,7 +350,18 @@ class PagedKVCache:
         # global, tables replicate, only pool bytes split across devices
         self.mesh = mesh
         self.tp = int(mesh.shape["tp"]) if mesh is not None else 1
-        self.blocks_per_seq = max(1, math.ceil(max_model_len / block_size))
+        family = paged_family(model_config)
+        groups = getattr(family, "paged_cache_groups", None)
+        # one entry a group: None (every position) or its ring's entries
+        self.rings: Tuple[Optional[int], ...] = (
+            tuple(groups(model_config, self.block_size)) if groups
+            else (None,))
+        self.uniform = self.rings == (None,)
+        pages = max(1, math.ceil(max_model_len / block_size))
+        widths = [pages if r is None else int(r) for r in self.rings]
+        # the first table column of each group; the row's width
+        self.group_start = [sum(widths[:g]) for g in range(len(widths))]
+        self.blocks_per_seq = sum(widths)
         if num_blocks <= 0:
             # auto-size: every slot can hold a full-length sequence, +1 null
             num_blocks = max_slots * self.blocks_per_seq + 1
@@ -345,9 +370,9 @@ class PagedKVCache:
         # here (block manager, tables, prefix-cache keys over TOKEN IDS)
         # is layout-agnostic, so int8 blocks hash/hit/evict exactly like
         # fp blocks; only the device pool layout changes
-        self.pool: Dict = init_paged_pool(model_config, num_blocks,
-                                          block_size, dtype,
-                                          kv_quant=kv_quant, mesh=mesh)
+        self.pool: Dict = family.init_paged_pool(model_config, num_blocks,
+                                                 block_size, dtype,
+                                                 kv_quant=kv_quant, mesh=mesh)
         self.manager = BlockManager(num_blocks, block_size,
                                     tenant_quota=tenant_quota)
         self.tables = np.zeros((max_slots, self.blocks_per_seq), np.int32)
@@ -363,6 +388,45 @@ class PagedKVCache:
     @property
     def free_blocks(self) -> int:
         return self.manager.free_blocks
+
+    # ---- what a sequence costs, over all groups ----------------------------
+
+    def _cost(self, pages: int) -> int:
+        """Blocks a sequence of ``pages`` pages holds over its groups."""
+        return sum(pages if r is None else min(pages, r)
+                   for r in self.rings)
+
+    def blocks_for(self, kv_tokens: int) -> int:
+        """Physical blocks a sequence of ``kv_tokens`` KV entries holds
+        over ALL its groups."""
+        return self._cost(self.manager.blocks_for(kv_tokens))
+
+    def _pages_held(self, n_blocks: int) -> int:
+        """Pages of a sequence that holds ``n_blocks``: the least ``p``
+        with ``_cost(p) >= n_blocks`` (the cost grows with the pages, so
+        a bisection; ``n_blocks`` itself where one group holds them all)."""
+        if self.uniform:
+            return n_blocks
+        lo, hi = 0, n_blocks
+        while lo < hi:
+            mid = (lo + hi) // 2
+            if self._cost(mid) < n_blocks:
+                lo = mid + 1
+            else:
+                hi = mid
+        return lo
+
+    def window_blocks(self, n_blocks: int) -> int:
+        """Of a sequence's ``n_blocks``, those its bounded groups hold."""
+        pages = self._pages_held(n_blocks)
+        return sum(min(pages, r) for r in self.rings if r is not None)
+
+    def _columns(self, page_from: int, page_to: int) -> List[int]:
+        """Table columns of the blocks taken for pages ``[page_from,
+        page_to)``, in the flat list's order."""
+        return [self.group_start[g] + p
+                for p in range(page_from, page_to)
+                for g, r in enumerate(self.rings) if r is None or p < r]
 
     # ---- device block I/O --------------------------------------------------
 
@@ -432,7 +496,7 @@ class PagedKVCache:
         running work).
         """
         n_tokens = int(reserve_kv) if reserve_kv is not None else len(ids)
-        n_total = self.manager.blocks_for(n_tokens)
+        n_total = self.blocks_for(n_tokens)
         if n_total > self.blocks_per_seq:
             raise ValueError(
                 f"sequence needs {n_total} blocks ({n_tokens} KV entries) "
@@ -483,13 +547,15 @@ class PagedKVCache:
         entries — the on-demand decode path. Returns the newly allocated
         blocks ([] when already covered), or None when the pool is dry
         (the engine then preempts)."""
-        n = self.manager.blocks_for(kv_tokens) - len(blocks)
+        n = self.blocks_for(kv_tokens) - len(blocks)
         if n <= 0:
             return []
         if not self.manager.can_alloc(n):
             return None
         new = self.manager.alloc(n)
-        self.tables[slot, len(blocks):len(blocks) + n] = new
+        self.tables[slot, self._columns(
+            self._pages_held(len(blocks)),
+            self.manager.blocks_for(kv_tokens))] = new
         blocks.extend(new)
         return new
 
@@ -521,7 +587,8 @@ class PagedKVCache:
 
     def assign(self, slot: int, blocks: List[int]) -> None:
         self.tables[slot] = 0
-        self.tables[slot, :len(blocks)] = blocks
+        self.tables[slot, self._columns(
+            0, self._pages_held(len(blocks)))] = blocks
 
     def release(self, slot: int, blocks: List[int]) -> None:
         self.manager.free(blocks)

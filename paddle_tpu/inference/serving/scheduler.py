@@ -445,11 +445,11 @@ class Scheduler:
                 # worst case is a budget, not a charge — EOS usually lands
                 # first, and a genuinely over-budget sole survivor is
                 # truncated, not hung)
-                n = self.cache.manager.blocks_for(req.prompt_len)
+                n = self.cache.blocks_for(req.prompt_len)
                 what = f"prompt ({req.prompt_len} tokens)"
             else:
                 # reservation mode admits only full worst-case footprints
-                n = self.cache.manager.blocks_for(req.kv_tokens)
+                n = self.cache.blocks_for(req.kv_tokens)
                 what = f"worst case ({req.kv_tokens} KV entries)"
             if n > usable:
                 raise ValueError(

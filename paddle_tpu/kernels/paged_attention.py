@@ -82,6 +82,18 @@ the benchmark's serving cell's. The engine counts the rows that take the
 short tile (``attn_rows_short`` of ``attn_rows``;
 ``health_snapshot()["short_row_pct"]``, docs/OPS.md).
 
+**The window-bounded form** (``window=``; ``paged_attention_window_q1`` /
+``_mq`` to a profile): a layer whose query at position ``i`` attends only
+``j > i - window``. The table row is then a RING of ``R`` entries, page
+``p`` of the slot's positions at entry ``p % R`` (the engine keeps ``R``
+blocks a sequence for such a layer, whatever its length), and the loop
+over cells starts at the page of the first query's lowest position and
+ends at the last query's: a decode slot copies ``window / bs + 1`` pages
+and never the context before them. The window's lower edge inside the
+first cell and the causal edge inside the last are masks; values behind
+the first query's window are zeroed like those past the last position.
+One kernel body: the bound is a static argument.
+
 **The latent form** (:func:`paged_attention_latent`, a model whose cache
 holds one compressed vector a token a layer, multi-head latent attention
 in its absorbed form): ONE pool ``[L, N, bs, D]`` whose page is key and
@@ -123,6 +135,9 @@ _LANES = 128
 _KV_TILE = 128        # KV positions a cell attends, about
 _MAX_PAGES = 16       # pages a cell holds at most (its copies are unrolled)
 _ROW_TILE = 128       # query rows a sub-tile of a chunk row holds, at most
+# what a profile calls the kernel: [window-bounded?][multi-query?]
+_NAMES = (("paged_attention_q1", "paged_attention_mq"),
+          ("paged_attention_window_q1", "paged_attention_window_mq"))
 
 
 def _sublanes(dtype) -> int:
@@ -183,7 +198,7 @@ def _weighted_values(p, v):
 
 
 def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
-            kv_dtype):
+            kv_dtype, window=None):
     """One grid step = one slot ``m``; inside it a loop over the slot's
     LIVE cells of ``P`` KV pages. The pools stay in HBM: the kernel copies
     a cell's live pages into a two-slot VMEM buffer itself, the next
@@ -205,7 +220,15 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     ``q <= dl`` — a ``dl == 0`` slot the ``R0``-row tile (exactly the
     decode step's work), a longer one as many ``TQ``-row sub-tiles as
     ``(dl + 1) * G`` rows fill. Query offset ``i`` attends ``j <= sl +
-    min(i, dl)``; output rows past ``dl`` are written as zeros."""
+    min(i, dl)``; output rows past ``dl`` are written as zeros.
+
+    ``window``: the WINDOW-BOUNDED form. Query offset ``i`` attends only
+    ``j > sl + i - window`` as well, and the table row is a RING of ``W``
+    entries: page ``p`` of the slot's positions lives at entry ``p % W``.
+    The loop over cells starts at the page that holds the first query's
+    lowest position, ``max(sl - window + 1, 0)``, and ends at the last
+    query's page: nothing before it is copied or computed, and the two
+    edges inside the first and last cells are masks."""
     multi = Q > 1
     tbl_ref, sl_ref = refs[:2]
     dl_ref = refs[2] if multi else None
@@ -223,7 +246,13 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     dl = dl_ref[m] if multi else 0
     # the slot's attendable window is j <= sl + dl: pages and cells past
     # it are never copied and never computed
-    pages = jnp.minimum((sl + dl) // bs + 1, W)
+    if window is None:
+        lo, first = 0, 0
+        pages = jnp.minimum((sl + dl) // bs + 1, W)
+    else:                              # pages [first, first + pages) only
+        lo = jnp.maximum(sl - (window - 1), 0)
+        first = lo // bs
+        pages = jnp.minimum((sl + dl) // bs + 1 - first, W)
     cells = (pages + (P - 1)) // P
 
     def unrolled(n, body):
@@ -240,7 +269,10 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
         def page(i):
             @pl.when(c * P + i < pages)
             def _live():
-                blk = tbl_ref[m, c * P + i]
+                col = c * P + i
+                if window is not None:
+                    col = jax.lax.rem(first + col, W)
+                blk = tbl_ref[m, col]
                 at = (layer, blk) if layered else (blk,)
                 for pool, buf, sem in zip(hbm, (kbuf, vbuf), (ksem, vsem)):
                     go(pltpu.make_async_copy(
@@ -273,7 +305,7 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     def attend(c, slot):
         """Fold cell ``c``'s ``C`` KV positions, resident in buffer slot
         ``slot``, into the accumulators of a tile of query rows."""
-        base = c * C
+        base = c * C if window is None else first * bs + c * C
 
         def tile(r0, nr):
             rows = pl.ds(r0, nr)
@@ -284,9 +316,12 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
             jcol = base + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
             jrow = base + jax.lax.broadcasted_iota(jnp.int32, (nr, C), 1)
             if multi:                  # per-query-row causal draft window
-                valid = jrow <= sl + jnp.minimum(row_pos(r0, nr, C), dl)
+                edge = sl + jnp.minimum(row_pos(r0, nr, C), dl)
             else:
-                valid = jrow <= sl
+                edge = sl
+            valid = jrow <= edge
+            if window is not None:
+                valid &= jrow > edge - window
             # containment: V at never-attendable positions must be ZEROED,
             # not merely zero-weighted — a poisoned request can park NaN
             # there (see llama._masked_sdpa); exact 0.0 weights make this
@@ -296,6 +331,8 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
             # stale block tail. An int8 value is finite; there it is the
             # V SCALE that can hold the NaN, and takes the zero.
             keep = (jrow[:1] if quant else jcol) <= sl + dl
+            if window is not None:     # and nothing behind the window
+                keep &= jcol >= lo
 
             def head(h):
                 q = q_ref[0, h, rows, :].astype(kv_dtype)    # [nr, D]
@@ -397,7 +434,7 @@ def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
                     draft_lens=None, k_scale=None, v_scale=None,
                     scale: Optional[float] = None, out_dtype=None,
-                    layer=None):
+                    layer=None, window: Optional[int] = None):
     """Decode attention for ``M`` serving slots straight off the block pool.
 
     ``q [M, H, D]`` — one query token per slot (the decode entry point) —
@@ -423,6 +460,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     ``[M, H, D]`` (or ``[M, Q, H, D]``) in ``out_dtype`` (default: the
     pool dtype for fp pools, fp32 for int8 pools — matching the gather
     path's ``_masked_sdpa`` output dtype).
+
+    ``window`` (a static int): the window-bounded form, under its own
+    names (``paged_attention_window_q1`` / ``_mq``). Query offset ``i``
+    attends ``seq_lens[m] + i - window < j <= seq_lens[m] + i`` and
+    ``block_tables [M, R]`` is a RING: position ``j`` lives in block
+    ``block_tables[m, (j // bs) % R]``. The caller sizes ``R`` so that the
+    pages from the first query's lowest position to the last query's fit
+    (``(window + Q) // bs + 2`` entries always do); the kernel copies
+    those pages and no other. fp pools only.
     """
     multi = q.ndim == 4
     if multi:
@@ -453,6 +499,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     if quant != (v_scale is not None):
         raise ValueError("paged_attention: k_scale and v_scale must be "
                          "given together")
+    if window is not None and quant:
+        raise ValueError("paged_attention: the window-bounded form reads "
+                         "fp pools only")
     if out_dtype is None:
         out_dtype = jnp.float32 if quant else k_pool.dtype
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
@@ -540,7 +589,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, W=W, P=P, scale=scale,
                           quant=quant, layered=in_kernel, Hk=Hk, G=G, Q=Q,
-                          R0=R0, TQ=TQ, kv_dtype=kv_dtype),
+                          R0=R0, TQ=TQ, kv_dtype=kv_dtype,
+                          window=None if window is None else int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, Hk, QG, D), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -552,7 +602,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
         # the trace and the compiled text name the custom call after this:
         # the decode form and the multi-query (mixed / verify) form are
         # two kernels to a profile, so they carry two names
-        name="paged_attention_mq" if multi else "paged_attention_q1",
+        # (and the window-bounded form two more)
+        name=_NAMES[window is not None][multi],
     )(*scalars, *ops)
     if multi:
         return out.reshape(M, Hk, Q, G, D).transpose(0, 2, 1, 3, 4) \

@@ -24,7 +24,12 @@ def paged_family(model_config):
     a family that counts nothing), ``validate_serving(cfg, serving_config)``
     (raises for what the family does not serve), ``describe(cfg)`` (the
     widths ``stats()["model"]`` shows, or None) and ``health(counters,
-    cfg)`` (``health_snapshot()["family"]``, or None). A config names its
+    cfg)`` (``health_snapshot()["family"]``, or None). Two more where a
+    family has them: ``paged_cache_groups(cfg, block_size)`` (layers that
+    share a block table, and the window groups' rings) and
+    ``mixed_lane_budget(cfg, max_slots)`` (the query lanes one mixed step
+    is kept to; without it a step carries every prompt in prefill). A
+    config names its
     module in a ``paged_family`` attribute; one that has none
     (``LlamaConfig``) is served by ``models.generation``. This is how
     ``inference.serving`` reaches a family: through the config object it

@@ -392,6 +392,118 @@ def test_latent_and_grouped_kernels_compile_for_v5e_without_a_chip(
             jax.jit(fn).lower(*args).compile()
 
 
+def _window_cell_cases():
+    """What the family with window and full attention layers brought, at
+    the shapes its serving cell runs, read from the cell's own
+    configuration: the window-bounded paged kernel on a group's ring
+    (query groups of 9 a kv head) and the paged kernel the dense family
+    calls at this family's full layers (groups of 6, a table of 1024
+    pages), each at a decode step and at a chunk row; and both grouped
+    matmuls of the held experts (3072 x 1024) at the (token, pick) pairs
+    of a decode step and of one wave of a packed mixed step."""
+    from paddle_tpu.kernels import grouped_matmul, paged_attention
+    from paddle_tpu.models.laguna import _WAVE_ROWS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "laguna-s-2.1-ep8-d9.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    heads = dict(zip(c["layer_types"], c["num_attention_heads_per_layer"]))
+    Hk, D = c["num_key_value_heads"], c["head_dim"]
+    M, bs, chunk = eng["max_slots"], eng["block_size"], eng["prefill_chunk"]
+    window = c["sliding_window"]
+    ring = -(-(window + chunk) // bs) + 1
+    group = c["layer_types"][:c["num_hidden_layers"]].count("full_attention")
+    E, I, held = c["hidden_size"], c["moe_intermediate_size"], \
+        c["num_experts"]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    cases = []
+    for kind, W, win in (("sliding_attention", ring, window),
+                         ("full_attention", eng["max_model_len"] // bs,
+                          None)):
+        for Q in (1, chunk):
+            name = (f"paged_attention{'_window' if win else ''}_"
+                    f"{'q1' if Q == 1 else 'mq'} {heads[kind]}/{Hk} heads")
+
+            def fn(q, k, v, tbl, sl, dl, Q=Q, win=win):
+                if Q == 1:
+                    return paged_attention(q[:, 0], k, v, tbl, sl,
+                                           layer=jnp.int32(1), window=win)
+                return paged_attention(q, k, v, tbl, sl, draft_lens=dl,
+                                       layer=jnp.int32(1), window=win)
+
+            cases.append((name, fn, lambda Q=Q, W=W, kind=kind: (
+                jnp.zeros((M, Q, heads[kind], D), bf),
+                jnp.zeros((group, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((group, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((M, W), i32), jnp.zeros((M,), i32),
+                jnp.zeros((M,), i32))))
+    for T in (M, _WAVE_ROWS * M):
+        rows = T * c["num_experts_per_tok"]
+        for K, N, gated in ((E, 2 * I, True), (I, E, False)):
+            cases.append((
+                f"moe_grouped_matmul {rows} rows {K}x{N}",
+                lambda x, w, g, gated=gated: grouped_matmul(
+                    x, w, g, layer=jnp.int32(2), gated=gated,
+                    use_kernel=True),
+                lambda rows=rows, K=K, N=N: (
+                    jnp.zeros((rows, K), bf),
+                    jnp.zeros((6, held, K, N), bf),
+                    jnp.zeros((held,), i32))))
+    return cases
+
+
+WINDOW_CELL_CASES = ["paged_attention_window_q1 72/8 heads",
+                     "paged_attention_window_mq 72/8 heads",
+                     "paged_attention_q1 48/8 heads",
+                     "paged_attention_mq 48/8 heads",
+                     "moe_grouped_matmul 320 rows 3072x2048",
+                     "moe_grouped_matmul 320 rows 1024x3072",
+                     "moe_grouped_matmul 10240 rows 3072x2048",
+                     "moe_grouped_matmul 10240 rows 1024x3072"]
+
+
+@pytest.mark.parametrize("case", WINDOW_CELL_CASES)
+def test_window_and_grouped_kernels_lower_for_tpu(case, monkeypatch):
+    """Both forms of the window-bounded paged kernel, the full layers'
+    calls and the grouped matmul at 3072 x 1024 through the Pallas TPU
+    lowering at the serving cell's shapes."""
+    from paddle_tpu.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cases = {name: (fn, build) for name, fn, build in _window_cell_cases()}
+    assert list(cases) == WINDOW_CELL_CASES
+    fn, build = cases[case]
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *jax.eval_shape(build))
+    text = exported.mlir_module()
+    assert "tpu_custom_call" in text, case
+    assert case.split()[0] in text        # the name a device trace shows
+
+
+@pytest.mark.slow
+def test_window_and_grouped_kernels_compile_for_v5e_without_a_chip(
+        monkeypatch):
+    """The same through libtpu's whole compiler for a described v5e (a
+    table of 1024 pages a slot in scalar memory, row offsets divided by a
+    group of 6 or 9)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.kernels import dispatch
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:                    # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no TPU topology without a chip: {e}")
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    where = SingleDeviceSharding(topo.devices[0])
+    with jax.default_matmul_precision("default"):
+        for name, fn, build in _window_cell_cases():
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=where),
+                jax.eval_shape(build))
+            jax.jit(fn).lower(*args).compile()
+
+
 def test_preset_is_the_738m_model():
     """The smoke's model is the one with a chip history: 738M parameters
     at LLaMA-7B's shape ratios, kernels and remat on."""

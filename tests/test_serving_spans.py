@@ -480,7 +480,7 @@ def test_train_step_and_its_kernels_carry_names_in_the_tpu_lowering(
 
 
 def test_every_pallas_call_has_a_name():
-    """Ten call sites, thirteen names (the paged kernel has two, by form,
+    """Ten call sites, fifteen names (the paged kernel has four, by form,
     and a latent form; the grouped matmul two, gated or plain)."""
     import os
     import re
@@ -489,9 +489,15 @@ def test_every_pallas_call_has_a_name():
     src = "".join(open(os.path.join(root, f)).read()
                   for f in sorted(os.listdir(root)) if f.endswith(".py"))
     assert len(re.findall(r"pl\.pallas_call\(", src)) == 10
-    names = re.findall(r'"(\w+)"', "".join(re.findall(r" name=(.*)", src)))
+    # a name is a literal on its ``name=`` line, or one of the paged
+    # kernel's four (by form) in the table that line indexes
+    names = re.findall(r'"(\w+)"', "".join(
+        re.findall(r" name=(.*)", src) +
+        re.findall(r"_NAMES = \(([^#]*?)\)\n\n", src, re.S)))
     assert sorted(names) == sorted([
-        "paged_attention_q1", "paged_attention_mq", "flash_attention_fwd",
+        "paged_attention_q1", "paged_attention_mq",
+        "paged_attention_window_q1", "paged_attention_window_mq",
+        "flash_attention_fwd",
         "flash_attention_bwd_dq", "flash_attention_bwd_dkv",
         "quant_matmul", "rms_norm_fwd", "rms_norm_bwd", "rope",
         "paged_attention_latent", "moe_grouped_matmul_gated",
