@@ -14,12 +14,14 @@ the real tokens** — pages a slot holds, query rows a slot carries.
 
 * **Grid ``(M,)``, and inside a slot a loop over its LIVE cells.** A cell
   is ``P`` KV pages, ``P`` chosen from the shapes so that ``P * bs`` is
-  about 128 KV positions (8 pages of 16; ``_tiling``). A slot attending
-  ``j <= sl + dl`` runs ``ceil(pages / P)`` cells, ``pages = (sl + dl) //
-  bs + 1``: no cell, no copy and no branch exists for the rest of the
-  ``W``-wide table (a grid over the table's width paid 0.16 us for every
-  page a slot did NOT hold, more than for the ones it did). ``W`` need
-  not divide by ``P``; the last cell's missing pages mask by position.
+  about 256 KV positions (16 pages of 16; ``_tiling``. 128 until PR 34:
+  what a (cell, head) pays whatever it holds — the accumulators read and
+  rescaled, the copies waited for — is paid half as often). A slot
+  attending ``j <= sl + dl`` runs ``ceil(pages / P)`` cells, ``pages = (sl
+  + dl) // bs + 1``: no cell, no copy and no branch exists for the rest of
+  the ``W``-wide table (a grid over the table's width paid 0.16 us for
+  every page a slot did NOT hold, more than for the ones it did). ``W``
+  need not divide by ``P``; the last cell's missing pages mask by position.
 * **Block tables consumed IN-KERNEL, pages copied by the kernel.** The
   ``[M, W]`` block table and ``[M]`` lengths ride in as scalar-prefetch
   operands; the K and V pools stay in HBM (``memory_space=ANY``) and the
@@ -32,12 +34,26 @@ the real tokens** — pages a slot holds, query rows a slot carries.
   source): a caller whose layer scan carries the whole pool hands it over
   as it is, and no layer's slab is ever sliced out (by the pool's rank
   alone: one kernel, one more index).
-* **One matmul pair a kv head a cell.** The kv heads are a static loop
-  inside the cell; a head stacks its ``P * bs`` rows out of the resident
-  pages and does ONE score matmul, ONE value matmul and ONE online-softmax
+* **One matmul pair a kv head a cell a query tile, and a cell's own work
+  once a cell.** The kv heads are a static loop inside the cell; a head
+  stacks its ``P * bs`` rows out of the resident pages (a page is
+  ``(bs, Hk, D)``, so a head's rows are read one sublane-strided row at a
+  time) and does ONE score matmul, ONE value matmul and ONE online-softmax
   update (running max ``m``, normalizer ``l``, weighted values ``acc``,
   merged with ``alpha = exp(m_prev - m_cur)`` — the sequential spelling
-  of split-K, as in ``flash_attention.py``'s forward kernel).
+  of split-K, as in ``flash_attention.py``'s forward kernel). A row of ONE
+  query tile (a decode row, the ``Q = 1`` form) stacks the rows in
+  registers as it goes. A chunk row of SEVERAL sub-tiles does whatever the
+  cell and the head alone decide ONCE a cell: every head's K and V rows
+  into a head-major VMEM scratch ``[Hk, P * bs, D]``, V contained (below)
+  and only in a cell that holds a position to contain; its sub-tiles then
+  read them contiguously, all heads of a sub-tile in one unrolled run (the
+  compiler overlaps one head's matmuls with the next one's softmax: a
+  branch or a loop INSIDE a head's work costs more than the stacking it
+  saves, PERF.md §6, PR 34). What the row alone decides is done once a
+  slot: each tile row's query offset, capped at ``dl``, is divided out of
+  its row index when the accumulators are cleared (``qpos``), and a
+  cell's masks add ``sl`` to it.
 * **GQA grouped IN-KERNEL.** Queries arrive as ``[M, Hk, Q * G, D]`` (row
   ``q * G + g`` of kv head ``kh`` is query offset ``q``'s head ``kh * G +
   g``), so each page is read ONCE and each head's rows are scored against
@@ -47,10 +63,11 @@ the real tokens** — pages a slot holds, query rows a slot carries.
   ``dl == 0`` slot (a decoding slot inside a mixed step) runs the
   ``R0``-row tile — its ``G`` heads padded to the query dtype's sublane
   tile, exactly the decode step's work; a longer one runs ``ceil((dl + 1)
-  * G / TQ)`` sub-tiles of ``TQ <= 128`` rows. The branch is on
-  ``draft_lens`` alone. **Output rows past ``dl`` are zeros** (until PR
-  25 they held a capped-window result nobody read): ``paged_mixed_step``
-  takes row ``dl``, the speculative verify masks by ``draft_lens``.
+  * G / TQ)`` sub-tiles of ``TQ <= 128`` rows against the cell's stacked
+  rows. The branch is on ``draft_lens`` alone. **Output rows past ``dl``
+  are zeros** (until PR 25 they held a capped-window result nobody read):
+  ``paged_mixed_step`` takes row ``dl``, the speculative verify masks by
+  ``draft_lens``.
 * **Passes that multiply by zero are not run.** bf16 queries against a
   bf16 or int8 pool contract as bf16 — one MXU pass, exact products, fp32
   accumulation: the sum ``HIGHEST`` computes over the cast operands — and
@@ -132,7 +149,8 @@ _NEG_INF = -1e30
 # operands need no such care (see ``_scores`` / ``_weighted_values``).
 _F32 = jax.lax.Precision.HIGHEST
 _LANES = 128
-_KV_TILE = 128        # KV positions a cell attends, about
+_KV_TILE = 256        # KV positions a cell attends, about
+_LATENT_KV_TILE = 128  # the latent form's: never read at 256 (PERF.md §7)
 _MAX_PAGES = 16       # pages a cell holds at most (its copies are unrolled)
 _ROW_TILE = 128       # query rows a sub-tile of a chunk row holds, at most
 # what a profile calls the kernel: [window-bounded?][multi-query?]
@@ -150,14 +168,14 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _tiling(bs, W, G, Q, q_dtype):
+def _tiling(bs, W, G, Q, q_dtype, kv_tile=_KV_TILE):
     """The three tile sizes, from the shapes alone. ``P`` pages a cell,
-    so a cell attends about ``_KV_TILE`` KV positions; ``R0`` query
+    so a cell attends about ``kv_tile`` KV positions; ``R0`` query
     rows for a row that carries ONE query position (its ``G`` grouped
     heads padded to the query dtype's sublane tile); ``TQ`` query rows a
     sub-tile of a longer row — the largest aligned divisor of ``Q * G``
     up to ``_ROW_TILE``, or the whole row where there is none."""
-    P = max(1, min(_KV_TILE // bs, _MAX_PAGES, W))
+    P = max(1, min(kv_tile // bs, _MAX_PAGES, W))
     QG = Q * G
     sub = _sublanes(q_dtype)
     R0 = min(_round_up(G, sub), QG)
@@ -202,13 +220,15 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     """One grid step = one slot ``m``; inside it a loop over the slot's
     LIVE cells of ``P`` KV pages. The pools stay in HBM: the kernel copies
     a cell's live pages into a two-slot VMEM buffer itself, the next
-    cell's while this one computes; per kv head the cell reads its
-    ``P * bs`` rows out of the buffer as ``kv_dtype`` and does ONE score
-    matmul, ONE value matmul and ONE update of ``m``/``l``/``acc``. An
-    int8 pool's scales arrive laid out by cell (``[1, cells, Hk, P *
-    bs]``, positions along lanes) and scale the scores' and the weights'
-    COLUMNS: ``(q · kᵀ) * ks`` and ``(p * vs) · v`` are the dequantized
-    sums with the int8 values, exact in either float type, as operands.
+    cell's while this one computes; per kv head and query tile the cell
+    reads its ``P * bs`` rows as ``kv_dtype`` (out of the buffer, or for a
+    row of several tiles out of the head-major scratch they were stacked
+    into once a cell) and does ONE score matmul, ONE value matmul and ONE
+    update of ``m``/``l``/``acc``. An int8 pool's scales arrive laid out
+    by cell (``[1, cells, Hk, P * bs]``, positions along lanes) and scale
+    the scores' and the weights' COLUMNS: ``(q · kᵀ) * ks`` and ``(p * vs)
+    · v`` are the dequantized sums with the int8 values, exact in either
+    float type, as operands.
 
     ``layered``: the pools are every layer's, ``[L, N, bs, Hk, D]``, and
     a last scalar-prefetch operand names the layer whose pages are copied
@@ -237,7 +257,8 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
         layer, refs = refs[0][0], refs[1:]
     q_ref, hbm = refs[0], refs[1:3]      # the K and V pools, in HBM
     ks_ref, vs_ref = refs[3:5] if quant else (None, None)
-    o_ref, acc_ref, m_ref, l_ref, kbuf, vbuf, ksem, vsem = refs[-8:]
+    (o_ref, acc_ref, m_ref, l_ref, qpos_ref, kh_ref, vh_ref, kbuf, vbuf,
+     ksem, vsem) = refs[-11:]
     m = pl.program_id(0)
     QG = Q * G
     C = P * bs
@@ -288,11 +309,11 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
             x = x.astype(jnp.float32)
         return x.astype(kv_dtype)
 
-    def row_pos(r0, nr, width):
+    def row_pos(r0, nr):
         """Query offset of each of the ``nr`` tile rows from ``r0`` on
-        (row ``q * G + g`` is query offset ``q``), rank 2 like every
-        index vector here (Mosaic has no rank-1 layout)."""
-        r = r0 + jax.lax.broadcasted_iota(jnp.int32, (nr, width), 0)
+        (row ``q * G + g`` is query offset ``q``), ``[nr, 1]``: rank 2
+        like every index vector here (Mosaic has no rank-1 layout)."""
+        r = r0 + jax.lax.broadcasted_iota(jnp.int32, (nr, 1), 0)
         return r // G if G > 1 else r
 
     def init(r0, nr):
@@ -301,48 +322,91 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
                                         jnp.float32)
         m_ref[:, rows, :] = jnp.full((Hk, nr, 1), _NEG_INF, jnp.float32)
         l_ref[:, rows, :] = jnp.zeros((Hk, nr, 1), jnp.float32)
+        if multi:                      # the row's causal edge past ``sl``:
+            # once a slot, so that no cell divides a tile of rows by ``G``
+            qpos_ref[rows, :] = jnp.minimum(row_pos(r0, nr), dl)
 
     def attend(c, slot):
         """Fold cell ``c``'s ``C`` KV positions, resident in buffer slot
-        ``slot``, into the accumulators of a tile of query rows."""
+        ``slot``, into the accumulators of every query row the slot
+        fills."""
         base = c * C if window is None else first * bs + c * C
+        last = base + (C - 1)
 
-        def tile(r0, nr):
-            rows = pl.ds(r0, nr)
-            # jcol/jrow: the cell's KV positions down sublanes / along
-            # lanes. A page of the cell that was not copied (past the
-            # window) holds whatever the buffer held: it masks by its
-            # logical position like any block's stale tail.
+        def attendable():
+            """Down sublanes, the cell's positions that SOME query of the
+            slot attends. Containment: V at the others must be ZEROED, not
+            merely zero-weighted — a poisoned request can park NaN there
+            (see llama._masked_sdpa); exact 0.0 weights make this
+            bit-invisible for finite KV. The widest window any query row
+            reaches is j <= sl + dl (every position there was written this
+            dispatch or earlier), so the union can never touch a stale
+            block tail; under ``window`` nothing behind the first query's
+            lower edge either. A page of the cell that was not copied
+            (past ``pages``) holds whatever the buffer held: it lies past
+            ``sl + dl`` like any block's stale tail."""
             jcol = base + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+            keep = jcol <= sl + dl
+            if window is not None:
+                keep &= jcol >= lo
+            return keep
+
+        def stack_heads():
+            """What the cell and the kv head alone decide, ONCE a cell:
+            each head's K and V rows out of the pages into the head-major
+            scratch that every sub-tile of the row then reads
+            contiguously, V contained. A cell wholly inside ``lo <= j <=
+            sl + dl`` has nothing to contain and runs none of it."""
+            def head(h):
+                kh_ref[h] = head_rows(kbuf, slot, h)
+                vh_ref[h] = head_rows(vbuf, slot, h)
+            unrolled(Hk, head)
+            if quant:                  # the V scales take the zero: below
+                return
+            outside = last > sl + dl
+            if window is not None:
+                outside |= base < lo
+
+            @pl.when(outside)
+            def _contain():
+                keep = attendable()
+
+                def head(h):
+                    v = vh_ref[h]
+                    vh_ref[h] = jnp.where(keep, v, jnp.zeros_like(v))
+                unrolled(Hk, head)
+
+        def tile(r0, nr, stacked):
+            """One tile of query rows against the cell. ``stacked``: the
+            tile is a sub-tile of a row that has several, and reads the
+            heads' rows ``stack_heads`` left; the one tile of a shorter
+            row stacks them in registers as it goes."""
+            rows = pl.ds(r0, nr)
+            # the cell's KV positions along lanes against each row's own
+            # edges (per-query-row causal draft window)
             jrow = base + jax.lax.broadcasted_iota(jnp.int32, (nr, C), 1)
-            if multi:                  # per-query-row causal draft window
-                edge = sl + jnp.minimum(row_pos(r0, nr, C), dl)
-            else:
-                edge = sl
+            edge = sl + qpos_ref[rows, :] if multi else sl
             valid = jrow <= edge
             if window is not None:
                 valid &= jrow > edge - window
-            # containment: V at never-attendable positions must be ZEROED,
-            # not merely zero-weighted — a poisoned request can park NaN
-            # there (see llama._masked_sdpa); exact 0.0 weights make this
-            # bit-invisible for finite KV. The widest window any query row
-            # reaches is j <= sl + dl (every position there was written
-            # this dispatch or earlier), so the union can never touch a
-            # stale block tail. An int8 value is finite; there it is the
-            # V SCALE that can hold the NaN, and takes the zero.
-            keep = (jrow[:1] if quant else jcol) <= sl + dl
-            if window is not None:     # and nothing behind the window
-                keep &= jcol >= lo
+            if quant:                  # an int8 value is finite: there it
+                # is the V SCALE that can hold the NaN, and takes the zero
+                keep = jrow[:1] <= sl + dl
+            elif not stacked:
+                keep = attendable()
 
             def head(h):
                 q = q_ref[0, h, rows, :].astype(kv_dtype)    # [nr, D]
-                k = head_rows(kbuf, slot, h)                 # [C, D]
-                v = head_rows(vbuf, slot, h)
+                if stacked:
+                    k, v = kh_ref[h], vh_ref[h]              # [C, D]
+                else:
+                    k = head_rows(kbuf, slot, h)
+                    v = head_rows(vbuf, slot, h)
+                    if not quant:
+                        v = jnp.where(keep, v, jnp.zeros_like(v))
                 s = _scores(q, k) * scale
                 if quant:              # dequant: one scale a KV column
                     s = s * ks_ref[0, c, pl.ds(h, 1), :]     # [1, C]
-                else:
-                    v = jnp.where(keep, v, jnp.zeros_like(v))
                 s = jnp.where(valid, s, _NEG_INF)
                 m_prev = m_ref[h, rows, :]               # [nr, 1]
                 m_cur = jnp.maximum(m_prev,
@@ -358,19 +422,23 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
                     _weighted_values(p, v)
                 m_ref[h, rows, :] = m_cur
             unrolled(Hk, head)
-        return tile
+
+        over_rows(functools.partial(tile, stacked=False),
+                  functools.partial(tile, stacked=True), stack_heads)
 
     def finalize(r0, nr):
         rows = pl.ds(r0, nr)
         l = l_ref[:, rows, :]
         out = acc_ref[:, rows, :] / jnp.where(l == 0.0, 1.0, l)
         if multi:                      # tile rows past the slot's draft
-            real = row_pos(r0, nr, 1) <= dl              # [nr, 1]
+            real = row_pos(r0, nr) <= dl                 # [nr, 1]
             out = jnp.where(real[None], out, 0.0)
         o_ref[0, :, rows, :] = out.astype(o_ref.dtype)
 
-    def over_rows(fn):
-        """Run ``fn(r0, nr)`` over the query tiles this slot fills."""
+    def over_rows(fn, sub=None, once=None):
+        """Run ``fn(r0, nr)`` over the query tiles this slot fills. A row
+        of SEVERAL tiles runs ``once()`` and then ``sub(r0, nr)`` over its
+        sub-tiles (``fn`` where no ``sub`` is given)."""
         if R0 == QG:                   # one tile is the whole row
             fn(0, QG)
             return
@@ -382,9 +450,12 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
             if TQ == QG:
                 fn(0, QG)
                 return
+            if once is not None:
+                once()
+            each = sub or fn
 
             def tile(t, carry):
-                fn(pl.multiple_of(t * TQ, TQ), TQ)
+                each(pl.multiple_of(t * TQ, TQ), TQ)
                 return carry
             jax.lax.fori_loop(0, ((dl + 1) * G + (TQ - 1)) // TQ, tile, 0)
 
@@ -393,7 +464,7 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
         pl.when(c + 1 < cells)(
             lambda: copy_pages(c + 1, 1 - slot, lambda cp: cp.start()))
         copy_pages(c, slot, lambda cp: cp.wait())
-        over_rows(attend(c, slot))
+        attend(c, slot)
         return carry
 
     copy_pages(0, 0, lambda cp: cp.start())
@@ -405,17 +476,19 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
 
 
 def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
-                pool_dtype) -> int:
+                pool_dtype, kv_dtype) -> int:
     """Scoped-VMEM request for one grid step, from the shapes: the query
     and output tiles (and an int8 pool's by-cell scales) are
     double-buffered by the pipeline, each padded to its dtype's vector
     tile; the kernel's own two-slot K and V page buffers of ``C = P * bs``
-    positions; the three accumulators, resident (``m``/``l`` pad their
-    one column to a 128-lane tile); the head loop's temporaries, sized by
-    the widest query tile a cell runs (``rows = max(R0, TQ)`` against
-    ``C`` positions) and no longer by ``Q``. A mixed step's prefill chunk
-    under GQA needs more than the compiler's 16 MiB default for its
-    resident tiles; a decode step far less."""
+    positions and the head-major K and V rows a chunk row stacks once a
+    cell; the three accumulators and the rows' query offsets, resident
+    (``m``/``l`` and the offsets pad their one column to a 128-lane
+    tile); the head loop's temporaries, sized by the widest query tile a
+    cell runs (``rows = max(R0, TQ)`` against ``C`` positions) and no
+    longer by ``Q``. A mixed step's prefill chunk under GQA needs more
+    than the compiler's 16 MiB default for its resident tiles; a decode
+    step far less."""
     isz = lambda dt: jnp.dtype(dt).itemsize
     pad = lambda n, dt: _round_up(n, _sublanes(dt))
     lanes = lambda n: _round_up(n, _LANES)
@@ -424,7 +497,9 @@ def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
     if jnp.dtype(pool_dtype) == jnp.int8:
         tiles += 2 * 2 * cells * pad(Hk, jnp.float32) * lanes(C) * 4
     kv = 2 * 2 * C * pad(Hk, pool_dtype) * lanes(D) * isz(pool_dtype)
+    kv += 2 * Hk * pad(C, kv_dtype) * lanes(D) * isz(kv_dtype)
     scratch = Hk * pad(QG, jnp.float32) * (lanes(D) + 2 * _LANES) * 4
+    scratch += pad(QG, jnp.int32) * _LANES * 4
     rows = pad(rows, jnp.float32)
     temps = 2 * 4 * (rows * (2 * lanes(D) + 6 * lanes(C)) +
                      6 * pad(C, jnp.float32) * lanes(D))
@@ -580,6 +655,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
             pltpu.VMEM((Hk, QG, D), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
             pltpu.VMEM((Hk, QG, 1), jnp.float32),
+            pltpu.VMEM((QG, 1), jnp.int32),
+            pltpu.VMEM((Hk, P * bs, D), kv_dtype),
+            pltpu.VMEM((Hk, P * bs, D), kv_dtype),
             pltpu.VMEM((2, P) + k_pool.shape[-3:], k_pool.dtype),
             pltpu.VMEM((2, P) + v_pool.shape[-3:], v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -597,7 +675,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_vmem_bytes(Hk, QG, D, P * bs, cells,
                                          max(R0, TQ), q.dtype, out_dtype,
-                                         k_pool.dtype)),
+                                         k_pool.dtype, kv_dtype)),
         interpret=_interpret(),
         # the trace and the compiled text name the custom call after this:
         # the decode form and the multi-query (mixed / verify) form are
@@ -702,7 +780,7 @@ def paged_attention_latent(q_lat, q_rope, pool, layer, block_tables,
                          f"against a pool of width {D} and q_lat of {R}")
     if out_dtype is None:
         out_dtype = pool.dtype
-    P, _, _ = _tiling(bs, W, H, 1, q_lat.dtype)
+    P, _, _ = _tiling(bs, W, H, 1, q_lat.dtype, _LATENT_KV_TILE)
     exact = q_lat.dtype == pool.dtype == jnp.bfloat16
     kv_dtype = jnp.bfloat16 if exact else jnp.float32
     scalars = (jnp.asarray(block_tables, jnp.int32),

@@ -403,9 +403,10 @@ class TestPagedAttention:
         np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
 
     @staticmethod
-    def _oracle_multi(q, pool, tbl, sl, dl):
+    def _oracle_multi(q, pool, tbl, sl, dl, window=None):
         """Gather + _masked_sdpa with the verify window: query offset i of
-        slot m attends j <= sl[m] + min(i, dl[m])."""
+        slot m attends j <= sl[m] + min(i, dl[m]), and under ``window``
+        only j > sl[m] + min(i, dl[m]) - window."""
         from paddle_tpu.models.generation import _kv_gather
         from paddle_tpu.models.llama import _masked_sdpa
         M, Q = q.shape[:2]
@@ -415,7 +416,10 @@ class TestPagedAttention:
                             M, C, Hk, D)     # a pool of one layer
         qi = jnp.arange(Q)
         hi = sl[:, None] + jnp.minimum(qi[None, :], dl[:, None])  # [M, Q]
-        mask = jnp.arange(C)[None, None, :] <= hi[:, :, None]
+        j = jnp.arange(C)[None, None, :]
+        mask = j <= hi[:, :, None]
+        if window is not None:
+            mask &= j > hi[:, :, None] - window
         return _masked_sdpa(q, kk, vv, mask)
 
     @staticmethod
@@ -560,6 +564,98 @@ class TestPagedAttention:
             np.testing.assert_allclose(
                 np.asarray(out, np.float32)[short, 0],
                 np.asarray(single, np.float32)[short], **tol)
+
+    @pytest.mark.parametrize("pool_kind", ["fp32", "bf16"])
+    @pytest.mark.parametrize("edge", ["before_last", "last", "first",
+                                      "second"])
+    @pytest.mark.parametrize("windowed", [False, True])
+    @pytest.mark.parametrize("G", [6, 9])
+    def test_tiling_follows_real_tokens_once_a_cell(self, G, windowed, edge,
+                                                    pool_kind):
+        """ISSUE 34's nesting at its edges, one call a case: chunk rows of
+        ``Q`` 128 under query groups of 6 and 9 (6 and 9 sub-tiles of 128
+        rows, each run against K and V rows stacked once a cell), the full
+        form and the window-bounded one (a ring table; cells count from
+        the window's first page, so they move with ``sl``). ``sl`` puts
+        the first query's causal edge one BEFORE a cell's last position
+        (one position to mask), on its LAST (the cell is interior: no mask
+        runs), on its FIRST (one attendable position) and on its second:
+        interior or edge, off by one, is the planted risk. A call holds a
+        whole chunk, one whose ``(dl + 1) * G`` rows are no multiple of
+        the sub-tile, a two-token row and a decode row, each at such an
+        ``sl`` in a cell of its own. Then every
+        position no query of the slot attends — unowned blocks, the null
+        block, the tail past ``sl + dl``, pages that fell out of the ring,
+        positions behind the window — takes NaN, which must change no
+        bit."""
+        import importlib
+        pa = importlib.import_module("paddle_tpu.kernels.paged_attention")
+        Q, Hk, D = 128, 2, 16
+        bs = 8 if G == 6 else 16                 # cells of 128 and of 256
+        dt = jnp.bfloat16 if pool_kind == "bf16" else jnp.float32
+        dl = np.array([Q - 1, 40, 1, 0], np.int32)
+        M = len(dl)
+        P, R0, TQ = pa._tiling(bs, 64, G, Q, dt)
+        C = P * bs
+        assert C == 16 * bs and TQ == 128 and R0 <= 16
+        assert Q * G == G * TQ                   # G sub-tiles a whole chunk
+        assert (41 * G) % TQ                     # a last sub-tile part full
+        # (a window of 2C - 1 lets a deep sl sit at any of the four offsets)
+        window = 2 * C - 1 if windowed else None
+
+        def offset(s):                           # of sl in its cell
+            lo = max(s - window + 1, 0) if windowed else 0
+            return (s - lo // bs * bs) % C
+        want_off = {"before_last": C - 2, "last": C - 1, "first": 0,
+                    "second": 1}[edge]
+        # one sl a slot, each in another cell, the first the deepest
+        sl = np.array([next(s for s in range(k * C + C // 2, (k + 2) * C)
+                            if offset(s) == want_off)
+                       for k in (3, 2, 1, 0)], np.int32)
+        pages = (sl + dl) // bs + 1              # a slot's pages, linear
+        Wlin = int(pages.max())
+        R = (window + Q) // bs + 2 if windowed else Wlin
+        N = M * Wlin + 3
+        rng = np.random.default_rng(34)
+        q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        lin = rng.permutation(np.arange(1, M * Wlin + 1)).reshape(M, Wlin)
+        lin = np.where(np.arange(Wlin)[None, :] < pages[:, None], lin, 0)
+        tbl = lin
+        if windowed:                             # page p at entry p % R: a
+            tbl = np.zeros((M, R), np.int64)     # later page takes its place
+            for m in range(M):
+                for pg in range(int(pages[m])):
+                    tbl[m, pg % R] = lin[m, pg]
+        dead = np.ones((N, bs), bool)            # no query may attend
+        j = np.arange(Wlin * bs)
+        for m in range(M):
+            seen = j <= sl[m] + dl[m]
+            if windowed:
+                seen &= j > sl[m] - window
+            dead[lin[m]] &= ~seen.reshape(Wlin, bs)
+        dead[0] = True
+        dead = jnp.asarray(dead)
+        lin, tbl, sl, dl = (jnp.asarray(a, jnp.int32)
+                            for a in (lin, tbl, sl, dl))
+
+        def run(poison):
+            k, v = ((jnp.where(dead[:, :, None, None], jnp.nan, x)
+                     for x in (kf, vf)) if poison else (kf, vf))
+            return pa.paged_attention(q, k, v, tbl, sl, draft_lens=dl,
+                                      window=window)
+
+        out = run(poison=False)
+        wide = {"k": kf.astype(jnp.float32), "v": vf.astype(jnp.float32)}
+        want = self._oracle_multi(q.astype(jnp.float32), wide, lin, sl, dl,
+                                  window)
+        tol = (dict(rtol=1e-2, atol=1e-2) if pool_kind == "bf16"
+               else dict(rtol=3e-5, atol=3e-5))
+        self._check_multi(out, want, dl, **tol)
+        np.testing.assert_array_equal(
+            np.asarray(run(poison=True), np.float32),
+            np.asarray(out, np.float32))
 
     @pytest.mark.parametrize("pool_kind", ["fp32", "bf16", "int8"])
     @pytest.mark.parametrize("Hk", [1, 4])
