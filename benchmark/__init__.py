@@ -71,10 +71,39 @@ there. What it brings:
     end of ``per_layer``, after whatever is last then. Append, never
     insert: an entry that is there keeps its index, because the driver
     reads an entry put in the middle as a change to the one whose place
-    it takes. That is all the tests hold of the order
-    (``tests/benchmark/test_benchmark_contract.py`` does to a copy of the
-    file what such a PR does): none pins the end of ``per_layer`` or a
-    reader's ``workloads`` to one cell.
+    it takes. That is all the tests hold of the order, and of a
+    reader's ``workloads`` they hold which cells are AMONG it: none pins
+    the end of ``per_layer`` or a reader's list to one cell.
+``tests/benchmark/test_benchmark_<family>.py``
+    the family's tests. What the file holds of the repo's
+    ``BENCHMARK.json`` (which readers its cell lists, where its own
+    entries stand: right after the entry that was last before them,
+    whatever follows; its cell FIRST in their ``workloads``, whatever
+    follows) it brings as a function ``check_*(bench)``, and its ``test_``
+    of the same matter takes the ``bench`` fixture and hands it over.
+
+How the last two are held (PR 27's tests pinned the tail, PR 32 freed it,
+PR 33's own file pinned it and its readers' lists again, PR 35 freed those
+and closed the way in). THE MARKER: a function of a
+``tests/benchmark/test_benchmark_*.py`` whose name starts with ``check_``
+and whose first parameter is named ``bench`` is a structural check; it
+takes ``(bench)`` or ``(bench, roots)``.
+``test_benchmark_contract.py`` does to a copy of the repo's file what the
+next ``model_config`` PR does (a configuration, a cell, its name on
+``out_tokens_per_s`` and on every reader it reads in, four new entries at
+the end; once in memory, once for real in a temporary directory) and
+calls EVERY function that bears the marker on the result
+(``structural_checks`` finds them, no list names them), so a family file
+that a later PR adds is walked by being there. THE RULE, which
+``test_no_test_goes_round_the_rehearsal`` reads off the sources: the
+repo's ``BENCHMARK.json`` is opened by the one ``bench`` fixture
+(``tests/benchmark/conftest.py``; ``part_of`` reads the configurations'
+names and ``paths`` while cases are collected), a ``test_`` does nothing
+with it but hand it to a function that bears the marker, nothing counts
+``per_layer`` from its end, and nothing holds a ``workloads`` equal to a
+list. A family file written by copying the last one passes its
+assertions through the rehearsal or fails that test in the PR that adds
+it.
 
 ``tests/benchmark/rehearsal/`` is the proof on the CPU: its ``toy.train``
 cell runs a model this directory has no file for, through

@@ -1,6 +1,34 @@
-"""For the tests of the CPU rehearsal: what a run handed its readers."""
+"""For the tests under ``tests/benchmark/``: the repo's ``BENCHMARK.json``
+as the ONE fixture that opens it, and what a run of the CPU rehearsal
+handed its readers."""
+
+import json
+import os
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+@pytest.fixture
+def bench():
+    """The repo's ``BENCHMARK.json``, parsed anew for each test. No other
+    code under ``tests/benchmark/`` opens that file, and a ``test_`` does
+    nothing with this dictionary but hand it to a ``check_*(bench)``
+    function: what a file asserts of the metrics' entries is then a
+    function the rehearsal of the next ``model_config`` PR finds and
+    calls on the dictionary that PR will leave
+    (``test_benchmark_contract.py``: ``structural_checks``, and
+    ``test_no_test_goes_round_the_rehearsal``, which holds both)."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture
+def cells(bench):
+    """The names of the repo's cells, for a test that runs each."""
+    return [w["name"] for w in bench["workloads"]]
 
 
 @pytest.fixture

@@ -11,6 +11,10 @@ a model ``benchmark/`` has no file for. They hold no model's numbers: a
 configuration names its published table (``published/<name>.json``), and
 the rules below are about kinds of key, not about values."""
 
+import ast
+import glob
+import importlib
+import inspect
 import json
 import os
 import re
@@ -38,11 +42,6 @@ def _json(*path):
         return json.load(f)
 
 
-@pytest.fixture(scope="module")
-def bench():
-    return _json(REPO, "BENCHMARK.json")
-
-
 # ---------------------------------------------------------------------------
 # the two trees, and one case a configuration
 # ---------------------------------------------------------------------------
@@ -50,11 +49,20 @@ def bench():
 TREES = {"repo": REPO, "rehearsal": REHEARSAL}
 
 
+def part_of(tree, key):
+    """``configs`` or ``paths`` of a tree's ``BENCHMARK.json``: which
+    configurations there are and where their files lie, read while the
+    cases below are collected, when no fixture exists yet. The metrics'
+    entries and the cells' are the ``bench`` fixture's alone
+    (``conftest.py``; ``test_no_test_goes_round_the_rehearsal``)."""
+    assert key in ("configs", "paths")
+    return _json(TREES[tree], "BENCHMARK.json")[key]
+
+
 def roots_of(tree):
     """Where a tree's files are looked for: its own directory first, then
     the repo's (``run.py`` does the same)."""
-    own = os.path.join(TREES[tree], _json(TREES[tree],
-                                          "BENCHMARK.json")["paths"][0])
+    own = os.path.join(TREES[tree], part_of(tree, "paths")[0])
     return [own, os.path.join(REPO, "benchmark")]
 
 
@@ -67,13 +75,11 @@ def find_file(tree, kind, name, ext):
 
 
 CONFIGS = [pytest.param(tree, c["name"], id=f"{tree}:{c['name']}")
-           for tree in TREES
-           for c in _json(TREES[tree], "BENCHMARK.json")["configs"]]
+           for tree in TREES for c in part_of(tree, "configs")]
 
 
 def load_config(tree, name):
-    entry = next(c for c in _json(TREES[tree], "BENCHMARK.json")["configs"]
-                 if c["name"] == name)
+    entry = next(c for c in part_of(tree, "configs") if c["name"] == name)
     return entry, _json(TREES[tree], entry["file"])
 
 
@@ -243,13 +249,16 @@ def check_cells_and_metrics_are_files(bench, roots):
     assert len(four) <= max(1, len(bench["workloads"]) // 4)
 
 
-# ``per_layer`` as PR 32 left it. The one rule about order: APPEND, never
-# insert. An entry that is there keeps its index (the driver reads an
-# entry put in the middle as a change to the one whose place it takes and
-# refuses the PR); what a later PR appends after these is free, and no
-# test knows which entries are last. Only a ``benchmark`` PR that retires
-# a metric edits this list.
-PER_LAYER_AT_PR32 = [
+# ``per_layer`` as it stands: every entry the driver has accepted, in its
+# place. The one rule about order: APPEND, never insert. An entry that is
+# there keeps its index (the driver reads an entry put in the middle as a
+# change to the one whose place it takes and refuses the PR); what a later
+# PR appends after these is free, and no test knows which entries are
+# last. Who edits this list: a ``benchmark`` PR, and only to add, at its
+# end, the names that accepted PRs have appended since (PR 35 added PR
+# 33's four), or to take out a metric it retires. A ``model_config`` PR
+# leaves it alone: its new entries come after these.
+PER_LAYER_THAT_EXISTS = [
     "mixed_wall_p50_ms.sat", "decode_wall_p50_ms.sat", "live_slots_mean.sat",
     "mixed_dispatches_per_req.sat", "step_ms.train", "mfu_pct.train",
     "peak_hbm_gb.train", "collective_exposed_pct.train4",
@@ -258,26 +267,48 @@ PER_LAYER_AT_PR32 = [
     "decode_iter_wall_ms.sat", "mixed_real_lane_pct.sat",
     "paged_attn_device_pct.sat", "moe_ffn_device_pct.sat",
     "moe_grouped_roofline_pct.sat", "latent_attn_roofline_pct.sat",
-    "moe_load_max_over_mean.sat", "mfu_pct.sat"]
+    "moe_load_max_over_mean.sat", "mfu_pct.sat",
+    "window_attention_roofline_pct.sat", "full_attention_roofline_pct.sat",
+    "kv_pool_used_pct.sat", "preemptions.sat"]
 
 
 def check_what_exists_keeps_its_place(bench):
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[:len(PER_LAYER_AT_PR32)] == PER_LAYER_AT_PR32
+    assert names[:len(PER_LAYER_THAT_EXISTS)] == PER_LAYER_THAT_EXISTS
+
+
+def structural_checks():
+    """Every structural assertion the files here make of the repo's
+    ``BENCHMARK.json``, FOUND and not listed: each function of a
+    ``tests/benchmark/test_benchmark_*.py`` whose name starts with
+    ``check_`` and whose first parameter is named ``bench`` (the marker;
+    ``benchmark/__init__.py`` states it). Such a function takes ``(bench)``
+    or ``(bench, roots)``, so that whoever finds it can call it. A family's
+    file that a later PR adds is among them by being there."""
+    found = []
+    for path in sorted(glob.glob(os.path.join(HERE, "test_benchmark_*.py"))):
+        # by the name pytest imported it under, beside this file
+        mod = importlib.import_module(os.path.basename(path)[:-3])
+        for name, fn in sorted(vars(mod).items()):
+            if not (name.startswith("check_") and inspect.isfunction(fn)):
+                continue
+            params = list(inspect.signature(fn).parameters)
+            if params[:1] != ["bench"] or name == "check_structure":
+                continue
+            assert params in (["bench"], ["bench", "roots"]), (path, name)
+            if fn not in found:
+                found.append(fn)
+    return found
 
 
 def check_structure(bench, roots):
-    """Every structural assertion ``tests/benchmark/*.py`` make of the
-    repo's ``BENCHMARK.json``."""
-    import test_benchmark_moe as moe        # beside this file, as pytest
-    import test_benchmark_spans as spans    # imports them
-    check_top_level_keys(bench)
-    check_names_and_units(bench)
-    check_sources_bounds_and_moves(bench)
-    check_cells_and_metrics_are_files(bench, roots)
-    check_what_exists_keeps_its_place(bench)
-    spans.check_the_span_readers_entries(bench)
-    moe.check_which_readers_list_the_cell(bench)
+    """Walk ``structural_checks()`` over ``bench``: the repo's
+    ``BENCHMARK.json`` or a dictionary a test made of it."""
+    for check in structural_checks():
+        if "roots" in inspect.signature(check).parameters:
+            check(bench, roots)
+        else:
+            check(bench)
 
 
 class TestBenchmarkJson:
@@ -292,80 +323,392 @@ class TestBenchmarkJson:
 
     def test_cells_and_metrics_are_files_found_by_name(self, bench):
         check_cells_and_metrics_are_files(
-            bench, [os.path.join(REPO, bench["paths"][0])])
+            bench, [os.path.join(REPO, "benchmark")])
 
     def test_what_exists_keeps_its_place(self, bench):
         check_what_exists_keeps_its_place(bench)
 
+    def test_every_check_of_every_file(self, bench):
+        check_structure(bench, [os.path.join(REPO, "benchmark")])
+
 
 # ---------------------------------------------------------------------------
-# the next ``model_config`` PR, done to a copy of the repo's file in memory
+# the next ``model_config`` PR, done to a copy of the repo's file: in memory
+# (``next_configuration``) and in a directory (``next_configuration_on_disk``)
 # ---------------------------------------------------------------------------
 
-FAMILYS_OWN = ("moe_", "latent_")      # readers of one family's kernels
-NEW_ENTRY = {"name": "window_attn_roofline_pct.sat", "unit": "%",
-             "better": "higher", "source": "device_trace",
-             "layer": "kernels", "moves": "out_tokens_per_s",
-             "workloads": ["next-serve-sat"]}
+FAMILYS_OWN = ("moe_", "latent_", "window_")   # one family's kernel readers
+CELL = "next-serve-sat"
+# Four, as a family brings: a kernel's share of busy time, a roofline share
+# for each of two kernels, a share of the cache. Under names no reader will
+# take: an entry of a name the repo's file has is a duplicate, and the
+# rehearsal would fail on the very PR it stands for (PR 33's readers had to
+# be renamed for that).
+NEW_ENTRIES = [
+    {"name": f"next_{what}.sat", "unit": "%", "better": better,
+     "source": source, "layer": layer, "moves": "out_tokens_per_s",
+     "workloads": [CELL]}
+    for what, better, source, layer in [
+        ("kernel_device_pct", "lower", "device_trace", "kernels"),
+        ("chunk_kernel_roofline_pct", "higher", "device_trace", "kernels"),
+        ("token_kernel_roofline_pct", "higher", "device_trace", "kernels"),
+        ("state_cache_pct", "lower", "program_counter", "engine step")]]
+NEW_NAMES = [e["name"] for e in NEW_ENTRIES]
+
+
+def _next_pr(bench, config, traffic_mix, reads_in, readers_dir):
+    """What that PR does to ``BENCHMARK.json`` (``benchmark/__init__.py``),
+    to ``bench`` in place: a configuration, a cell on it, the cell's name
+    appended to ``out_tokens_per_s`` and to every reader ``reads_in`` says
+    it reads in; and the files of its new readers, which read nothing, in
+    ``readers_dir``. Their ENTRIES the caller puts where it wants them."""
+    bench["configs"].append(config)
+    bench["workloads"].append({
+        "name": CELL, "config": config["name"], "traffic": traffic_mix,
+        "chips": 1, "why": "closed loop; the next family's kernels"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] == "out_tokens_per_s" or reads_in(m):
+            m["workloads"].append(CELL)
+    os.makedirs(readers_dir)
+    for e in NEW_ENTRIES:
+        with open(os.path.join(readers_dir, e["name"] + ".py"), "w") as f:
+            f.write(f'LAYER = "{e["layer"]}"\nMOVES = "{e["moves"]}"\n'
+                    f'UNIT = "{e["unit"]}"\n\n\ndef read(run):\n'
+                    f'    return None\n')
 
 
 @pytest.fixture
 def next_configuration(bench, tmp_path):
-    """What that PR does to ``BENCHMARK.json``: a configuration, a cell on
-    it, the cell's name appended to ``out_tokens_per_s`` and to every
-    ``.sat`` reader that is not one family's own; and, in a tree of its
-    own (nothing is written into the repo's), the file of ONE new reader,
-    whose entry the test puts where it wants it."""
-    bench = json.loads(json.dumps(bench))
-    cell = NEW_ENTRY["workloads"][0]
-    bench["configs"].append({
-        "name": "next-model-d4", "source": "https://huggingface.co/org/next",
-        "file": "benchmark/configs/next-model-d4.json",
-        "reduced": ["num_hidden_layers"],
-        "why": "window and full layers mixed, 256 routed experts"})
-    bench["workloads"].append({
-        "name": cell, "config": "next-model-d4", "traffic": "reason-sat",
-        "chips": 1, "why": "closed loop; the window-bounded paged kernel"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] == "out_tokens_per_s" or (
-                m["name"].endswith(".sat")
-                and not m["name"].startswith(FAMILYS_OWN)):
-            m["workloads"].append(cell)
-    readers = tmp_path / "layer_metrics"
-    readers.mkdir()
-    (readers / (NEW_ENTRY["name"] + ".py")).write_text(
-        'LAYER = "kernels"\nMOVES = "out_tokens_per_s"\nUNIT = "%"\n\n\n'
-        'def read(run):\n    return None\n')
+    """In memory: the cell reads in EVERY ``.sat`` reader that is not one
+    family's own kernel reader (so in the three other readers PR 33
+    brought too: any family with full layers over a block pool does).
+    Nothing is written into the repo's tree: the readers' files go to a
+    tree of the test's own, which ``roots`` puts first."""
+    _next_pr(
+        bench,
+        {"name": "next-model-d4", "source": "https://huggingface.co/org/next",
+         "file": "benchmark/configs/next-model-d4.json",
+         "reduced": ["num_hidden_layers"],
+         "why": "linear-attention and full layers mixed, 512 routed experts"},
+        "reason-sat",
+        lambda m: (m["name"].endswith(".sat")
+                   and not m["name"].startswith(FAMILYS_OWN)),
+        str(tmp_path / "layer_metrics"))
     return bench, [str(tmp_path), os.path.join(REPO, "benchmark")]
 
 
 def test_the_next_configuration_is_appended_and_every_check_passes(
         next_configuration):
     bench, roots = next_configuration
-    bench["per_layer"].append(dict(NEW_ENTRY))
+    bench["per_layer"] += [dict(e) for e in NEW_ENTRIES]
     check_structure(bench, roots)
-    # the cell reads what the families share and, last, its own
+    # ... and "every check" is every file's: the walk found them
+    assert {f"{c.__module__}.{c.__name__}" for c in structural_checks()} >= {
+        "test_benchmark_contract.check_top_level_keys",
+        "test_benchmark_contract.check_names_and_units",
+        "test_benchmark_contract.check_sources_bounds_and_moves",
+        "test_benchmark_contract.check_cells_and_metrics_are_files",
+        "test_benchmark_contract.check_what_exists_keeps_its_place",
+        "test_benchmark_spans.check_the_span_readers_entries",
+        "test_benchmark_moe.check_which_readers_list_the_cell",
+        "test_benchmark_laguna.check_which_readers_list_the_cell"}
+    # the cell reads what the families share and its own four, in order
     listed = [m["name"] for m in bench["per_layer"]
-              if "next-serve-sat" in m["workloads"]]
-    assert {"step_host_ms.sat", "paged_attn_device_pct.sat",
-            "mfu_pct.sat"} < set(listed)
-    assert listed[-1] == NEW_ENTRY["name"]
+              if CELL in m["workloads"]]
+    assert {"step_host_ms.sat", "paged_attn_device_pct.sat", "mfu_pct.sat",
+            "full_attention_roofline_pct.sat", "kv_pool_used_pct.sat",
+            "preemptions.sat"} < set(listed)
+    assert [n for n in listed if n in NEW_NAMES] == NEW_NAMES
     assert not [n for n in listed if n.startswith(FAMILYS_OWN)]
 
 
-@pytest.mark.parametrize("at", [0, 4, 12, 17, len(PER_LAYER_AT_PR32) - 1])
-def test_an_entry_put_in_the_middle_is_told_apart(next_configuration, at):
-    """The same entry, inserted: everything else still holds (so nothing
-    but its place is wrong), and the order's check says so. "Append, never
-    insert" is what the tests hold of the order, and all they hold."""
+@pytest.mark.parametrize("pin", ["its four are last",
+                                 "its lists hold its cell alone"])
+def test_with_a_pin_of_pr33_put_back_the_rehearsal_fails(
+        next_configuration, monkeypatch, pin):
+    """What the rehearsal is for. PR 33's family file held, of the repo's
+    file, that its four readers were LAST in ``per_layer`` and that each
+    listed its cell ALONE; both were true of the file as it was, the
+    assertions stood in a ``test_`` that opened the file itself, and the
+    rehearsal, which listed its checks by hand, never met them: ISSUE 35
+    found them by doing the next PR's addition on a copy. Either one put
+    back into the family's ``check_*`` fails the rehearsal, here, in the
+    PR that writes it."""
+    import test_benchmark_laguna as laguna
+    sound = laguna.check_which_readers_list_the_cell
+
+    def check_which_readers_list_the_cell(bench):
+        sound(bench)
+        names = [m["name"] for m in bench["per_layer"]]
+        if pin == "its four are last":
+            assert names[len(names) - 4:] == laguna.NEW, pin
+        else:
+            for m in bench["per_layer"]:
+                if m["name"] in laguna.NEW:
+                    assert len(m["workloads"]) == 1, pin
+
     bench, roots = next_configuration
-    bench["per_layer"].insert(at, dict(NEW_ENTRY))
+    bench["per_layer"] += [dict(e) for e in NEW_ENTRIES]
+    check_structure(bench, roots)                      # sound as it stands
+    monkeypatch.setattr(laguna, "check_which_readers_list_the_cell",
+                        check_which_readers_list_the_cell)
+    with pytest.raises(AssertionError, match=pin):
+        check_structure(bench, roots)
+
+
+@pytest.mark.parametrize("at", range(len(PER_LAYER_THAT_EXISTS)))
+def test_an_entry_put_in_the_middle_is_told_apart(next_configuration, at):
+    """One of the new entries, inserted at a place that exists (PR 33's
+    four among them), the others appended: everything else still holds (so
+    nothing but its place is wrong), and the order's check says so.
+    "Append, never insert" is what the tests hold of the order, and all
+    they hold."""
+    bench, roots = next_configuration
+    bench["per_layer"].insert(at, dict(NEW_ENTRIES[0]))
+    bench["per_layer"] += [dict(e) for e in NEW_ENTRIES[1:]]
     check_top_level_keys(bench)
     check_names_and_units(bench)
     check_sources_bounds_and_moves(bench)
     check_cells_and_metrics_are_files(bench, roots)
     with pytest.raises(AssertionError):
         check_what_exists_keeps_its_place(bench)
+
+
+@pytest.fixture
+def next_configuration_on_disk(bench, tmp_path):
+    """The addition done for real, as ISSUE 35 did it by hand: in a
+    directory, a copy of ``BENCHMARK.json`` with a configuration (a copy
+    of the newest configuration's file under another name), a cell on it,
+    the cell's name on ``out_tokens_per_s`` and on every reader the newest
+    cell reads in (sixteen at PR 35), four reader files and their entries
+    at the end. Returns the directory, ``roots`` as ``run.py`` would have
+    them there, and the names of those readers."""
+    root = tmp_path / "tree"
+    newest = bench["workloads"][-1]
+    config = dict(next(c for c in bench["configs"]
+                       if c["name"] == newest["config"]))
+    with open(os.path.join(REPO, config["file"])) as f:
+        sizes = json.load(f)
+    config.update(name="next-model-ep8-d9",
+                  file="benchmark/configs/next-model-ep8-d9.json",
+                  why="a copy of the newest configuration under another name")
+    os.makedirs(root / "benchmark" / "configs")
+    (root / config["file"]).write_text(
+        json.dumps({**sizes, "name": config["name"]}))
+    shared = [m["name"] for m in bench["per_layer"]
+              if newest["name"] in m.get("workloads", ())]
+    _next_pr(bench, config, newest["traffic"],
+             lambda m: m["name"] in shared,
+             str(root / "benchmark" / "layer_metrics"))
+    bench["per_layer"] += [dict(e) for e in NEW_ENTRIES]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    return (root, [str(root / "benchmark"), os.path.join(REPO, "benchmark")],
+            shared)
+
+
+def test_the_addition_done_in_a_directory_passes_every_check(
+        next_configuration_on_disk):
+    root, roots, shared = next_configuration_on_disk
+    added = json.loads((root / "BENCHMARK.json").read_text())
+    check_structure(added, roots)
+    # what ``run.py`` would find for the cell there: its configuration's
+    # file, cut as the guide allows, and a reader for each of its entries
+    cell = next(w for w in added["workloads"] if w["name"] == CELL)
+    entry = next(c for c in added["configs"] if c["name"] == cell["config"])
+    cfg = _json(root, entry["file"])
+    assert cfg["name"] == entry["name"] and cfg["reduced"] == entry["reduced"]
+    check_cut(cfg, _json(REPO, "benchmark", "published",
+                         cfg["published"] + ".json")["config"])
+    reads = [m["name"] for m in added["per_layer"]
+             if CELL in m.get("workloads", ())]
+    assert reads == shared + NEW_NAMES
+    for name in reads:
+        assert callable(harness.load_by_name("layer_metrics", name,
+                                             roots).read)
+    assert all(harness.load_by_name("layer_metrics", n, roots).read({})
+               is None for n in NEW_NAMES)
+
+
+# ---------------------------------------------------------------------------
+# no way round the rehearsal: the sources under ``tests/benchmark/``
+# ---------------------------------------------------------------------------
+
+OPENERS = {("conftest.py", "bench"),                  # the one fixture
+           ("test_benchmark_contract.py", "part_of")}  # configs, paths only
+FIXTURES_ON_BENCH = {("conftest.py", "cells"),
+                     ("test_benchmark_contract.py", "next_configuration"),
+                     ("test_benchmark_contract.py",
+                      "next_configuration_on_disk")}
+
+
+def _functions(tree):
+    """Every function of a parsed file with the names of those round it."""
+    def walk(node, outer):
+        for child in ast.iter_child_nodes(node):
+            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                yield child, outer
+                yield from walk(child, outer + [child.name])
+            else:
+                yield from walk(child, outer)
+    yield from walk(tree, [])
+
+
+def _outside_functions(node):
+    for child in ast.iter_child_nodes(node):
+        if not isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            yield child
+            yield from _outside_functions(child)
+
+
+def _mentions(node, *names):
+    return any(isinstance(n, ast.Name) and n.id in names
+               for n in ast.walk(node))
+
+
+def _is_negative(node):
+    return any(isinstance(n, ast.UnaryOp) and isinstance(n.op, ast.USub)
+               and isinstance(n.operand, ast.Constant)
+               for n in ast.walk(node))
+
+
+def _handed_to_checks(fn):
+    """The calls in ``fn`` that hand a name to a ``check_*`` function as
+    its first argument: (the function's name, the argument's node)."""
+    calls = [(getattr(n.func, "id", getattr(n.func, "attr", "")), n.args[0])
+             for n in ast.walk(fn) if isinstance(n, ast.Call) and n.args]
+    return [(name, arg) for name, arg in calls
+            if name.startswith("check_") and isinstance(arg, ast.Name)]
+
+
+def ways_round(sources):
+    """``sources``: file name -> text of each file under
+    ``tests/benchmark/``. The ways a file could hold something of the
+    repo's ``BENCHMARK.json`` that the rehearsal never meets, each as a
+    line of words; none is the rule."""
+    found = []
+    for file, text in sorted(sources.items()):
+        tree = ast.parse(text)
+        # 1. the repo's file is opened in one place
+        scopes = [("<module>", list(_outside_functions(tree)))] + [
+            (fn.name, list(ast.walk(fn)))
+            for fn, outer in _functions(tree) if not outer]
+        for name, nodes in scopes:
+            opens = any(
+                isinstance(n, (ast.Call, ast.BinOp))
+                and _mentions(n, "REPO", "TREES")
+                and any(isinstance(c, ast.Constant) and isinstance(
+                    c.value, str) and c.value.endswith("BENCHMARK.json")
+                    and " " not in c.value for c in ast.walk(n))
+                for n in nodes)
+            if opens and (file, name) not in OPENERS:
+                found.append(f"{file}: {name} opens the repo's "
+                             f"BENCHMARK.json; the bench fixture does that")
+        for fn, outer in _functions(tree):
+            params = [a.arg for a in fn.args.args]
+            where = f"{file}: {'.'.join(outer + [fn.name])}"
+            # 2. a test_ only hands the fixture's dictionary to a check_*
+            if fn.name.startswith("test_") and "bench" in params:
+                handed = {id(arg) for _, arg in _handed_to_checks(fn)}
+                if any(isinstance(n, ast.Name) and n.id == "bench"
+                       and id(n) not in handed for n in ast.walk(fn)):
+                    found.append(f"{where} does more with bench than hand "
+                                 f"it to a check_* function")
+            # 3. nor does a fixture of a file's own pass it on
+            if "bench" in params and (file, fn.name) not in \
+                    FIXTURES_ON_BENCH and any(
+                        "fixture" in ast.unparse(d)
+                        for d in fn.decorator_list):
+                found.append(f"{where} is a fixture over bench")
+            # 4. nothing counts per_layer from its end ...
+            tainted = set()
+            for n in ast.walk(fn):       # assignments, in source order
+                if isinstance(n, ast.Assign) and (
+                        "per_layer" in ast.unparse(n.value)
+                        or _mentions(n.value, *tainted)):
+                    tainted |= {t.id for t in n.targets
+                                if isinstance(t, ast.Name)}
+            for n in ast.walk(fn):
+                if isinstance(n, ast.Subscript) and _is_negative(n.slice) \
+                        and ("per_layer" in ast.unparse(n.value)
+                             or _mentions(n.value, *tainted)):
+                    found.append(f"{where} counts per_layer from its end: "
+                                 f"{ast.unparse(n)}")
+                # 5. ... or holds a reader's workloads equal to a list
+                if isinstance(n, ast.Compare) and any(
+                        isinstance(op, ast.Eq) for op in n.ops):
+                    sides = [n.left, *n.comparators]
+                    if any(isinstance(s, ast.Subscript) and isinstance(
+                            s.slice, ast.Constant) and s.slice.value ==
+                            "workloads" for s in sides) and any(
+                                isinstance(s, ast.List) for s in sides):
+                        found.append(f"{where} holds a workloads equal to "
+                                     f"a list: {ast.unparse(n)}")
+    return found
+
+
+def _the_sources():
+    sources = {}
+    for path in glob.glob(os.path.join(HERE, "*.py")):
+        with open(path) as f:
+            sources[os.path.basename(path)] = f.read()
+    return sources
+
+
+def test_no_test_goes_round_the_rehearsal():
+    """The repo's ``BENCHMARK.json`` is opened by the ``bench`` fixture
+    alone (and, for the names of its configurations and its ``paths``, by
+    ``part_of``); a ``test_`` does nothing with it but hand it to a
+    ``check_*`` function, and every such function is one the rehearsal
+    finds; no file counts ``per_layer`` from its end or holds a
+    ``workloads`` equal to a list. A family file written by copying the
+    last one then either brings its assertions as a ``check_*(bench)`` the
+    rehearsal walks, or fails here, in the PR that adds it."""
+    sources = _the_sources()
+    assert len(sources) >= 6 and "conftest.py" in sources
+    assert ways_round(sources) == []
+    walked = {c.__name__ for c in structural_checks()} | {"check_structure"}
+    for file, text in sources.items():
+        for fn, _ in _functions(ast.parse(text)):
+            if fn.name.startswith("test_") and "bench" in [
+                    a.arg for a in fn.args.args]:
+                called = {name for name, arg in _handed_to_checks(fn)
+                          if arg.id == "bench"}
+                assert called and called <= walked, (file, fn.name, called)
+
+
+# (the slice is spelt apart so that a grep of these files for it finds none)
+AS_PR33_WROTE_IT = '''
+def test_which_readers_list_the_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [m["name"] for m in bench["per_layer"]FROM_THE_END] == NEW
+    for m in bench["per_layer"]:
+        if m["name"] in NEW:
+            assert m["workloads"] == [REAL]
+'''.replace("FROM_THE_END", "[" + "-4:]")
+
+
+@pytest.mark.parametrize("text,said", [
+    (AS_PR33_WROTE_IT, ["opens the repo's BENCHMARK.json",
+                        "counts per_layer from its end",
+                        "holds a workloads equal to a list"]),
+    ('def test_the_tail(bench):\n'
+     '    names = [m["name"] for m in bench["per_layer"]]\n'
+     '    assert names[-1] == "ours.sat"\n',
+     ["does more with bench than hand it", "counts per_layer from its end"]),
+    ('BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))\n',
+     ["<module> opens the repo's BENCHMARK.json"]),
+    ('@pytest.fixture\ndef ours(bench):\n    return bench["per_layer"]\n',
+     ["ours is a fixture over bench"]),
+    ('def test_which_readers_list_the_cell(bench):\n'
+     '    check_which_readers_list_the_cell(bench)\n', []),
+], ids=["as-pr33-wrote-it", "the-fixture-and-an-index-from-the-end",
+        "opened-at-import", "a-fixture-of-its-own", "a-check-handed-over"])
+def test_a_family_file_that_goes_round_is_told(text, said):
+    got = ways_round({"test_benchmark_copied.py": text})
+    assert len(got) == len(said), got
+    for words in said:
+        assert any(words in line for line in got), (words, got)
 
 
 class TestConfigurations:
@@ -375,8 +718,7 @@ class TestConfigurations:
     @pytest.mark.parametrize("tree,name", CONFIGS)
     def test_configuration_is_files_found_by_name(self, tree, name):
         entry, cfg = load_config(tree, name)
-        paths = _json(TREES[tree], "BENCHMARK.json")["paths"]
-        assert entry["file"].startswith(paths[0] + "/")
+        assert entry["file"].startswith(part_of(tree, "paths")[0] + "/")
         assert cfg["name"] == entry["name"]
         assert cfg["source"] == entry["source"]
         assert cfg["reduced"] == entry["reduced"]
@@ -433,7 +775,7 @@ class TestConfigurations:
         is unlike the repo's configurations."""
         _, cfg = load_config(*self.TOY)
         repo_models = {load_config("repo", c["name"])[1]["model"]
-                       for c in _json(REPO, "BENCHMARK.json")["configs"]}
+                       for c in part_of("repo", "configs")}
         assert cfg["model"] not in repo_models
         assert not os.path.exists(os.path.join(
             REPO, "benchmark", "models", cfg["model"] + ".py"))

@@ -137,23 +137,35 @@ def test_a_picked_local_expert_left_out_is_not_correct(capsys, cache_dir,
     assert faults == {"name": "health_faults", "value": 0.0, "limit": 0.0}
 
 
+# What a family may hold of the repo's ``BENCHMARK.json``: what it BROUGHT,
+# where it put it. Not what is last in ``per_layer`` and not the whole of a
+# reader's ``workloads``: the next configuration appends its readers after
+# these and its cell's name to the lists of the readers it reads in
+# (``full_attention_roofline_pct.sat``, ``kv_pool_used_pct.sat`` and
+# ``preemptions.sat`` read in any family with full layers over a block
+# pool). The assertions are a ``check_*(bench)`` function, so the rehearsal
+# of that PR (``test_benchmark_contract.py``) finds them and calls them on
+# the dictionary it will leave; the ``test_`` hands them the repo's file.
+
 def _listed(bench, cell):
     return [m for m in bench["per_layer"] if cell in m.get("workloads", ())]
 
 
-def test_which_readers_list_the_cell():
+def check_which_readers_list_the_cell(bench):
     """The real cell reads the twelve readers it shares with the cells
-    before it and, LAST in ``per_layer``, the four it brought; the
-    rehearsal's entries are their twins. (Not ``decode_iter_wall_ms.sat``:
-    in this cell nearly every step is a mixed step, and a window with no
-    decode dispatch leaves that reader nothing to read.)"""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    before it and the four it brought, which stand where they were
+    appended (right after ``mfu_pct.sat``, the last entry there was) and
+    name this cell first; the rehearsal's entries are their twins. (Not
+    ``decode_iter_wall_ms.sat``: in this cell nearly every step is a mixed
+    step, and a window with no decode dispatch leaves that reader nothing
+    to read.) ``bench``: the repo's ``BENCHMARK.json``, or a dictionary a
+    test made of it."""
+    from test_benchmark_spans import in_place_after
     with open(os.path.join(REHEARSAL, "BENCHMARK.json")) as f:
         ours = _listed(json.load(f), CELL)
-    real = _listed(bench, REAL)
-    assert [m["name"] for m in real] == SHARED + NEW
-    assert [m["name"] for m in bench["per_layer"][-4:]] == NEW
+    assert [m["name"] for m in _listed(bench, REAL)] == SHARED + NEW
+    assert in_place_after([m["name"] for m in bench["per_layer"]],
+                          "mfu_pct.sat", NEW)
     assert [m["name"] for m in ours] == SHARED + NEW
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for m in ours:
@@ -164,14 +176,17 @@ def test_which_readers_list_the_cell():
         assert f'LAYER = "{m["layer"]}"' in src
         assert f'MOVES = "{m["moves"]}"' in src
         assert f'UNIT = "{m["unit"]}"' in src
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [REAL]
+    for name in NEW:      # written for this cell; a later cell appends
+        assert by_name[name]["workloads"].index(REAL) == 0
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert REAL in e2e["out_tokens_per_s"]["workloads"]
     cell = next(w for w in bench["workloads"] if w["name"] == REAL)
     assert cell["chips"] == 1 and cell["traffic"] == "mixedlen-sat"
     assert cell["config"] == "laguna-s-2.1-ep8-d9"
+
+
+def test_which_readers_list_the_cell(bench):
+    check_which_readers_list_the_cell(bench)
 
 
 @pytest.mark.parametrize("tree", ["toy", "real"])

@@ -104,11 +104,21 @@ def test_a_picked_local_expert_left_out_is_not_correct(capsys, cache_dir,
 # Since PR 32 the real cell reports every reader this tree's toy cell
 # does: the nine it shares with the dense family (``APPENDED``: the cell's
 # name appended to their ``workloads``) and the family's own five (``NEW``:
-# entries appended at the end of ``per_layer``). No test pins the end of
-# ``per_layer`` or a reader's ``workloads`` to one cell: the next
-# configuration appends its cell's name to the lists of the readers that
-# read in it and its own readers' entries after whatever is last then
-# (``benchmark/__init__.py``; ``test_benchmark_contract.py`` rehearses it).
+# entries appended at the end of ``per_layer``). A family's test file holds
+# of the repo's ``BENCHMARK.json`` only what the family brought, never what
+# is last in ``per_layer`` nor the whole of a reader's ``workloads``: the
+# next configuration appends its cell's name to the lists of the readers
+# that read in it and its own readers' entries after whatever is last then
+# (``benchmark/__init__.py``). How that is held, since PR 35 (PR 33's own
+# file had pinned both again): a file's structural assertions are a
+# ``check_*(bench)`` function, as below; ``test_benchmark_contract.py``'s
+# rehearsal of that PR finds every such function of every
+# ``test_benchmark_*.py`` (``structural_checks``: none is listed by hand)
+# and calls it on the dictionary that PR will leave; and
+# ``test_no_test_goes_round_the_rehearsal`` reads the sources: the repo's
+# file is opened by the ``bench`` fixture alone (``conftest.py``), a
+# ``test_`` only hands it to a ``check_*``, nothing indexes ``per_layer``
+# from its end or holds a ``workloads`` equal to a list.
 
 def _listed(bench, cell):
     return [m for m in bench["per_layer"] if cell in m.get("workloads", ())]
@@ -134,9 +144,8 @@ def check_which_readers_list_the_cell(bench):
         assert f'UNIT = "{m["unit"]}"' in src
 
 
-def test_which_readers_list_the_cell():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        check_which_readers_list_the_cell(json.load(f))
+def test_which_readers_list_the_cell(bench):
+    check_which_readers_list_the_cell(bench)
 
 
 def test_the_toys_cut_keeps_the_guides_floors():

@@ -177,9 +177,8 @@ def test_new_cell_config_and_metric_are_files_found_by_name(
     assert all(p.read_bytes() == data for p, data in before.items())
 
 
-def test_a_cell_of_the_repos_benchmark_refuses_to_run_off_the_chip(capsys):
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        cells = [w["name"] for w in json.load(f)["workloads"]]
+def test_a_cell_of_the_repos_benchmark_refuses_to_run_off_the_chip(capsys,
+                                                                   cells):
     for cell in cells:
         rc = bench_run.main(["--workload", cell, "--seconds", "1"])
         captured = capsys.readouterr()

@@ -162,9 +162,8 @@ def check_the_span_readers_entries(bench):
                           "compiles_in_window.train", SPAN_READERS)
 
 
-def test_every_new_reader_is_an_entry_with_its_layer_and_unit():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        check_the_span_readers_entries(json.load(f))
+def test_every_new_reader_is_an_entry_with_its_layer_and_unit(bench):
+    check_the_span_readers_entries(bench)
 
 
 REHEARSAL_SIX = [*((name, "tiny.sat") for name in SPAN_READERS),
