@@ -26,8 +26,10 @@ never slices (copies) a layer's experts out of it. On the chip that slice
 cost as much as the matmul it fed (PERF.md, PR 27).
 
 ``gated=True`` reads ``w`` as ``[gate | up]`` (``N = 2 I``) and returns
-``silu(x w_gate) * (x w_up)`` ``[R, I]``: the activation rides the last
-contraction step, so the ``[R, 2 I]`` intermediate never exists.
+``act(x w_gate) * (x w_up)`` ``[R, I]``, ``act`` the ``activation``
+(``"silu"``: SwiGLU; ``"relu"``: ReGLU): it rides the last contraction
+step, so the ``[R, 2 I]`` intermediate never exists. Either is the one
+kernel ``moe_grouped_matmul_gated`` to a profile.
 
 ``use_kernel=False`` is the XLA-composed path (``jax.lax.ragged_dot``),
 the oracle the kernel is tested against and what the CPU runs by default.
@@ -48,6 +50,7 @@ __all__ = ["grouped_matmul", "visit_plan"]
 
 _ROW_TILE = 128
 _SLAB_BYTES = 8 << 20          # one contraction step's weight copy, about
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def _round_up(x: int, m: int) -> int:
@@ -88,7 +91,7 @@ def visit_plan(group_sizes, tm: int, row_tiles: int):
 
 
 def _kernel(group_ref, tile_ref, start_ref, end_ref, live_ref, base_ref,
-            x_ref, w_ref, o_ref, acc_ref, *, tm, nk, gated):
+            x_ref, w_ref, o_ref, acc_ref, *, tm, nk, act):
     v, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(v < live_ref[0])
@@ -107,14 +110,15 @@ def _kernel(group_ref, tile_ref, start_ref, end_ref, live_ref, base_ref,
                 jnp.int32, (tm, 1), 0)
             mine = (rows >= start_ref[g]) & (rows < end_ref[g])
             out = acc_ref[...]
-            if gated:
+            if act is not None:
                 half = out.shape[1] // 2
-                out = jax.nn.silu(out[:, :half]) * out[:, half:]
+                out = act(out[:, :half]) * out[:, half:]
             o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
 
 
 def grouped_matmul(x, w, group_sizes, *, layer=None, gated: bool = False,
-                   use_kernel: bool = False, out_dtype=None):
+                   activation: str = "silu", use_kernel: bool = False,
+                   out_dtype=None):
     """See the module's docstring. ``x [R, K]``, ``w [G, K, N]`` (or ``[L,
     G, K, N]`` with ``layer``), ``group_sizes [G]`` int32 -> ``[R, N]``
     (``[R, N // 2]`` gated)."""
@@ -127,6 +131,7 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, gated: bool = False,
             else (jnp.asarray(layer, jnp.int32) * G).reshape(1))
     out_dtype = out_dtype or x.dtype
     No = N // 2 if gated else N
+    act = _ACTIVATIONS[activation] if gated else None
     sizes = jnp.asarray(group_sizes, jnp.int32)
     total = sizes.sum()
     if not use_kernel:
@@ -135,7 +140,7 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, gated: bool = False,
         out = jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
                                  preferred_element_type=jnp.float32)
         if gated:
-            out = jax.nn.silu(out[:, :No]) * out[:, No:]
+            out = act(out[:, :No]) * out[:, No:]
     else:
         tm = min(_ROW_TILE, _round_up(R, 16))
         Rp = _round_up(R, tm)
@@ -158,7 +163,7 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, gated: bool = False,
         vmem = (2 * tk * N * w.dtype.itemsize + 2 * tm * tk * x.dtype.itemsize
                 + 3 * tm * N * 4 + 2 * tm * No * jnp.dtype(out_dtype).itemsize)
         out = pl.pallas_call(
-            functools.partial(_kernel, tm=tm, nk=nk, gated=gated),
+            functools.partial(_kernel, tm=tm, nk=nk, act=act),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Rp, No), out_dtype),
             compiler_params=pltpu.CompilerParams(

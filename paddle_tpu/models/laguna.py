@@ -1,7 +1,11 @@
-"""The ``laguna`` family (Laguna-S): window and full attention layers
-mixed, a head count a kind of layer, per-head gated attention, routed
-experts beside a shared one, served through the paged engine with ONE
-block pool for both kinds of layer.
+"""The window/full family (Laguna-S, SmallThinker): window and full
+attention layers mixed, a head count a kind of layer, routed experts,
+served through the paged engine with ONE block pool for both kinds of
+layer. What differs between the architectures is static configuration,
+whose defaults are Laguna's (:class:`LagunaConfig`): the rotary embedding
+of each kind of layer (or none), a per-head gate or none, a dense layer 0
+or a sparse one, the router's input and score, the experts' activation, a
+shared expert or none.
 
 **The block** (pre-norm; ``N_*`` an RMSNorm with its own weight, ``l`` the
 layer, ``H_l`` its query heads)::
@@ -14,19 +18,25 @@ a W_kv`` as ``num_key_value_heads`` heads each; no bias, no normalisation
 of queries or keys. Rotary embedding by the KIND of layer, on rotate-half
 pairs ``(i, i + r / 2)`` of the first ``r`` lanes, the rest passing
 through: a sliding layer plain (``r`` = all lanes, ``theta`` 1e4), a full
-layer YaRN on half the lanes (:func:`rope_inv_freq`). Query head ``h``
-reads key-value head ``h // (H_l / Hk)``; position ``i`` attends ``j <=
-i`` and, in a sliding layer, only ``j > i - sliding_window``. The gate:
+layer YaRN on half the lanes (:func:`rope_inv_freq`), or none where
+``full_rope_type`` is ``"none"`` (NoPE). Query head ``h`` reads key-value
+head ``h // (H_l / Hk)``; position ``i`` attends ``j <= i`` and, in a
+sliding layer, only ``j > i - sliding_window``. The gate (``head_gate``):
 ``z = sigmoid(a W_g)`` (``W_g [E, H_l]``), output ``concat_h(z_h o_h)
-W_o``.
+W_o``; without it ``concat_h(o_h) W_o``.
 
-**FFN.** Layer 0 is a gated dense FFN. Every other layer scores all
-``num_experts`` in float32 (``sigmoid``), takes the top
+**FFN.** Layer 0 is a gated dense FFN (``first_layer_dense``) or a sparse
+layer like the others. A sparse layer scores all ``num_experts`` in
+float32 (``router_score``: ``sigmoid``, or ``softmax``), takes the top
 ``num_experts_per_tok``, weighs them ``moe_routed_scaling_factor * s /
-sum s`` and adds the shared expert ungated; it holds ``n_local_experts``
-of them from ``expert_offset`` on and computes their part of the sum (the
-routing, the sort by expert and the grouped matmul are
-:mod:`.pangu_ultra_moe`'s, under the same counters).
+sum s``, and adds the shared expert ungated where it has one
+(``shared_expert_intermediate_size`` 0: none); an expert is gated SiLU or
+ReLU (``expert_activation``). The router reads ``m`` or, with
+``router_before_attention``, the layer's input ``x`` itself, on which
+attention has no bearing, so the routing may run beside it. It holds
+``n_local_experts`` experts from ``expert_offset`` on and computes their
+part of the sum (the routing, the sort by expert and the grouped matmul
+are :mod:`.pangu_ultra_moe`'s, under the same counters).
 
 **The stack.** Layer 0 alone, then a ``lax.scan`` over PERIODS (a period's
 sliding layers then its full layer as the scan's ``xs``), then the
@@ -126,6 +136,15 @@ class LagunaConfig:
     yarn_attention_factor: float = 1.4852030263919618
     sliding_rope_theta: float = 10000.0
     sliding_rotary_factor: float = 1.0
+    full_rope_type: str = "yarn"           # or "none": no rotary (NoPE)
+    # the architecture's other differences, Laguna's by default
+    first_layer_dense: bool = True         # layer 0's FFN dense, or sparse
+    head_gate: bool = True                 # sigmoid gate a query head
+    router_before_attention: bool = False  # the router reads x, not m
+    router_score: str = "sigmoid"          # or "softmax"
+    expert_activation: str = "silu"        # or "relu" (ReGLU)
+    # the architecture's name, which ``describe`` gives
+    family: str = "laguna"
     # the most query tokens of one sequence a dispatch carries (the
     # engine's ``prefill_chunk``): with the window, what a ring holds
     window_chunk: int = 128
@@ -163,6 +182,12 @@ class LagunaConfig:
                 f"{self.n_sliding} sliding layers do not split into cache "
                 f"groups of {self.n_full} (the full layers' count): cut the "
                 f"depth at whole periods")
+        for name, allowed in (("full_rope_type", ("yarn", "none")),
+                              ("router_score", ("sigmoid", "softmax")),
+                              ("expert_activation", ("silu", "relu"))):
+            if getattr(self, name) not in allowed:
+                raise ValueError(f"{name} is one of {allowed}, got "
+                                 f"{getattr(self, name)!r}")
         if self.expert_offset < 0 or (self.expert_offset +
                                       self.n_local_experts
                                       > self.num_experts):
@@ -206,6 +231,11 @@ class LagunaConfig:
                                        self.layer_types) if k == SLIDING), 0)
 
     @property
+    def n_sparse(self) -> int:
+        """Layers with routed experts."""
+        return self.num_hidden_layers - int(self.first_layer_dense)
+
+    @property
     def window_groups(self) -> int:
         return self.n_sliding // self.n_full
 
@@ -231,7 +261,7 @@ def describe(cfg: LagunaConfig) -> Dict[str, Any]:
     names (``stats()["model"]``)."""
     out = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
            if f.name not in ("dtype", "param_dtype")}
-    out.update(family="laguna", dtype=jnp.dtype(cfg.dtype).name,
+    out.update(dtype=jnp.dtype(cfg.dtype).name,
                layer_types=list(cfg.layer_types),
                num_attention_heads_per_layer=list(
                    cfg.num_attention_heads_per_layer),
@@ -248,7 +278,7 @@ def health(counters: Dict[str, int], cfg: LagunaConfig) -> Dict:
     total, local = (counters.get("moe_pairs_total"),
                     counters.get("moe_pairs_local"))
     lanes = total / cfg.num_experts_per_tok / max(
-        cfg.num_hidden_layers - 1, 1) if total else 0
+        cfg.n_sparse, 1) if total else 0
     held = counters.get("kv_blocks_in_use_sum")
 
     def mean_read(name, layers):
@@ -278,18 +308,21 @@ def _layer_shapes(cfg: LagunaConfig, kind: str, sparse: bool
     H = cfg.heads_full if kind == FULL else cfg.heads_sliding
     shapes = {"ln_in": (E,), "ln_post": (E,), "wq": (E, H * D),
               "wkv": (E, 2 * Hk * D),     # [keys | values]: one matmul
-              "wg": (E, H), "wo": (H * D, E)}
+              "wo": (H * D, E)}
+    if cfg.head_gate:
+        shapes["wg"] = (E, H)
     if not sparse:
         I = cfg.intermediate_size
         shapes.update(w_gate=(E, I), w_up=(E, I), w_down=(I, E))
     else:
         I, Is = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
         shapes.update(router=(E, cfg.num_experts),
-                      ws_gate=(E, Is), ws_up=(E, Is), ws_down=(Is, E),
                       # the held experts: [gate | up] side by side, so one
                       # grouped matmul makes both
                       w_gu=(cfg.n_local_experts, E, 2 * I),
                       w_down=(cfg.n_local_experts, I, E))
+        if Is:
+            shapes.update(ws_gate=(E, Is), ws_up=(E, Is), ws_down=(Is, E))
     return shapes
 
 
@@ -297,7 +330,7 @@ def _parts(cfg: LagunaConfig):
     """``(path, stacked-over, kind, sparse)`` of each stacked tree of the
     parameters, in the stack's order."""
     P, S, T = cfg.n_periods, cfg.slides_per_period, cfg.n_tail
-    parts = [(("first",), (), FULL, False)]
+    parts = [(("first",), (), FULL, not cfg.first_layer_dense)]
     if P:
         parts += [(("periods", "slide"), (P, S), SLIDING, True),
                   (("periods", "full"), (P,), FULL, True)]
@@ -351,8 +384,10 @@ def _norm(x, w, cfg):
 
 
 def rope_inv_freq(cfg: LagunaConfig, kind: str):
-    """``(inv_freq [r / 2] float32, factor)`` of one kind of layer; ``r``
-    is the rotated lanes. Sliding: ``theta ** (-2i / r)``. Full: YaRN as
+    """``(inv_freq [r / 2] float32, factor)`` of one kind of layer, None
+    for a kind with no rotary embedding; ``r`` is the rotated lanes.
+    Sliding: ``theta ** (-2i / r)``. Full: none (``full_rope_type``
+    ``"none"``), or YaRN as
     ``transformers`` computes it: the plain and the ``/ factor``
     frequencies blended by a ramp between the lanes that turn
     ``beta_fast`` and ``beta_slow`` times in the original context, and
@@ -361,6 +396,8 @@ def rope_inv_freq(cfg: LagunaConfig, kind: str):
         r = int(cfg.head_dim * cfg.sliding_rotary_factor)
         i = jnp.arange(0, r, 2, dtype=jnp.float32)
         return cfg.sliding_rope_theta ** (-i / r), 1.0
+    if cfg.full_rope_type == "none":
+        return None
     r = int(cfg.head_dim * cfg.full_rotary_factor)
     theta, L0 = cfg.full_rope_theta, cfg.yarn_original_max_position
     extra = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
@@ -378,8 +415,12 @@ def rope_inv_freq(cfg: LagunaConfig, kind: str):
 
 
 def _rope_tables(cfg, kind, pos):
-    """``cos, sin [.., r / 2]`` (float32) at integer positions ``pos``."""
-    inv, factor = rope_inv_freq(cfg, kind)
+    """``cos, sin [.., r / 2]`` (float32) at integer positions ``pos``;
+    None for a kind of layer with no rotary embedding."""
+    freq = rope_inv_freq(cfg, kind)
+    if freq is None:
+        return None
+    inv, factor = freq
     ang = pos.astype(jnp.float32)[..., None] * inv
     return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
@@ -397,18 +438,24 @@ def _rope(x, cos, sin):
 
 def _project(lp, a, rope, cfg):
     """``a [T, E]`` (normed) -> ``q [T, H, D]``, ``k``, ``v [T, Hk, D]``
-    (rotated) and the gate ``z [T, H]`` (float32)."""
+    (rotated where ``rope`` is not None) and the gate ``z [T, H]``
+    (float32; None without one)."""
     dt, D, Hk = cfg.dtype, cfg.head_dim, cfg.num_key_value_heads
     T = a.shape[0]
     q = (a @ lp["wq"].astype(dt)).reshape(T, -1, D)
     kv = (a @ lp["wkv"].astype(dt)).reshape(T, 2 * Hk, D)
-    z = jax.nn.sigmoid((a @ lp["wg"].astype(dt)).astype(jnp.float32))
+    z = (jax.nn.sigmoid((a @ lp["wg"].astype(dt)).astype(jnp.float32))
+         if "wg" in lp else None)
+    if rope is None:
+        return q, kv[:, :Hk], kv[:, Hk:], z
     return _rope(q, *rope), _rope(kv[:, :Hk], *rope), kv[:, Hk:], z
 
 
 def _gated_out(lp, o, z, cfg):
-    """``concat_h(z_h o_h) W_o`` of ``o [T, H, D]``."""
-    o = (o.astype(jnp.float32) * z[..., None]).astype(cfg.dtype)
+    """``concat_h(z_h o_h) W_o`` of ``o [T, H, D]`` (``concat_h(o_h) W_o``
+    where ``z`` is None)."""
+    if z is not None:
+        o = (o.astype(jnp.float32) * z[..., None]).astype(cfg.dtype)
     return o.reshape(o.shape[0], -1) @ lp["wo"].astype(cfg.dtype)
 
 
@@ -477,6 +524,7 @@ def forward(params: Dict, ids, cfg: LagunaConfig, use_kernel: bool = False):
              SLIDING: (j <= i) & (j > i - cfg.sliding_window)}
 
     def layer(x, carry, lp, kind, slide_rank, full_rank, experts, in_stack):
+        r = x if cfg.router_before_attention else None
         a = _norm(x, lp["ln_in"], cfg)
         q, k, v, z = _project(lp, a, ropes[kind], cfg)
         o = _masked_sdpa(
@@ -486,7 +534,7 @@ def forward(params: Dict, ids, cfg: LagunaConfig, use_kernel: bool = False):
             jnp.broadcast_to(masks[kind], (B, S, S)))
         x = x + _gated_out(lp, o.reshape(T, -1, cfg.head_dim), z, cfg)
         f, _ = _ffn({**lp, **experts}, _norm(x, lp["ln_post"], cfg), real,
-                    cfg, use_kernel, in_stack)
+                    cfg, use_kernel, in_stack, r)
         return x + f, carry
 
     x, _ = _walk(params, cfg, x, (), layer)
@@ -694,6 +742,7 @@ def _paged_rows(params, cfg, tokens, starts, n_row, block_tables, pool,
                 tbl = lax.dynamic_slice_in_dim(block_tables, first, R, axis=1)
                 window, col = cfg.sliding_window, first + page % R
             phys = jnp.where(real, block_tables[row, col], 0)
+            r = x if cfg.router_before_attention else None
             q, k, v, z = _project(lp, _norm(x, lp["ln_in"], cfg),
                                   ropes[kind], cfg)
             pool, _, _ = _kv_store(pool, idx, phys, off, k, v)
@@ -701,7 +750,7 @@ def _paged_rows(params, cfg, tokens, starts, n_row, block_tables, pool,
                              window, use_kernel)
             x = x + _gated_out(lp, to_lanes(o), z, cfg)
             f, moe = _ffn({**lp, **experts}, _norm(x, lp["ln_post"], cfg),
-                          real, cfg, use_kernel, in_stack)
+                          real, cfg, use_kernel, in_stack, r)
             return x + f, (pool, counts.at[:4].add(moe).at[
                 jnp.array(_READ_AT[kind])].add(read[kind]))
 

@@ -110,6 +110,10 @@ class PanguUltraMoEConfig:
 
     # where the serving engine finds this family's paged entry points
     paged_family = "paddle_tpu.models.pangu_ultra_moe"
+    # the router's score and the experts' activation (:func:`route`,
+    # :func:`_routed_experts`); another family may set them otherwise
+    router_score = "sigmoid"
+    expert_activation = "silu"
 
     def __post_init__(self):
         if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
@@ -284,23 +288,29 @@ def _dense_ffn(m, wg, wu, wd, dt):
 
 def route(lp, m, cfg):
     """Float32 router on ``m [T, E]``: ``(ids [T, k], weights [T, k])``
-    over ALL ``n_routed_experts``."""
-    s = jax.nn.sigmoid(m.astype(jnp.float32) @
-                       lp["router"].astype(jnp.float32))
+    over ALL ``n_routed_experts``. Scores ``sigmoid`` or ``softmax`` of
+    the logits (``cfg.router_score``); the top-k by score, weighed
+    ``routed_scaling_factor * s / sum s`` (for the softmax that is the
+    softmax over the picks' logits)."""
+    z = m.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+    s = (jax.nn.softmax(z, -1) if cfg.router_score == "softmax"
+         else jax.nn.sigmoid(z))
     top, ids = lax.top_k(s, cfg.num_experts_per_tok)
     w = cfg.routed_scaling_factor * top / (top.sum(-1, keepdims=True) + 1e-20)
     return ids, w
 
 
-def _routed_experts(lp, m, real, cfg, use_kernel, layer=None):
+def _routed_experts(lp, m, real, cfg, use_kernel, layer=None,
+                    router_input=None):
     """The held experts' part of the routed sum on ``m [T, E]`` for the
     lanes ``real [T]``, and this call's counters ``[4]`` (the first four
-    of ``PAGED_COUNTERS``). With ``layer`` (a traced index), ``lp["w_gu"]``
-    and ``lp["w_down"]`` are a run's experts still stacked over its layers
+    of ``PAGED_COUNTERS``). The router reads ``router_input [T, E]`` where
+    given, else ``m``. With ``layer`` (a traced index), ``lp["w_gu"]`` and
+    ``lp["w_down"]`` are a run's experts still stacked over its layers
     and the grouped matmul reads layer ``layer`` of them in place."""
     from ..kernels.grouped_matmul import grouped_matmul
     T, k, Eh = m.shape[0], cfg.num_experts_per_tok, cfg.n_local_experts
-    ids, w = route(lp, m, cfg)
+    ids, w = route(lp, m if router_input is None else router_input, cfg)
     here = ((ids >= cfg.expert_offset) & (ids < cfg.expert_offset + Eh)
             & real[:, None])
     # sort the (token, pick) pairs by held expert; a pair that is not this
@@ -312,6 +322,7 @@ def _routed_experts(lp, m, real, cfg, use_kernel, layer=None):
     lane = (jnp.arange(T * k, dtype=jnp.int32) // k)[order]
     rows = m[lane]
     h = grouped_matmul(rows, lp["w_gu"], sizes, layer=layer, gated=True,
+                       activation=cfg.expert_activation,
                        use_kernel=use_kernel)
     y = grouped_matmul(h, lp["w_down"], sizes, layer=layer,
                        use_kernel=use_kernel)
@@ -323,14 +334,18 @@ def _routed_experts(lp, m, real, cfg, use_kernel, layer=None):
     return out.astype(m.dtype), counts
 
 
-def _ffn(lp, m, real, cfg, use_kernel, layer=None):
+def _ffn(lp, m, real, cfg, use_kernel, layer=None, router_input=None):
     """``(f [T, E], counters [4])`` of one layer's FFN, dense or expert by
-    what the layer holds (``layer``: see :func:`_routed_experts`)."""
+    what the layer holds, the shared expert added where it holds one
+    (``layer``, ``router_input``: see :func:`_routed_experts`)."""
     dt = cfg.dtype
     if "router" not in lp:
         return (_dense_ffn(m, lp["w_gate"], lp["w_up"], lp["w_down"], dt),
                 jnp.zeros((4,), jnp.int32))
-    routed, counts = _routed_experts(lp, m, real, cfg, use_kernel, layer)
+    routed, counts = _routed_experts(lp, m, real, cfg, use_kernel, layer,
+                                     router_input)
+    if "ws_gate" not in lp:
+        return routed, counts
     shared = _dense_ffn(m, lp["ws_gate"], lp["ws_up"], lp["ws_down"], dt)
     return shared + routed, counts
 

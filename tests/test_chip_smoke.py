@@ -504,6 +504,112 @@ def test_window_and_grouped_kernels_compile_for_v5e_without_a_chip(
             jax.jit(fn).lower(*args).compile()
 
 
+def _smallthinker_cell_cases():
+    """What SmallThinker's cell runs of the same kernels, read from its
+    configuration: both forms of the paged kernel on a pool of FOUR kv
+    heads (a page ``[16, 4, 128]`` bf16, fewer heads than a packed
+    sublane tile) in groups of 7, the window form on a ring of 265 entries
+    (a window of 4096, 16-17 cells of 256 a decode row), the full form on
+    a table of 1024; and the ReGLU grouped matmul (2560 x 768, 64 experts)
+    at a decode step's and a wave's (token, pick) pairs."""
+    from paddle_tpu.kernels import grouped_matmul, paged_attention
+    from paddle_tpu.models.laguna import _WAVE_ROWS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "smallthinker-21ba3b-d8.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    H, Hk, D = (c["num_attention_heads"], c["num_key_value_heads"],
+                c["head_dim"])
+    M, bs, chunk = eng["max_slots"], eng["block_size"], eng["prefill_chunk"]
+    window = c["sliding_window_size"]
+    ring = -(-(window + chunk) // bs) + 1
+    E, I, held = (c["hidden_size"], c["moe_ffn_hidden_size"],
+                  c["moe_num_primary_experts"])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    cases = []
+    for W, win in ((ring, window), (eng["max_model_len"] // bs, None)):
+        for Q in (1, chunk):
+            name = (f"paged_attention{'_window' if win else ''}_"
+                    f"{'q1' if Q == 1 else 'mq'} {H}/{Hk} heads")
+
+            def fn(q, k, v, tbl, sl, dl, Q=Q, win=win):
+                if Q == 1:
+                    return paged_attention(q[:, 0], k, v, tbl, sl,
+                                           layer=jnp.int32(1), window=win)
+                return paged_attention(q, k, v, tbl, sl, draft_lens=dl,
+                                       layer=jnp.int32(1), window=win)
+
+            cases.append((name, fn, lambda Q=Q, W=W: (
+                jnp.zeros((M, Q, H, D), bf),
+                jnp.zeros((2, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((2, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((M, W), i32), jnp.zeros((M,), i32),
+                jnp.zeros((M,), i32))))
+    for T in (M, _WAVE_ROWS * M):
+        rows = T * c["moe_num_active_primary_experts"]
+        for K, N, gated in ((E, 2 * I, True), (I, E, False)):
+            cases.append((
+                f"moe_grouped_matmul {rows} rows {K}x{N}",
+                lambda x, w, g, gated=gated: grouped_matmul(
+                    x, w, g, layer=jnp.int32(2), gated=gated,
+                    activation="relu", use_kernel=True),
+                lambda rows=rows, K=K, N=N: (
+                    jnp.zeros((rows, K), bf),
+                    jnp.zeros((6, held, K, N), bf),
+                    jnp.zeros((held,), i32))))
+    return cases
+
+
+SMALLTHINKER_CELL_CASES = ["paged_attention_window_q1 28/4 heads",
+                           "paged_attention_window_mq 28/4 heads",
+                           "paged_attention_q1 28/4 heads",
+                           "paged_attention_mq 28/4 heads",
+                           "moe_grouped_matmul 192 rows 2560x1536",
+                           "moe_grouped_matmul 192 rows 768x2560",
+                           "moe_grouped_matmul 6144 rows 2560x1536",
+                           "moe_grouped_matmul 6144 rows 768x2560"]
+
+
+@pytest.mark.parametrize("case", SMALLTHINKER_CELL_CASES)
+def test_four_kv_head_and_reglu_kernels_lower_for_tpu(case, monkeypatch):
+    """Both forms of the paged kernel on a four-kv-head pool and the ReGLU
+    grouped matmul through the Pallas TPU lowering at the cell's shapes."""
+    from paddle_tpu.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cases = {name: (fn, build)
+             for name, fn, build in _smallthinker_cell_cases()}
+    assert list(cases) == SMALLTHINKER_CELL_CASES
+    fn, build = cases[case]
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *jax.eval_shape(build))
+    text = exported.mlir_module()
+    assert "tpu_custom_call" in text, case
+    assert case.split()[0] in text        # the name a device trace shows
+
+
+@pytest.mark.slow
+def test_four_kv_head_and_reglu_kernels_compile_for_v5e_without_a_chip(
+        monkeypatch):
+    """The same through libtpu's whole compiler for a described v5e."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.kernels import dispatch
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:                    # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no TPU topology without a chip: {e}")
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    where = SingleDeviceSharding(topo.devices[0])
+    with jax.default_matmul_precision("default"):
+        for name, fn, build in _smallthinker_cell_cases():
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=where),
+                jax.eval_shape(build))
+            jax.jit(fn).lower(*args).compile()
+
+
 def test_preset_is_the_738m_model():
     """The smoke's model is the one with a chip history: 738M parameters
     at LLaMA-7B's shape ratios, kernels and remat on."""
