@@ -203,11 +203,11 @@ def _paged_operands(key, M, Q, H, Hk, D, bs, W, quant, dtype):
     ops = {"q": q if Q > 1 else q[:, 0], "tbl": jnp.asarray(tbl, jnp.int32),
            "sl": jnp.asarray(sl), "dl": jnp.asarray(dl)}
     for name, kx in (("k", kk), ("v", kv)):
-        x = jax.random.normal(kx, (N, bs, Hk, D), jnp.float32)
+        x = jax.random.normal(kx, (N, Hk, bs, D), jnp.float32)
         if quant:
             ops[name], ops[name + "s"] = _kv_quantize(x)
         else:
-            ops[name] = jnp.where(owned[:, :, None, None], x,
+            ops[name] = jnp.where(owned[:, None, :, None], x,
                                   jnp.nan).astype(dtype)
     return ops
 
@@ -310,7 +310,7 @@ def kernel_cases(cfg, batch, seq, serving_config):
     def paged_ref(o):
         multi = o["q"].ndim == 4
         q = o["q"] if multi else o["q"][:, None]
-        Q, Hk = q.shape[1], o["k"].shape[-2]
+        Q, Hk = q.shape[1], o["k"].shape[-3]
         pz = {"k": o["k"], "v": o["v"]}
         if "ks" in o:
             pz.update(k_scale=o["ks"], v_scale=o["vs"])

@@ -1,7 +1,7 @@
 """Paged KV cache — host-side block accounting over the device block pool.
 
 The PagedAttention idea (vLLM) recast for the XLA serving stack: the device
-holds ONE physical block pool ``{"k","v": [L, num_blocks, block_size, Hk,
+holds ONE physical block pool ``{"k","v": [L, num_blocks, Hk, block_size,
 D]}`` (:func:`paddle_tpu.models.generation.init_paged_pool`); a sequence
 owns an ordered list of physical blocks recorded in its slot's row of the
 block-table matrix, and the compiled decode step gathers exactly those

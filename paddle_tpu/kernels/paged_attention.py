@@ -26,33 +26,33 @@ the real tokens** — pages a slot holds, query rows a slot carries.
   ``[M, W]`` block table and ``[M]`` lengths ride in as scalar-prefetch
   operands; the K and V pools stay in HBM (``memory_space=ANY``) and the
   kernel copies each live page ``tbl[m, c * P + i]`` — the engine's whole
-  ``(bs, Hk, D)`` block, one copy serving every kv head — into a two-slot
+  ``(Hk, bs, D)`` block, one copy serving every kv head — into a two-slot
   VMEM buffer, starting cell ``c + 1``'s copies before it computes cell
   ``c``. The ``[slots, W*bs, ...]`` gather is never materialized in HBM.
-  The pools may be EVERY layer's, ``[L, N, bs, Hk, D]``, with the layer a
+  The pools may be EVERY layer's, ``[L, N, Hk, bs, D]``, with the layer a
   further scalar-prefetch operand (``pool.at[layer, blk]`` is the copy's
   source): a caller whose layer scan carries the whole pool hands it over
   as it is, and no layer's slab is ever sliced out (by the pool's rank
   alone: one kernel, one more index).
 * **One matmul pair a kv head a cell a query tile, and a cell's own work
   once a cell.** The kv heads are a static loop inside the cell; a head
-  stacks its ``P * bs`` rows out of the resident pages (a page is
-  ``(bs, Hk, D)``, so a head's rows are read one sublane-strided row at a
-  time) and does ONE score matmul, ONE value matmul and ONE online-softmax
-  update (running max ``m``, normalizer ``l``, weighted values ``acc``,
-  merged with ``alpha = exp(m_prev - m_cur)`` — the sequential spelling
-  of split-K, as in ``flash_attention.py``'s forward kernel). A row of ONE
-  query tile (a decode row, the ``Q = 1`` form) stacks the rows in
-  registers as it goes. A chunk row of SEVERAL sub-tiles does whatever the
-  cell and the head alone decide ONCE a cell: every head's K and V rows
-  into a head-major VMEM scratch ``[Hk, P * bs, D]``, V contained (below)
-  and only in a cell that holds a position to contain; its sub-tiles then
-  read them contiguously, all heads of a sub-tile in one unrolled run (the
-  compiler overlaps one head's matmuls with the next one's softmax: a
-  branch or a loop INSIDE a head's work costs more than the stacking it
-  saves, PERF.md §6, PR 34). What the row alone decides is done once a
-  slot: each tile row's query offset, capped at ``dl``, is divided out of
-  its row index when the accumulators are cleared (``qpos``), and a
+  stacks its ``P * bs`` rows out of the resident pages (the pool is
+  HEAD-MAJOR, a page ``(Hk, bs, D)``: a head's rows of a page are one
+  whole ``(bs, D)`` tile) and does ONE score matmul, ONE value matmul and
+  ONE online-softmax update (running max ``m``, normalizer ``l``, weighted
+  values ``acc``, merged with ``alpha = exp(m_prev - m_cur)`` — the
+  sequential spelling of split-K, as in ``flash_attention.py``'s forward
+  kernel). A row of ONE query tile (a decode row, the ``Q = 1`` form)
+  reads the rows as it goes. A chunk row of SEVERAL sub-tiles does
+  whatever the cell and the head alone decide ONCE a cell: every head's K
+  and V rows into a head-major VMEM scratch ``[Hk, P * bs, D]``, V
+  contained (below) and only in a cell that holds a position to contain;
+  its sub-tiles then read them contiguously, all heads of a sub-tile in
+  one unrolled run (the compiler overlaps one head's matmuls with the next
+  one's softmax: a branch or a loop INSIDE a head's work costs more than
+  the stacking it saves, PERF.md §6). What the row alone decides is done
+  once a slot: each tile row's query offset, capped at ``dl``, is divided
+  out of its row index when the accumulators are cleared (``qpos``), and a
   cell's masks add ``sl`` to it.
 * **GQA grouped IN-KERNEL.** Queries arrive as ``[M, Hk, Q * G, D]`` (row
   ``q * G + g`` of kv head ``kh`` is query offset ``q``'s head ``kh * G +
@@ -78,8 +78,8 @@ the real tokens** — pages a slot holds, query rows a slot carries.
   ``models.generation.init_paged_pool``) stream int8 pages — the capacity
   AND bandwidth win at once — and dequantize as ``(q · kᵀ) * ks`` and ``(p
   * vs) · v``: the scale of KV position ``j`` multiplies column ``j``. A
-  dense dequantized pool never exists. The scale planes are ``[N, bs,
-  Hk]`` and a page's ``(bs, Hk)`` tile is narrower than a kernel's own
+  dense dequantized pool never exists. The scale planes are ``[N, Hk,
+  bs]`` and a page's ``(Hk, bs)`` tile is narrower than a kernel's own
   copy may slice from HBM, so the caller-side wrapper gathers them by
   table into ``[M, cells, Hk, P * bs]`` (1/32 of the pool's bytes; the
   one dense thing left — revisit with the scale layout, PERF.md §7).
@@ -230,7 +230,7 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     · v`` are the dequantized sums with the int8 values, exact in either
     float type, as operands.
 
-    ``layered``: the pools are every layer's, ``[L, N, bs, Hk, D]``, and
+    ``layered``: the pools are every layer's, ``[L, N, Hk, bs, D]``, and
     a last scalar-prefetch operand names the layer whose pages are copied
     (``pool.at[layer, blk]``); nothing else differs.
 
@@ -302,12 +302,13 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
 
     def head_rows(buf, slot, h):
         """Head ``h``'s rows of the ``P`` pages in buffer slot ``slot``,
-        stacked in the cell's order: ``[P * bs, D]`` of ``kv_dtype``."""
-        parts = [buf[slot, i, :, h, :] for i in range(P)]
-        x = parts[0] if P == 1 else jnp.concatenate(parts, axis=0)
+        stacked in the cell's order: ``[P * bs, D]`` of ``kv_dtype``. A
+        page is ``(Hk, bs, D)``, so the head's rows are ``P`` whole
+        ``(bs, D)`` tiles, read at once."""
+        x = buf[slot, :, h]                              # [P, bs, D]
         if quant:                      # int8 -> float goes through fp32
             x = x.astype(jnp.float32)
-        return x.astype(kv_dtype)
+        return x.astype(kv_dtype).reshape(C, x.shape[-1])
 
     def row_pos(r0, nr):
         """Query offset of each of the ``nr`` tile rows from ``r0`` on
@@ -475,14 +476,15 @@ def _kernel(*refs, bs, W, P, scale, quant, layered, Hk, G, Q, R0, TQ,
     over_rows(finalize)
 
 
-def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
+def _vmem_bytes(Hk, QG, D, bs, C, cells, rows, q_dtype, out_dtype,
                 pool_dtype, kv_dtype) -> int:
     """Scoped-VMEM request for one grid step, from the shapes: the query
     and output tiles (and an int8 pool's by-cell scales) are
     double-buffered by the pipeline, each padded to its dtype's vector
     tile; the kernel's own two-slot K and V page buffers of ``C = P * bs``
-    positions and the head-major K and V rows a chunk row stacks once a
-    cell; the three accumulators and the rows' query offsets, resident
+    positions (``(Hk, bs, D)`` pages: a head's ``bs`` rows padded to the
+    pool dtype's tile) and the head-major K and V rows a chunk row stacks
+    once a cell; the three accumulators and the rows' query offsets, resident
     (``m``/``l`` and the offsets pad their one column to a 128-lane
     tile); the head loop's temporaries, sized by the widest query tile a
     cell runs (``rows = max(R0, TQ)`` against ``C`` positions) and no
@@ -496,7 +498,8 @@ def _vmem_bytes(Hk, QG, D, C, cells, rows, q_dtype, out_dtype,
                                  pad(QG, out_dtype) * isz(out_dtype))
     if jnp.dtype(pool_dtype) == jnp.int8:
         tiles += 2 * 2 * cells * pad(Hk, jnp.float32) * lanes(C) * 4
-    kv = 2 * 2 * C * pad(Hk, pool_dtype) * lanes(D) * isz(pool_dtype)
+    kv = 2 * 2 * (C // bs) * Hk * pad(bs, pool_dtype) * lanes(D) * \
+        isz(pool_dtype)
     kv += 2 * Hk * pad(C, kv_dtype) * lanes(D) * isz(kv_dtype)
     scratch = Hk * pad(QG, jnp.float32) * (lanes(D) + 2 * _LANES) * 4
     scratch += pad(QG, jnp.int32) * _LANES * 4
@@ -520,15 +523,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     seq_lens[m] + i`` (committed KV plus the in-pass draft prefix). Rows
     ``i > draft_lens[m]`` are not computed and come back as ZEROS: no
     caller reads them. ``k_pool``/``v_pool``
-    ``[N, bs, Hk, D]`` — ONE layer's physical block pool (fp, or int8 with
-    ``k_scale``/``v_scale [N, bs, Hk]`` fp32 per-token-per-head scales) —
-    or, told apart by rank, EVERY layer's, ``[L, N, bs, Hk, D]`` (scales
-    ``[L, N, bs, Hk]``) with ``layer``, an int32 scalar that may be
-    traced: the kernel's page copies index the layer, so a caller that
-    holds the whole pool (a layer scan's carry) never slices a layer out
-    of it. ``block_tables [M, W]`` int32 — slot ``m``'s KV position ``j``
-    lives in physical block ``block_tables[m, j // bs]`` at offset ``j %
-    bs``;
+    ``[N, Hk, bs, D]`` — ONE layer's physical block pool, head-major (fp,
+    or int8 with ``k_scale``/``v_scale [N, Hk, bs]`` fp32
+    per-token-per-head scales) — or, told apart by rank, EVERY layer's,
+    ``[L, N, Hk, bs, D]`` (scales ``[L, N, Hk, bs]``) with ``layer``, an
+    int32 scalar that may be traced: the kernel's page copies index the
+    layer, so a caller that holds the whole pool (a layer scan's carry)
+    never slices a layer out of it. ``block_tables [M, W]`` int32 — slot
+    ``m``'s KV position ``j`` lives in physical block ``block_tables[m, j
+    // bs]`` at offset ``j % bs``;
     ``seq_lens [M]`` int32 — slot ``m`` attends positions ``j <=
     seq_lens[m]`` (its new token's KV was just scattered at ``seq_lens[m]``).
     Table entries past a slot's window are never read. Returns
@@ -561,10 +564,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     layered = k_pool.ndim == 5
     if layered != (layer is not None):
         raise ValueError(
-            "paged_attention: a pool of every layer [L, N, bs, Hk, D] is "
-            "read at `layer`, one layer's [N, bs, Hk, D] without it; got "
+            "paged_attention: a pool of every layer [L, N, Hk, bs, D] is "
+            "read at `layer`, one layer's [N, Hk, bs, D] without it; got "
             f"a rank-{k_pool.ndim} pool and layer={layer!r}")
-    bs, Hk = k_pool.shape[-3:-1]
+    Hk, bs = k_pool.shape[-3:-1]
     W = block_tables.shape[1]
     if H % Hk:
         raise ValueError(f"paged_attention: {H} query heads not divisible "
@@ -604,21 +607,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     # — every index map takes them positionally after the grid indices
     scalars = (tbl, sl, jnp.asarray(draft_lens, jnp.int32)) if multi \
         else (tbl, sl)
-    # a kernel's own copy slices HBM in whole 32-bit rows of the (Hk, D)
-    # plane. A pool with fewer kv heads than one such row packs (bf16
-    # under 2, int8 under 4: a TP shard of a GQA model) is padded up to it
-    # here — a copy of the shard's one layer a call, the price of that
-    # shape (of a whole pool the layer is sliced out first, so the price
-    # stays a layer's)
-    narrow = -Hk % max(1, 4 // k_pool.dtype.itemsize)
-    if narrow:
-        if layered:
-            k_pool, v_pool = (jax.lax.dynamic_index_in_dim(
-                x, layer, 0, keepdims=False) for x in (k_pool, v_pool))
-        k_pool, v_pool = (jnp.pad(x, ((0, 0), (0, 0), (0, narrow), (0, 0)))
-                          for x in (k_pool, v_pool))
-    in_kernel = layered and not narrow     # the kernel indexes the layer
-    if in_kernel:
+    if layered:                        # the kernel indexes the layer
         scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
 
     def qmap(m, *_):
@@ -633,7 +622,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
                 pl.BlockSpec(memory_space=pl.ANY)]
     ops = [qg, k_pool, v_pool]
     if quant:
-        # a page's scale tile is (bs, Hk) fp32, narrower than anything a
+        # a page's scale tile is (Hk, bs) fp32, narrower than anything a
         # kernel's own copy may slice out of HBM (Mosaic wants 128 lanes),
         # so the scales — 1/32 of the pool's bytes — are gathered here, by
         # table, into the layout a cell reads: [M, cells, Hk, P * bs]
@@ -642,7 +631,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
         def by_cell(plane):
             pages = plane[layer, whole_cells] if layered \
                 else plane[whole_cells]
-            return pages.reshape(M, cells, P * bs, Hk).transpose(0, 1, 3, 2)
+            return pages.reshape(M, cells, P, Hk, bs).transpose(
+                0, 1, 3, 2, 4).reshape(M, cells, Hk, P * bs)
         in_specs += [pl.BlockSpec((1, cells, Hk, P * bs), qmap)] * 2
         ops += [by_cell(k_scale), by_cell(v_scale)]
 
@@ -666,14 +656,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, W=W, P=P, scale=scale,
-                          quant=quant, layered=in_kernel, Hk=Hk, G=G, Q=Q,
+                          quant=quant, layered=layered, Hk=Hk, G=G, Q=Q,
                           R0=R0, TQ=TQ, kv_dtype=kv_dtype,
                           window=None if window is None else int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, Hk, QG, D), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=_vmem_bytes(Hk, QG, D, P * bs, cells,
+            vmem_limit_bytes=_vmem_bytes(Hk, QG, D, bs, P * bs, cells,
                                          max(R0, TQ), q.dtype, out_dtype,
                                          k_pool.dtype, kv_dtype)),
         interpret=_interpret(),

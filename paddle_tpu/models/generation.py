@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -634,7 +635,7 @@ def _local_heads(cfg: LlamaConfig, pool: Dict) -> Tuple[int, int]:
     the local query-head count follows from the pool shape and the config
     keeps its global head counts (``cfg.head_dim`` stays correct, being
     derived from the UNCHANGED hidden_size / num_attention_heads)."""
-    Hk = pool["k"].shape[3]
+    Hk = pool["k"].shape[2]
     return Hk * (cfg.num_attention_heads // cfg.kv_heads), Hk
 
 
@@ -672,15 +673,15 @@ def health(counters: Dict, cfg: LlamaConfig) -> Dict:
 
 def paged_pool_specs(pool: Dict, mesh, axis: str = "tp") -> Dict:
     """PartitionSpecs splitting every pool leaf's kv-heads axis over mesh
-    ``axis``: K/V ``[L, N, bs, Hk, D]`` and scale ``[L, N, bs, Hk]``
-    leaves both shard dim 3, so int8 pools shard k/v and their scale
+    ``axis``: K/V ``[L, N, Hk, bs, D]`` and scale ``[L, N, Hk, bs]``
+    leaves both shard dim 2, so int8 pools shard k/v and their scale
     planes identically and a shard's scales always describe its own
     blocks. Block ids stay GLOBAL — tables and slot operands replicate,
     only pool bytes split. Indivisible head counts raise the structured
     :func:`~paddle_tpu.distributed.sharding.shard_dim_spec` error naming
     the leaf."""
     from ..distributed.sharding import shard_dim_spec
-    return {name: shard_dim_spec(leaf.shape, mesh, axis, dim=3,
+    return {name: shard_dim_spec(leaf.shape, mesh, axis, dim=2,
                                  name=f"paged_pool.{name}")
             for name, leaf in pool.items()}
 
@@ -688,18 +689,21 @@ def paged_pool_specs(pool: Dict, mesh, axis: str = "tp") -> Dict:
 def init_paged_pool(cfg: LlamaConfig, num_blocks: int, block_size: int,
                     dtype=None, kv_quant=None, mesh=None,
                     tp_axis: str = "tp") -> Dict:
-    """Physical KV block pool ``{"k","v": [L, num_blocks, block_size, Hk,
+    """Physical KV block pool ``{"k","v": [L, num_blocks, Hk, block_size,
     D]}`` shared by every sequence the serving engine runs (PagedAttention
-    layout): a sequence holds only the blocks its block table points at,
-    so HBM scales with tokens actually in flight instead of
-    ``max_slots * max_seq``. Physical block 0 is reserved as the NULL
-    block — the scatter target for masked lanes (padded prefill positions,
-    retired slots) — and is never handed out by the block manager
-    (``inference.serving.paged_cache``).
+    layout, HEAD-MAJOR inside a block: a kv head's ``block_size`` rows of
+    a block are one contiguous ``(block_size, D)`` tile, which is what the
+    paged kernel reads; the block stays axis 1, so everything that moves
+    whole blocks indexes ``[:, b]``): a sequence holds only the blocks its
+    block table points at, so HBM scales with tokens actually in flight
+    instead of ``max_slots * max_seq``. Physical block 0 is reserved as
+    the NULL block — the scatter target for masked lanes (padded prefill
+    positions, retired slots) — and is never handed out by the block
+    manager (``inference.serving.paged_cache``).
 
     ``kv_quant="int8"`` stores K/V as int8 with PER-TOKEN-PER-HEAD fp32
-    scales alongside (``{"k","v": int8, "k_scale","v_scale": [L, N, bs,
-    Hk]}``): each KV entry quantizes independently at write time, so
+    scales alongside (``{"k","v": int8, "k_scale","v_scale": [L, N, Hk,
+    bs]}``): each KV entry quantizes independently at write time, so
     incremental decode scatters never re-quantize a block, preemption
     recompute reproduces bit-identical int8 entries, and the prefix cache
     shares quantized blocks exactly like fp ones (content keys hash token
@@ -721,7 +725,7 @@ def init_paged_pool(cfg: LlamaConfig, num_blocks: int, block_size: int,
     from .llama import KV_QUANT_MODES, validate_quant_mode
     validate_quant_mode(kv_quant, KV_QUANT_MODES, "kv_quant")
     dt = dtype if dtype is not None else cfg.dtype
-    shape = (cfg.num_hidden_layers, num_blocks, block_size, cfg.kv_heads,
+    shape = (cfg.num_hidden_layers, num_blocks, cfg.kv_heads, block_size,
              cfg.head_dim)
     if kv_quant == "int8":
         pool = {"k": jnp.zeros(shape, jnp.int8),
@@ -770,27 +774,62 @@ def _kv_quantize(x):
     return q.astype(jnp.int8), scale
 
 
-def _kv_store(p: Dict, layer, phys, off, k, v):
-    """Scatter freshly computed ``k``/``v [..., Hk, D]`` into the WHOLE
-    pool at ``(layer, phys, off)`` (quantizing when the pool is int8): an
-    in-place write of the new entries into leaves ``[L, N, bs, Hk, D]``
+def _kv_store(p: Dict, layer, phys, off, k, v, rows: int):
+    """Write freshly computed ``k``/``v [..., Hk, D]`` into the WHOLE pool,
+    entry ``i`` at ``(layer, phys[i], :, off[i])`` (quantizing when the
+    pool is int8): an in-place write into leaves ``[L, N, Hk, bs, D]``
     that a layer scan carries, never a layer's slab sliced out and put
     back. Returns ``(new_pool, k_attend, v_attend)`` — the attend pair is
     what LATER READS of these entries will observe (identity for fp pools,
     the int8 round-trip for quantized ones), so the batched prefill can
     attend exactly the values decode will gather back and every engine
-    path sees ONE consistent view of a KV entry."""
-    at = (layer, phys, off)
+    path sees ONE consistent view of a KV entry.
+
+    The entries are ``rows`` runs of consecutive positions, one a row
+    (row-major, as every paged program lays out its lanes), and an entry
+    with ``phys == 0`` (the null block: a masked lane) is not written.
+    The pool is written a BLOCK at a time, one for each run of entries
+    that share a block: its ``(Hk, bs, D)`` is read, the run's rows put
+    in and the block written back whole. An entry is ``Hk`` rows of ``D``
+    a head apart: as a scatter of rows it costs about five times the
+    blocks on the TPU, and as one ``(Hk, D)`` window the compiler lays the
+    pool out head-minor and copies it whole every layer (PERF.md §6)."""
+    lead = phys.shape
+    T = math.prod(lead)
+    bs, N = p["k"].shape[3], p["k"].shape[1]
+    phys = phys.reshape(T)
+    off = off.reshape(T)
+    # the blocks a run of n consecutive positions touches: n // bs + 2
+    runs = min(T, T // bs + 2 * rows)
+    prev = jnp.concatenate([jnp.full((1,), -1, phys.dtype), phys[:-1]])
+    first = jnp.nonzero((phys != 0) & (phys != prev), size=runs,
+                        fill_value=T)[0]
+    lane = jnp.minimum(first, T - 1)
+    block = phys[lane]
+    j = jnp.arange(bs, dtype=jnp.int32)
+    src = lane[:, None] + j[None, :] - off[lane][:, None]      # [runs, bs]
+    at = jnp.clip(src, 0, T - 1)
+    mine = (src >= 0) & (src < T) & (phys[at] == block[:, None]) & \
+        (off[at] == j[None, :])
+    dest = jnp.where(first < T, block, N)      # past the pool: dropped
+
+    def put(leaf, x):
+        """``x [*lead, Hk, ...]`` into ``leaf [L, N, Hk, bs, ...]``."""
+        x = x.reshape((T,) + x.shape[len(lead):]).astype(leaf.dtype)
+        new = jnp.swapaxes(x[at], 1, 2)                 # [runs, Hk, bs, ...]
+        mask = mine.reshape((runs, 1, bs) + (1,) * (new.ndim - 3))
+        whole = jnp.where(mask, new, leaf[layer, block])
+        return leaf.at[layer, dest].set(whole, mode="drop")
+
     if "k_scale" in p:
         qk, sk = _kv_quantize(k)
         qv, sv = _kv_quantize(v)
-        out = {"k": p["k"].at[at].set(qk), "v": p["v"].at[at].set(qv),
-               "k_scale": p["k_scale"].at[at].set(sk),
-               "v_scale": p["v_scale"].at[at].set(sv)}
+        out = {"k": put(p["k"], qk), "v": put(p["v"], qv),
+               "k_scale": put(p["k_scale"], sk),
+               "v_scale": put(p["v_scale"], sv)}
         return out, qk.astype(jnp.float32) * sk[..., None], \
             qv.astype(jnp.float32) * sv[..., None]
-    return {"k": p["k"].at[at].set(k.astype(p["k"].dtype)),
-            "v": p["v"].at[at].set(v.astype(p["v"].dtype))}, k, v
+    return {"k": put(p["k"], k), "v": put(p["v"], v)}, k, v
 
 
 def _kv_gather(p: Dict, layer, block_tables, B: int, C: int, Hk: int,
@@ -800,11 +839,14 @@ def _kv_gather(p: Dict, layer, block_tables, B: int, C: int, Hk: int,
     gather — the XLA FALLBACK path (``_masked_sdpa`` consumes the result).
     The Pallas kernel (``kernels.paged_attention``) never materializes
     this."""
-    kk = p["k"][layer, block_tables].reshape(B, C, Hk, D)
-    vv = p["v"][layer, block_tables].reshape(B, C, Hk, D)
+    def logical(x):                    # [B, W, Hk, bs, ...] -> [B, C, Hk, ...]
+        x = jnp.swapaxes(x, 2, 3)
+        return x.reshape((B, C, Hk) + x.shape[4:])
+    kk = logical(p["k"][layer, block_tables])
+    vv = logical(p["v"][layer, block_tables])
     if "k_scale" in p:
-        ks = p["k_scale"][layer, block_tables].reshape(B, C, Hk)
-        vs = p["v_scale"][layer, block_tables].reshape(B, C, Hk)
+        ks = logical(p["k_scale"][layer, block_tables])
+        vs = logical(p["v_scale"][layer, block_tables])
         kk = kk.astype(jnp.float32) * ks[..., None]
         vv = vv.astype(jnp.float32) * vs[..., None]
     return kk, vv
@@ -857,7 +899,7 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
     B, Sb = ids.shape
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
     D = cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     W = block_tables.shape[1]
     dt = cfg.dtype
     cos, sin = rope_cos_sin(Sb, D, cfg.rope_theta)
@@ -887,7 +929,7 @@ def paged_prefill(params: Dict, cfg: LlamaConfig, ids, prompt_lens,
         v = v.reshape(B, Sb, Hk, D)
         q = _rope(q, cos, sin, False)
         k = _rope(k, cos, sin, False)
-        pool, ka, va = _kv_store(pool, layer, phys, off, k, v)
+        pool, ka, va = _kv_store(pool, layer, phys, off, k, v, B)
         o = _masked_sdpa(q, ka, va, kv_mask)
         m = _merge_heads(o, cfg).astype(dt)
         d = _mm(m, lp, "wo", dt)
@@ -931,7 +973,7 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
     M = tokens.shape[0]
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
     D = cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     W = block_tables.shape[1]
     C = W * bs
     dt = cfg.dtype
@@ -963,7 +1005,8 @@ def paged_decode_step(params: Dict, cfg: LlamaConfig, tokens, seq_lens,
         v = v.reshape(M, 1, Hk, D)
         q = _rope(q, cos, sin, False)
         k = _rope(k, cos, sin, False)
-        pool, _, _ = _kv_store(pool, layer, phys, off, k[:, 0], v[:, 0])
+        pool, _, _ = _kv_store(pool, layer, phys, off, k[:, 0], v[:, 0],
+                               M)
         if use_kernel:
             from ..kernels.paged_attention import paged_attention
             o = paged_attention(q[:, 0], pool["k"], pool["v"], block_tables,
@@ -1141,7 +1184,7 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
     M, Q = tokens.shape
     H, Hk = _local_heads(cfg, pool)    # the shard's head slice under TP
     D = cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     W = block_tables.shape[1]
     C = W * bs
     dt = cfg.dtype
@@ -1209,7 +1252,7 @@ def _paged_multiquery_forward(params: Dict, cfg: LlamaConfig, tokens,
             q = _rope(q.reshape(1, Tw, H, D), cos, sin, False)
             k = _rope(k.reshape(1, Tw, Hk, D), cos, sin, False)
             pool, _, _ = _kv_store(pool, layer, phys, off, k,
-                                   v.reshape(1, Tw, Hk, D))
+                                   v.reshape(1, Tw, Hk, D), M)
             if packed:
                 # row m's lanes are contiguous in the wave: M slices of Qa
                 # lanes (past the wave's end: zeros no one reads)

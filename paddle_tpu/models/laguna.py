@@ -52,12 +52,12 @@ groups of as many layers as there are full ones, each bounded by a RING of
 R``), which holds ``sliding_window + window_chunk`` tokens whatever the
 sequence's length. Equal groups make a physical block cost the same bytes
 whoever takes it, so one allocator serves all: the pool is ``{"k", "v":
-[layers a group, N, bs, Hk, D]}``, axis 0 the layer's index INSIDE its
-group, and a table row holds the groups' entries side by side, ``[full |
-ring 1 | ring 2 ...]``. A full layer calls ``kernels.paged_attention`` as
-the dense family does; a sliding layer its window-bounded form on its
-group's ring, which copies the pages from the first query's lowest
-position on and no other.
+[layers a group, N, Hk, bs, D]}`` (head-major, as the dense family's),
+axis 0 the layer's index INSIDE its group, and a table row holds the
+groups' entries side by side, ``[full | ring 1 | ring 2 ...]``. A full
+layer calls ``kernels.paged_attention`` as the dense family does; a
+sliding layer its window-bounded form on its group's ring, which copies
+the pages from the first query's lowest position on and no other.
 
 **Paged serving.** One forward over query LANES (:func:`_paged_rows`), as
 ``generation._paged_multiquery_forward`` has it: everything a token is
@@ -588,13 +588,13 @@ def mixed_lane_budget(cfg: LagunaConfig, max_slots: int) -> int:
 
 def init_paged_pool(cfg: LagunaConfig, num_blocks: int, block_size: int,
                     dtype=None, kv_quant=None, mesh=None) -> Dict:
-    """``{"k", "v": [layers a group, num_blocks, block_size, Hk, D]}``:
+    """``{"k", "v": [layers a group, num_blocks, Hk, block_size, D]}``:
     block ``b`` holds 16 positions of every layer of WHICHEVER group took
     it. Block 0 is the null block, as in every pool of the engine."""
     if kv_quant is not None or mesh is not None:
         raise ValueError("the laguna pool is neither quantized nor sharded")
     dt = dtype if dtype is not None else cfg.dtype
-    shape = (cfg.n_full, num_blocks, block_size, cfg.num_key_value_heads,
+    shape = (cfg.n_full, num_blocks, cfg.num_key_value_heads, block_size,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
@@ -639,7 +639,7 @@ def _attend_rows(q, pool, idx, tbl, start, dl, window, use_kernel):
                                    layer=idx, window=window)[:, None]
         return paged_attention(q, pool["k"], pool["v"], tbl, start,
                                draft_lens=dl, layer=idx, window=window)
-    bs, Hk = pool["k"].shape[2:4]
+    Hk, bs = pool["k"].shape[2:4]
     C = tbl.shape[1] * bs
     kk, vv = _kv_gather(pool, idx, tbl, M, C, Hk, D)
     edge = start[:, None] + jnp.minimum(jnp.arange(Q)[None, :], dl[:, None])
@@ -668,7 +668,7 @@ def _paged_rows(params, cfg, tokens, starts, n_row, block_tables, pool,
     positions, which is what a ring is sized for."""
     M, Q = tokens.shape
     dt, D = cfg.dtype, cfg.head_dim
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     R, G = cfg.ring_blocks(bs), cfg.window_groups
     Wf = block_tables.shape[1] - G * R           # the full group's entries
     packed = wave_lanes is not None
@@ -745,7 +745,7 @@ def _paged_rows(params, cfg, tokens, starts, n_row, block_tables, pool,
             r = x if cfg.router_before_attention else None
             q, k, v, z = _project(lp, _norm(x, lp["ln_in"], cfg),
                                   ropes[kind], cfg)
-            pool, _, _ = _kv_store(pool, idx, phys, off, k, v)
+            pool, _, _ = _kv_store(pool, idx, phys, off, k, v, M)
             o = _attend_rows(to_rows(q), pool, idx, tbl, att_start, att_dl,
                              window, use_kernel)
             x = x + _gated_out(lp, to_lanes(o), z, cfg)

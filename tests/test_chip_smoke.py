@@ -172,8 +172,8 @@ def _sat_cell_cases(cases, more_chunks=(), shards=(1,)):
     the cell's own configuration: the decode step and the mixed step at
     the cell's ``prefill_chunk`` and at ``more_chunks``; for ``shards``
     past 1, one TP shard's heads of the mixed step, on a bf16 and an int8
-    pool (at 8 shards a pool holds ONE kv head: narrower than the
-    kernel's own copies can slice)."""
+    pool (at 8 shards a pool holds ONE kv head: a page of one ``(bs, D)``
+    tile, which the kernel copies whole)."""
     with open(os.path.join(REPO, "benchmark", "configs",
                            "mistral-7b-v0.3-d16.json")) as f:
         c = json.load(f)
@@ -433,8 +433,8 @@ def _window_cell_cases():
 
             cases.append((name, fn, lambda Q=Q, W=W, kind=kind: (
                 jnp.zeros((M, Q, heads[kind], D), bf),
-                jnp.zeros((group, eng["num_blocks"], bs, Hk, D), bf),
-                jnp.zeros((group, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((group, eng["num_blocks"], Hk, bs, D), bf),
+                jnp.zeros((group, eng["num_blocks"], Hk, bs, D), bf),
                 jnp.zeros((M, W), i32), jnp.zeros((M,), i32),
                 jnp.zeros((M,), i32))))
     for T in (M, _WAVE_ROWS * M):
@@ -541,8 +541,8 @@ def _smallthinker_cell_cases():
 
             cases.append((name, fn, lambda Q=Q, W=W: (
                 jnp.zeros((M, Q, H, D), bf),
-                jnp.zeros((2, eng["num_blocks"], bs, Hk, D), bf),
-                jnp.zeros((2, eng["num_blocks"], bs, Hk, D), bf),
+                jnp.zeros((2, eng["num_blocks"], Hk, bs, D), bf),
+                jnp.zeros((2, eng["num_blocks"], Hk, bs, D), bf),
                 jnp.zeros((M, W), i32), jnp.zeros((M,), i32),
                 jnp.zeros((M,), i32))))
     for T in (M, _WAVE_ROWS * M):

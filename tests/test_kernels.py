@@ -302,7 +302,7 @@ class TestPagedAttention:
         from paddle_tpu.models.generation import _kv_gather
         from paddle_tpu.models.llama import _masked_sdpa
         M = q.shape[0]
-        N, bs, Hk, D = pool["k"].shape
+        N, Hk, bs, D = pool["k"].shape
         C = tbl.shape[1] * bs
         kk, vv = _kv_gather({n: a[None] for n, a in pool.items()}, 0, tbl,
                             M, C, Hk, D)     # a pool of one layer
@@ -324,8 +324,8 @@ class TestPagedAttention:
         W = int(rng.integers(2, 5))
         N = M * W + 3                            # slack blocks stay free
         q = jnp.asarray(rng.standard_normal((M, Hk * G, D)), jnp.float32)
-        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
-        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
+        kf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
+        vf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
         # ragged lengths pinned around block boundaries: the off-by-one
         # regime where a mask bug shows
         cap = W * bs - 1
@@ -386,8 +386,8 @@ class TestPagedAttention:
         rng = np.random.default_rng(7)
         M, H, Hk, D, bs, W, N = 2, 4, 2, 8, 4, 3, 8
         q = jnp.asarray(rng.standard_normal((M, H, D)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
         tbl = jnp.asarray([[1, 2, 0], [3, 4, 5]], jnp.int32)
         sl = jnp.asarray([5, 9], jnp.int32)
         base = paged_attention(q, k, v, tbl, sl)
@@ -397,8 +397,8 @@ class TestPagedAttention:
             for i, b in enumerate(blocks):
                 for off in range(bs):
                     if i * bs + off > s:
-                        k2 = k2.at[b, off].set(jnp.nan)
-                        v2 = v2.at[b, off].set(jnp.nan)
+                        k2 = k2.at[b, :, off].set(jnp.nan)
+                        v2 = v2.at[b, :, off].set(jnp.nan)
         out = paged_attention(q, k2, v2, tbl, sl)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
 
@@ -410,7 +410,7 @@ class TestPagedAttention:
         from paddle_tpu.models.generation import _kv_gather
         from paddle_tpu.models.llama import _masked_sdpa
         M, Q = q.shape[:2]
-        N, bs, Hk, D = pool["k"].shape
+        N, Hk, bs, D = pool["k"].shape
         C = tbl.shape[1] * bs
         kk, vv = _kv_gather({n: a[None] for n, a in pool.items()}, 0, tbl,
                             M, C, Hk, D)     # a pool of one layer
@@ -452,8 +452,8 @@ class TestPagedAttention:
         N = M * W + 3
         quant = bool(trial % 2)
         q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), jnp.float32)
-        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
-        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), jnp.float32)
+        kf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
+        vf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), jnp.float32)
         cap = W * bs - Q                       # room for the draft window
         sl = jnp.asarray([int(rng.integers(0, cap + 1)) for _ in range(M)],
                          jnp.int32)
@@ -517,8 +517,8 @@ class TestPagedAttention:
         M, N = len(sl), len(sl) * W + 3
         rng = np.random.default_rng(25)
         q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
-        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
-        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), dt)
         tbl = rng.permutation(np.arange(1, M * W + 1)).reshape(M, W)
         need = (sl + dl) // bs + 1
         tbl = np.where(np.arange(W)[None, :] < need[:, None], tbl, 0)
@@ -535,12 +535,12 @@ class TestPagedAttention:
                 kq, ks = self._quantize(kf)
                 vq, vs = self._quantize(vf)
                 if poison:                       # poison the QUANT layout
-                    ks = jnp.where(dead[:, :, None], jnp.nan, ks)
-                    vs = jnp.where(dead[:, :, None], jnp.nan, vs)
+                    ks = jnp.where(dead[:, None, :], jnp.nan, ks)
+                    vs = jnp.where(dead[:, None, :], jnp.nan, vs)
                 pool = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
                 kw.update(k_scale=ks, v_scale=vs)
             else:
-                k, v = ((jnp.where(dead[:, :, None, None], jnp.nan, x)
+                k, v = ((jnp.where(dead[:, None, :, None], jnp.nan, x)
                          for x in (kf, vf)) if poison else (kf, vf))
                 pool = {"k": k, "v": v}
             return pool, pa.paged_attention(qq, pool["k"], pool["v"], tbl,
@@ -618,8 +618,8 @@ class TestPagedAttention:
         N = M * Wlin + 3
         rng = np.random.default_rng(34)
         q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
-        kf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
-        vf = jnp.asarray(rng.standard_normal((N, bs, Hk, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((N, Hk, bs, D)), dt)
         lin = rng.permutation(np.arange(1, M * Wlin + 1)).reshape(M, Wlin)
         lin = np.where(np.arange(Wlin)[None, :] < pages[:, None], lin, 0)
         tbl = lin
@@ -641,7 +641,7 @@ class TestPagedAttention:
                             for a in (lin, tbl, sl, dl))
 
         def run(poison):
-            k, v = ((jnp.where(dead[:, :, None, None], jnp.nan, x)
+            k, v = ((jnp.where(dead[:, None, :, None], jnp.nan, x)
                      for x in (kf, vf)) if poison else (kf, vf))
             return pa.paged_attention(q, k, v, tbl, sl, draft_lens=dl,
                                       window=window)
@@ -661,22 +661,21 @@ class TestPagedAttention:
     @pytest.mark.parametrize("Hk", [1, 4])
     @pytest.mark.parametrize("Q", [1, 8])
     def test_whole_pool_reads_its_layer(self, Q, Hk, pool_kind):
-        """ISSUE 30: handed EVERY layer's pool ``[L, N, bs, Hk, D]`` and
+        """Handed EVERY layer's pool ``[L, N, Hk, bs, D]`` and
         ``layer`` (traced, as under a layer scan) the kernel returns, for
         each layer, what the one-layer call on ``pool[layer]`` returns,
         bit for bit: decode and multi-query form, fp32, bf16 and int8
-        with its scale planes ``[L, N, bs, Hk]``. ``Hk`` 1 is the
-        ``narrow`` shape under bf16 and int8 (fewer kv heads than a
-        32-bit row packs: the layer is sliced out and padded, not the
-        whole pool)."""
+        with its scale planes ``[L, N, Hk, bs]``. ``Hk`` 1 is a pool of
+        one kv head under bf16 and int8: a page of one ``(bs, D)`` tile,
+        copied whole out of the whole pool like any other."""
         from paddle_tpu.kernels.paged_attention import paged_attention
         L, M, G, D, bs, W = 3, 3, 2, 8, 4, 3
         N = M * W + 2
         dt = jnp.bfloat16 if pool_kind == "bf16" else jnp.float32
         rng = np.random.default_rng(30)
         q = jnp.asarray(rng.standard_normal((M, Q, Hk * G, D)), dt)
-        kf = jnp.asarray(rng.standard_normal((L, N, bs, Hk, D)), dt)
-        vf = jnp.asarray(rng.standard_normal((L, N, bs, Hk, D)), dt)
+        kf = jnp.asarray(rng.standard_normal((L, N, Hk, bs, D)), dt)
+        vf = jnp.asarray(rng.standard_normal((L, N, Hk, bs, D)), dt)
         tbl = jnp.asarray(rng.permutation(np.arange(1, M * W + 1))
                           .reshape(M, W), jnp.int32)
         sl = jnp.asarray([0, bs, W * bs - Q], jnp.int32)
@@ -709,7 +708,7 @@ class TestPagedAttention:
         (or another block's) entries."""
         from paddle_tpu.kernels.paged_attention import paged_attention
         q = jnp.zeros((1, 2, 8), jnp.float32)
-        k = jnp.zeros((2, 3, 4, 1, 8), jnp.float32)
+        k = jnp.zeros((2, 3, 1, 4, 8), jnp.float32)
         tbl, sl = jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32)
         with pytest.raises(ValueError, match="layer"):
             paged_attention(q, k, k, tbl, sl)

@@ -168,7 +168,7 @@ def _rows_case(seed, M, Q, H, starts, dls, R=None, W=12):
     Hk, D, Lg = 2, 16, 3
     n_tbl = R or W
     N = 1 + M * n_tbl
-    pool = {k: jnp.asarray(rng.standard_normal((Lg, N, BS, Hk, D)),
+    pool = {k: jnp.asarray(rng.standard_normal((Lg, N, Hk, BS, D)),
                            jnp.float32) for k in ("k", "v")}
     tbl = jnp.asarray(1 + np.arange(M * n_tbl).reshape(M, n_tbl), jnp.int32)
     q = jnp.asarray(rng.standard_normal((M, Q, H, D)), jnp.float32)
@@ -187,10 +187,12 @@ def test_c_a_sliding_layer_never_reads_behind_its_window(kernel):
     cells = np.asarray(tbl)[0][:, None] * BS + np.arange(BS)[None, :]
     bad = {name: np.array(arr) for name, arr in pool.items()}
     for name in bad:
-        flat = bad[name].reshape(3, -1, 2, 16)
+        # the head-major pool by cell: [layers, N * BS, Hk, D]
+        flat = np.swapaxes(bad[name], 2, 3).reshape(3, -1, 2, 16)
         flat[:, cells.reshape(-1)[behind]] = np.nan
         flat[:, :BS] = np.nan                              # the null block
-        bad[name] = jnp.asarray(flat.reshape(pool[name].shape))
+        bad[name] = jnp.asarray(np.swapaxes(
+            flat.reshape(3, -1, BS, 2, 16), 2, 3))
     assert behind.sum() >= 8
     clean = L._attend_rows(q, pool, 1, tbl, start, dl, window, kernel)
     dirty = L._attend_rows(q, bad, 1, tbl, start, dl, window, kernel)
@@ -266,8 +268,8 @@ def test_e_window_kernel_is_the_gathered_oracle(case):
     live = [(p, c) for p, c in zip(ring_pos, cells)
             if max(pos - 8, -1) < p <= pos]
     assert sorted(p for p, _ in live) == list(range(max(0, pos - 7), pos + 1))
-    k = np.asarray(pool["k"][2]).reshape(-1, 2, 16)[[c for _, c in live]]
-    v = np.asarray(pool["v"][2]).reshape(-1, 2, 16)[[c for _, c in live]]
+    k, v = (np.swapaxes(np.asarray(pool[n][2]), 1, 2).reshape(-1, 2, 16)[
+        [c for _, c in live]] for n in ("k", "v"))
     for h in range(6):
         s = k[:, h // 3] @ np.asarray(q[m, i, h]) / 4.0
         p = np.exp(s - s.max())

@@ -469,7 +469,7 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
     M, Q = tokens.shape
     H, Hk = G._local_heads(cfg, pool)
     D, dt = cfg.head_dim, cfg.dtype
-    bs, W = pool["k"].shape[2], block_tables.shape[1]
+    bs, W = pool["k"].shape[3], block_tables.shape[1]
     C = W * bs
     draft_lens = jnp.maximum(q_lens - 1, 0)
     qi = jnp.arange(Q)
@@ -486,14 +486,14 @@ def _plain_mixed_step(params, cfg, tokens, starts, q_lens, block_tables,
     x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
 
     def store(p, k, v):
-        """One layer's slab ``[N, bs, Hk, D]`` with the new entries in."""
+        """One layer's slab ``[N, Hk, bs, D]`` with the new entries in."""
         out = dict(p)
         if "k_scale" in p:
             (k, sk), (v, sv) = G._kv_quantize(k), G._kv_quantize(v)
-            out["k_scale"] = p["k_scale"].at[phys, off].set(sk)
-            out["v_scale"] = p["v_scale"].at[phys, off].set(sv)
-        out["k"] = p["k"].at[phys, off].set(k.astype(p["k"].dtype))
-        out["v"] = p["v"].at[phys, off].set(v.astype(p["v"].dtype))
+            out["k_scale"] = p["k_scale"].at[phys, :, off].set(sk)
+            out["v_scale"] = p["v_scale"].at[phys, :, off].set(sv)
+        out["k"] = p["k"].at[phys, :, off].set(k.astype(p["k"].dtype))
+        out["v"] = p["v"].at[phys, :, off].set(v.astype(p["v"].dtype))
         return out
 
     def body(h, xs):

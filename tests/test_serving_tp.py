@@ -132,10 +132,10 @@ class TestTPBitParity:
             e2 = mk(params, 2, kv_quant="int8", paged_kernel=kernel)
             o2 = e2.run(prompts, max_new_tokens=10, eos_token_id=None)
             assert _parity(o1, o2), f"kernel={kernel}"
-            # the scale leaves actually split with the kv heads (dim 3 of
-            # both layouts; jax normalizes away trailing None entries)
-            assert e2.cache.pool["k_scale"].sharding.spec[3] == "tp"
-            assert e2.cache.pool["k"].sharding.spec[3] == "tp"
+            # the scale leaves actually split with the kv heads (dim 2 of
+            # both head-major layouts)
+            assert e2.cache.pool["k_scale"].sharding.spec[2] == "tp"
+            assert e2.cache.pool["k"].sharding.spec[2] == "tp"
 
     def test_seeded_sampling(self, tp_platform, eng1, eng2, prompts):
         """Sampled streams (per-request temperature/top-k/top-p/seed)
@@ -322,7 +322,7 @@ class TestTPCapacityAndObservability:
         for leaf in eng2.cache.pool.values():
             shards = leaf.addressable_shards
             assert len(shards) == 2
-            assert shards[0].data.shape[3] * 2 == leaf.shape[3]
+            assert shards[0].data.shape[2] * 2 == leaf.shape[2]
 
     def test_block_bytes_arithmetic(self, tp_platform):
         full = G.paged_pool_block_bytes(CFG, 8)
