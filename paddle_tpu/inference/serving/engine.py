@@ -175,12 +175,22 @@ HEALTH_SNAPSHOT_FIELDS = {
                          "milliseconds per engine step in each serve:* "
                          "phase since start (idle / cmds / route / deliver "
                          "from the server's pump, supervise from the "
-                         "supervisor, plan / operands / dispatch / fetch / "
-                         "commit / journal from the engine; fetch is the "
-                         "device's time as the host sees it). The same "
-                         "spans are in a profiler trace when one is taken; "
-                         "the cumulative totals, counters and histograms "
-                         "behind this row ride stats()['spans']",
+                         "supervisor, plan / build / upload / dispatch / "
+                         "wait / copy / commit / journal from the engine: "
+                         "build makes a dispatch's numpy operands, upload "
+                         "hands them to the device, wait is the device's "
+                         "time as the host sees it, copy brings the "
+                         "outputs back while the device has nothing "
+                         "queued). Those spans are flat and are in a "
+                         "profiler trace when one is taken; operands (= "
+                         "build + upload) and fetch (= wait + copy) are "
+                         "rows of sums, written once a dispatch, with no "
+                         "trace event. The cumulative totals, counters and "
+                         "histograms behind this row ride stats()['spans'] "
+                         "(the histogram dispatch_s: each dispatch's wall "
+                         "time, dispatch to copy, every kind together; "
+                         "two snapshots subtract to a window's longest "
+                         "dispatch)",
     "request_wait": "per-request waits from cumulative histograms: "
                     "queue_wait_p50_s / queue_wait_p99_s (front-line "
                     "enqueue -> first admission, the server's command "
@@ -675,7 +685,10 @@ class ServingEngine:
         # where the pump thread's time goes, always on: every phase of a
         # step is one flat ``serve:*`` span (profiler.annotate, so it is
         # in the trace when one is taken) summed here with the lane and
-        # iteration counters and the per-request wait histograms.
+        # iteration counters, the per-request wait histograms and the
+        # dispatch wall histogram ``dispatch_s``; the rows
+        # ``serve:operands`` and ``serve:fetch`` sum a dispatch's leaves
+        # (_upload, _fetch) and are in no trace.
         # Cumulative, never reset: stats()["spans"] read twice subtracts
         # to a window. The supervisor and the server's pump write their
         # phases into THIS aggregator, and a supervisor rebuild hands it
@@ -935,18 +948,63 @@ class ServingEngine:
         """One flat ``serve:*`` phase span of the current step."""
         return self.spans.span(name, kind, step=self.step_no)
 
+    def _upload(self, kind: str, build, host: tuple,
+                adapters=None) -> tuple:
+        """The ``serve:upload`` span of one dispatch: each numpy operand
+        in ``host`` handed to the device (``jnp.asarray``), then the LoRA
+        operand of the rows' ``adapters`` where the dispatch takes one.
+        ``build`` is the ``serve:build`` span just closed, in which the
+        operands were made; the aggregate row ``serve:operands`` gets
+        the two together (``build.t0`` to this span's end, from the
+        leaves' own stamps) and has no trace event of its own. Returns
+        the device operands, in ``host``'s order."""
+        import jax.numpy as jnp
+        with self._span("serve:upload", kind) as up:
+            ops = tuple(jnp.asarray(x) for x in host)
+            if adapters is not None:
+                ops += self._lora_operand(adapters)
+        self.spans.add("serve:operands", up.t1 - build.t0, kind)
+        return ops
+
+    def _fetch(self, kind: str, outs: tuple, aux: tuple = (),
+               host=np.asarray):
+        """Bring one dispatch's outputs to the host, as two flat spans:
+        ``serve:wait``, ``jax.block_until_ready`` on the arrays the copy
+        reads (``outs`` and the family's counter array in ``aux``; never
+        the pool), which returns when the program has finished, and
+        ``serve:copy``, ``host`` of each of ``outs`` and
+        :meth:`_count_dispatch`, while the device has nothing queued.
+        In the prefill path ``host`` is the first-token pick
+        (:meth:`_first_tokens`): what it runs after the program's own
+        outputs are ready (the host argmax, or the sampler's program and
+        its copy) is ``serve:copy``. The aggregate row ``serve:fetch``
+        gets both (the wait's start to the copy's end) and has no trace
+        event of its own. Returns the host copies, the counters and the
+        copy span, whose end closes the dispatch's wall time."""
+        import jax
+        with self._span("serve:wait", kind) as wait:
+            jax.block_until_ready((outs, aux))
+        with self._span("serve:copy", kind) as copy:
+            got = tuple(host(x) for x in outs)
+            counts = self._count_dispatch(*aux)
+        self.spans.add("serve:fetch", copy.t1 - wait.t0, kind)
+        return got, counts, copy
+
     def _record_dispatch(self, kind: str, t0: float, t1: float) -> None:
         """Count + time ONE device dispatch by kind (ISSUE 20), from the
-        ``serve:dispatch`` span's start to the ``serve:fetch`` span's
+        ``serve:dispatch`` span's start to the ``serve:copy`` span's
         end (the same two ``perf_counter`` stamps, no second pair). Every
         dispatch — batched prefill, embed encode, decode loop, mixed
         step, spec verify — lands here, so ``chunks`` is the all-kinds
         dispatch total, the per-kind ``*_dispatches`` counters split it,
         and the wall time feeds the bounded window behind the p50/p99
-        dispatch-latency rows in stats()/health_snapshot()."""
+        dispatch-latency rows in stats()/health_snapshot() and, every
+        kind together, the cumulative histogram ``dispatch_s``, which
+        two snapshots subtract to a window (its longest dispatch)."""
         self._stats["chunks"] += 1
         self._stats[kind + "_dispatches"] += 1
         self._dispatch_ms[kind].append((t1 - t0) * 1e3)
+        self.spans.observe("dispatch_s", t1 - t0)
         # what the pool holds, once a dispatch (over the dispatches of a
         # window: the mean share of the pool in use), and of it what the
         # bounded groups of a grouped cache hold
@@ -959,7 +1017,7 @@ class ServingEngine:
 
     def _count_dispatch(self, aux=None) -> Dict[str, int]:
         """Add one dispatch's device counters (the small array a counting
-        family's program returns, fetched here inside the ``serve:fetch``
+        family's program returns, fetched here inside the ``serve:copy``
         the dispatch makes anyway) to the span aggregator under the
         family's names, and return them. A family that counts nothing
         passes nothing."""
@@ -1783,8 +1841,7 @@ class ServingEngine:
                           emitted: Dict[int, List[int]]) -> None:
         """ONE batched bucketed prefill over a wave of cold short
         prompts; every request in it emits its first token."""
-        import jax.numpy as jnp
-        with self._span("serve:operands", "prefill"):
+        with self._span("serve:build", "prefill") as build:
             self._prefill_buckets.add(Sb)
             Bb = 1
             while Bb < len(group):
@@ -1801,17 +1858,16 @@ class ServingEngine:
                 tables[r] = self.cache.tables[req.slot]
                 act[r] = True
                 aids[r] = req.adapter_slot
-            ops = (jnp.asarray(ids), jnp.asarray(plens), jnp.asarray(tables))
-            tail = (jnp.asarray(act), *self._lora_operand(aids))
+        ops = self._upload("prefill", build, (ids, plens, tables, act), aids)
         with _watchdog.section("serving.prefill"):
             with self._span("serve:dispatch", "prefill") as d:
                 logits, self.cache.pool, aux = self._jprefill(
-                    self._params, *ops, self.cache.pool, *tail)
-            with self._span("serve:fetch", "prefill") as f:
-                first = self._first_tokens(logits, group, Bb)
-                self._count_dispatch(aux)
+                    self._params, *ops[:3], self.cache.pool, *ops[3:])
+            (first,), _, copy = self._fetch(
+                "prefill", (logits,), (aux,),
+                lambda lg: self._first_tokens(lg, group, Bb))
         with self._span("serve:commit", "prefill"):
-            self._record_dispatch("prefill", d.t0, f.t1)
+            self._record_dispatch("prefill", d.t0, copy.t1)
             self.spans.count("prefill_tokens",
                              sum(r.prompt_len for r in group))
             self.spans.count("prefill_lanes_real",
@@ -1842,8 +1898,7 @@ class ServingEngine:
         embedding requests. The whole batch admits, encodes and FINISHES
         inside one locked step — embeds hold no decode slot and no KV
         blocks, so no observer ever sees one mid-flight."""
-        import jax.numpy as jnp
-        with self._span("serve:operands", "embed"):
+        with self._span("serve:build", "embed") as build:
             Bb = 1
             while Bb < len(grp):
                 Bb *= 2
@@ -1852,14 +1907,13 @@ class ServingEngine:
             for r, req in enumerate(grp):
                 ids[r, :req.prompt_len] = req.prompt
                 lens[r] = req.prompt_len
-            ops = (jnp.asarray(ids), jnp.asarray(lens))
+        ops = self._upload("embed", build, (ids, lens))
         with _watchdog.section("serving.prefill"):
             with self._span("serve:dispatch", "embed") as d:
                 pooled = self._jembed(self._embed_params, *ops)
-            with self._span("serve:fetch", "embed") as f:
-                pooled = np.asarray(pooled)
+            (pooled,), _, copy = self._fetch("embed", (pooled,))
         with self._span("serve:commit", "embed"):
-            self._record_dispatch("prefill", d.t0, f.t1)
+            self._record_dispatch("prefill", d.t0, copy.t1)
             now = time.time()
             for r, req in enumerate(grp):
                 req.embedding = pooled[r]
@@ -2108,8 +2162,7 @@ class ServingEngine:
         per slot (EOS truncates), advance the sampling cursor, register
         freshly-filled prefix blocks and roll back the rejected tail's
         surplus blocks."""
-        import jax.numpy as jnp
-        with self._span("serve:operands", "spec"):
+        with self._span("serve:build", "spec") as build:
             Q = self._spec_k + 1
             M = self.config.max_slots
             toks = np.zeros((M, Q), np.int32)
@@ -2121,24 +2174,18 @@ class ServingEngine:
                 toks[m, 1:1 + len(d)] = d
                 toks[m, 1 + len(d):] = self._tokens[m]   # pad: a real token
                 dl[m] = len(d)
-            ops = (jnp.asarray(toks),
-                   jnp.asarray(self._seq_lens), jnp.asarray(dl),
-                   jnp.asarray(self._steps_left), jnp.asarray(self._done),
-                   jnp.asarray(self.cache.tables), jnp.asarray(self._keys),
-                   jnp.asarray(self._sample_idx), jnp.asarray(self._temp),
-                   jnp.asarray(self._topk), jnp.asarray(self._topp),
-                   *self._lora_operand(self._adapters))
+            host = (toks, self._seq_lens, dl, self._steps_left, self._done,
+                    self.cache.tables, self._keys, self._sample_idx,
+                    self._temp, self._topk, self._topp)
+        ops = self._upload("spec", build, host, self._adapters)
         with _watchdog.section("serving.decode"):
             with self._span("serve:dispatch", "spec") as d:
                 self.cache.pool, cand, acc, *aux = self._jspec(
                     self._params, self.cache.pool, *ops)
                 del ops
-            with self._span("serve:fetch", "spec") as f:
-                cand = np.asarray(cand)
-                acc = np.asarray(acc)
-                self._count_dispatch(*aux)
+            (cand, acc), _, copy = self._fetch("spec", (cand, acc), aux)
         with self._span("serve:commit", "spec"):
-            self._record_dispatch("spec", d.t0, f.t1)
+            self._record_dispatch("spec", d.t0, copy.t1)
             for req in decoding:
                 m = req.slot
                 if self._done[m] or self._steps_left[m] <= 0:
@@ -2180,10 +2227,8 @@ class ServingEngine:
         prompt samples the first token in this same dispatch (TTFT no
         longer waits for the next step's decode); incomplete chunks and
         readmission recomputes discard their sampled lane."""
-        import jax.numpy as jnp
-
         from ...models.generation import seed_key
-        with self._span("serve:operands", "mixed"):
+        with self._span("serve:build", "mixed") as build:
             chunk = self.config.prefill_chunk
             M = self.config.max_slots
             decode_rows = [r for r in self._sched.decoding
@@ -2235,22 +2280,17 @@ class ServingEngine:
                 topk[m] = req.top_k if req.top_k is not None else 0
                 topp[m] = req.top_p if req.top_p is not None else 1.0
                 adapters[m] = req.adapter_slot
-            ops = (jnp.asarray(toks),
-                   jnp.asarray(starts), jnp.asarray(qlens),
-                   jnp.asarray(active), jnp.asarray(self.cache.tables),
-                   jnp.asarray(keys), jnp.asarray(sidx), jnp.asarray(temp),
-                   jnp.asarray(topk), jnp.asarray(topp),
-                   *self._lora_operand(adapters))
+            host = (toks, starts, qlens, active, self.cache.tables, keys,
+                    sidx, temp, topk, topp)
+        ops = self._upload("mixed", build, host, adapters)
         with _watchdog.section("serving.decode"):
             with self._span("serve:dispatch", "mixed") as d:
                 self.cache.pool, nxt, *aux = self._jmixed(
                     self._params, self.cache.pool, *ops)
                 del ops
-            with self._span("serve:fetch", "mixed") as f:
-                nxt = np.asarray(nxt)
-                counts = self._count_dispatch(*aux)
+            (nxt,), counts, copy = self._fetch("mixed", (nxt,), aux)
         with self._span("serve:commit", "mixed"):
-            self._record_dispatch("mixed", d.t0, f.t1)
+            self._record_dispatch("mixed", d.t0, copy.t1)
             self.spans.count("prefill_tokens", sum(n for _, n in plan))
             self.spans.count("decode_tokens", len(decode_rows))
             # query lanes that carried a real token against the lanes the
@@ -2313,30 +2353,23 @@ class ServingEngine:
                          emitted: Dict[int, List[int]]) -> None:
         """One dispatch of the decode loop over the slot table, up to
         ``k`` iterations (a device scalar: no retrace)."""
-        import jax.numpy as jnp
-        with self._span("serve:operands", "decode"):
+        with self._span("serve:build", "decode") as build:
             before = self._steps_left.copy()
-            ops = (jnp.asarray(self._tokens),
-                   jnp.asarray(self._seq_lens),
-                   jnp.asarray(self._steps_left),
-                   jnp.asarray(self._done), jnp.asarray(self.cache.tables),
-                   jnp.asarray(self._eos), jnp.asarray(k, jnp.int32),
-                   jnp.asarray(self._keys), jnp.asarray(self._sample_idx),
-                   jnp.asarray(self._temp), jnp.asarray(self._topk),
-                   jnp.asarray(self._topp),
-                   *self._lora_operand(self._adapters))
+            host = (self._tokens, self._seq_lens, self._steps_left,
+                    self._done, self.cache.tables, self._eos, np.int32(k),
+                    self._keys, self._sample_idx, self._temp, self._topk,
+                    self._topp)
+        ops = self._upload("decode", build, host, self._adapters)
         with _watchdog.section("serving.decode"):
             with self._span("serve:dispatch", "decode") as d:
-                out = self._jdecode(self._params, self.cache.pool, *ops)
+                self.cache.pool, *out = self._jdecode(
+                    self._params, self.cache.pool, *ops)
                 del ops      # released here, inside the span that used them
-            with self._span("serve:fetch", "decode") as f:
-                self.cache.pool = out[0]
-                tokens, seq_lens, steps_left, done, toks = (
-                    np.asarray(x) for x in out[1:6])
-                self._count_dispatch(*out[6:])
-                del out
+            (tokens, seq_lens, steps_left, done, toks), _, copy = \
+                self._fetch("decode", tuple(out[:5]), out[5:])
+            del out
         with self._span("serve:commit", "decode"):
-            self._record_dispatch("decode", d.t0, f.t1)
+            self._record_dispatch("decode", d.t0, copy.t1)
             # np.array (copy): zero-copy views of jax outputs are
             # read-only, and admission writes these slots in place next
             # step
@@ -2456,10 +2489,13 @@ class ServingEngine:
         named in the hang diagnosis exactly like a training section.
 
         The step's wall time is covered by flat, disjoint ``serve:*``
-        spans (``plan`` / ``operands`` / ``dispatch`` / ``fetch`` /
-        ``commit`` / ``journal``; none encloses another, so the one
-        covering a device idle gap names its cause), each carrying
-        ``step=`` and, at a dispatch site, ``kind=``."""
+        spans (``plan`` / ``build`` / ``upload`` / ``dispatch`` /
+        ``wait`` / ``copy`` / ``commit`` / ``journal``; none encloses
+        another, so the one covering a device idle gap names its cause),
+        each carrying ``step=`` and, at a dispatch site, ``kind=``. The
+        aggregator's rows ``serve:operands`` (build + upload) and
+        ``serve:fetch`` (wait + copy) are sums of a dispatch's leaves,
+        not trace events."""
         _watchdog.touch()
         with self._lock, _watchdog.section("serving.step"):
             self.step_no += 1

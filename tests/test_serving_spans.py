@@ -27,10 +27,16 @@ CFG = LlamaConfig(vocab_size=97, hidden_size=64, intermediate_size=96,
                   num_key_value_heads=2, max_position_embeddings=96)
 SC = dict(block_size=4, max_slots=2, max_model_len=48, decode_chunk=2,
           queue_depth=16, prefill_chunk=8)
-ENGINE_SPANS = ("serve:plan", "serve:operands", "serve:dispatch",
-                "serve:fetch", "serve:commit", "serve:journal")
+ENGINE_SPANS = ("serve:plan", "serve:build", "serve:upload",
+                "serve:dispatch", "serve:wait", "serve:copy", "serve:commit",
+                "serve:journal")
+# a dispatch's phases, each a trace event with ``kind=``
+DISPATCH_SPANS = ENGINE_SPANS[1:-1]
 PUMP_SPANS = ENGINE_SPANS + ("serve:idle", "serve:cmds", "serve:route",
                              "serve:deliver", "serve:supervise")
+# rows of the aggregator that sum a dispatch's leaves: no trace event
+SUMS = {"serve:operands": ("serve:build", "serve:upload"),
+        "serve:fetch": ("serve:wait", "serve:copy")}
 
 
 @pytest.fixture(scope="module")
@@ -200,12 +206,62 @@ def test_lane_and_iteration_counters_by_hand(params, programs):
     assert st["spans"]["histograms"]["prefill_s"]["count"] == 2
     snap = eng.health_snapshot()
     assert set(snap["phase_ms_per_step"]) == {
-        n[len("serve:"):] for n in ENGINE_SPANS}
+        n[len("serve:"):] for n in ENGINE_SPANS + tuple(SUMS)}
     assert snap["request_wait"]["queue_wait_p50_s"] is not None
     # the operator's reading of the lane counters: 22 of 48, 5 of 8
     assert snap["real_lane_pct"] == {"mixed": 45.83, "prefill": 62.5}
     assert snap["short_row_pct"] == 40.0
     json.dumps(snap)
+
+
+def test_operands_and_fetch_rows_are_the_sums_of_their_leaves():
+    """Every kind of dispatch: a verify engine with an encoder attached
+    drafts from a repeated bigram (spec), chunks the long prompt (mixed),
+    takes the short one in a batched prefill, falls through to the decode
+    loop where nothing drafts, and encodes (embed). Per kind, the row
+    ``serve:operands`` is ``serve:build`` + ``serve:upload`` and
+    ``serve:fetch`` is ``serve:wait`` + ``serve:copy``, from the leaves'
+    own stamps: the first leaf's start to the second's end. What lies
+    between the two leaves (the first span's bookkeeping and the second's
+    opening: 6-10 us a dispatch on an unloaded CPU) is in the row and in
+    neither leaf, as it was in the one span the row was before; the bound
+    below leaves a loaded CPU twenty times that. Every dispatch writes
+    each row once and one ``dispatch_s`` sample."""
+    import dataclasses
+
+    from paddle_tpu.models.bert import BertConfig, bert_init_params
+    cfg = dataclasses.replace(CFG, vocab_size=16)
+    bcfg = BertConfig(vocab_size=16, hidden_size=32, num_hidden_layers=1,
+                      num_attention_heads=4, intermediate_size=64,
+                      max_position_embeddings=64)
+    eng = ServingEngine(
+        init_params(cfg, jax.random.PRNGKey(0)), cfg,
+        ServingConfig(**{**SC, "spec_decode": 2, "spec_ngram": 2}),
+        embed_model=(bcfg, bert_init_params(bcfg, seed=3)))
+    rep = np.full((2 * cfg.vocab_size + 1,), 5, np.int32)
+    rep[1::2] = np.arange(cfg.vocab_size)
+    eng.submit(rep, max_new_tokens=6, eos_token_id=None)
+    eng.submit(rep[:6], max_new_tokens=5, eos_token_id=None,
+               temperature=0.8, top_k=8, seed=1)
+    erid = eng.submit_embedding(rep[:9])
+    while eng.pending:
+        eng.step()
+    assert eng.embedding(erid).shape == (bcfg.hidden_size,)
+    st = eng.stats()
+    sp = st["spans"]["spans"]
+    kinds = {"prefill", "mixed", "decode", "spec", "embed"}
+    for row, leaves in SUMS.items():
+        assert set(sp[row]["kinds"]) == kinds, row
+        for kind in kinds:
+            got = sp[row]["kinds"][kind]
+            parts = [sp[n]["kinds"][kind] for n in leaves]
+            assert got["count"] == parts[0]["count"] == parts[1]["count"]
+            gap = got["seconds"] - sum(p["seconds"] for p in parts)
+            assert 0 <= gap <= 200e-6 * got["count"], (row, kind, gap)
+        assert sp[row]["count"] == st["chunks"]
+    assert st["spans"]["histograms"]["dispatch_s"]["count"] == st["chunks"]
+    assert st["chunks"] == sum(st[k + "_dispatches"] for k in (
+        "prefill", "mixed", "decode", "spec"))
 
 
 # ---------------------------------------------------------------------------
@@ -247,8 +303,9 @@ def test_pump_spans_close_the_threads_wall_time(params, programs):
 
     a, b, wall = asyncio.run(go())
     d = profiler.snapshot_delta(b["spans"], a["spans"])
-    assert set(d["spans"]) == set(PUMP_SPANS)
-    covered = sum(row["seconds"] for row in d["spans"].values())
+    assert set(d["spans"]) == set(PUMP_SPANS) | set(SUMS)
+    # the flat spans; the two rows of sums would count their leaves twice
+    covered = sum(d["spans"][n]["seconds"] for n in PUMP_SPANS)
     # stats() itself holds the engine lock between the pump's spans; a
     # span open at either edge is cut: both are far inside 5%
     assert covered == pytest.approx(wall, rel=0.05)
@@ -359,8 +416,7 @@ def test_trace_holds_flat_serve_spans_with_step_and_kind(params, programs,
         assert start >= end, f"{name} opens inside another span"
     for name, _, _, args in spans:
         assert args["step"] >= 1, name
-        at_dispatch = name in ("serve:operands", "serve:dispatch",
-                               "serve:fetch", "serve:commit")
+        at_dispatch = name in DISPATCH_SPANS
         assert ("kind" in args) == at_dispatch, (name, args)
     kinds = {args["kind"] for _, _, _, args in spans if "kind" in args}
     assert kinds == {"prefill", "mixed", "decode"}
@@ -370,8 +426,9 @@ def test_trace_holds_flat_serve_spans_with_step_and_kind(params, programs,
     names = [n for n, _, _, a in spans if a["step"] == step
              and n in ENGINE_SPANS]
     assert names[0] == "serve:plan"
-    assert names[-5:] == ["serve:operands", "serve:dispatch", "serve:fetch",
-                          "serve:commit", "serve:journal"]
+    assert names[-7:] == ["serve:build", "serve:upload", "serve:dispatch",
+                          "serve:wait", "serve:copy", "serve:commit",
+                          "serve:journal"]
 
 
 # ---------------------------------------------------------------------------
